@@ -59,7 +59,7 @@ def main() -> int:
     os.makedirs(os.path.join(tmp, "m"))
     os.makedirs(os.path.join(tmp, "v"))
     mport, vport = free_port(), free_port()
-    env = dict(os.environ, JAX_PLATFORMS="cpu", SEAWEEDFS_FORCE_CPU="1",
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                WEED_SERVE_SHARDS=str(shards))
     procs = []
     try:

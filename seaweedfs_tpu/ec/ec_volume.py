@@ -27,6 +27,7 @@ from ..storage import idx as idx_mod
 from ..storage import types as t
 from ..storage.needle import Needle
 from ..storage.superblock import SuperBlock
+from ..utils import metrics as metrics_mod
 from .coder import ErasureCoder
 
 # shared fan-out pool for parallel remote-survivor fetches; sized for one
@@ -291,6 +292,9 @@ class EcVolume:
                 f"cannot reconstruct shard {missing_shard}: "
                 f"only {have} of {self.g.data_shards} shards reachable")
         rebuilt = self.coder.reconstruct(shards, targets=(missing_shard,))
+        reg = metrics_mod.shared("ec")
+        reg.count("reconstruct_intervals")
+        reg.count("reconstruct_bytes", value=size)
         return np.asarray(rebuilt[missing_shard]).tobytes()
 
     # --- delete path ---

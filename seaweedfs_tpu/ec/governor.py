@@ -3,7 +3,7 @@
 The streaming pipeline used to run a fixed 8 MB batch at queue depth 4
 regardless of what actually binds it — but the binding stage is a host
 property (page-cache memcpy on a 1-core container, disk on spinners, the
-device link on tunneled chips), and the right batch/depth follows from
+host-device link), and the right batch/depth follows from
 the measured stage times, not from a constant. This governor closes the
 loop:
 

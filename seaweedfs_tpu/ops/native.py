@@ -1,7 +1,9 @@
 """ctypes binding to the native C++ core (native/rs_core.cpp).
 
-Builds the shared library on first use (g++ via native/Makefile) and exposes
-the CPU-side GF(2^8) matrix kernel and CRC32C. This is the build's
+Builds the shared library on first use (g++ via native/Makefile), and again
+whenever the source, the Makefile or the host CPU differ from what the
+library on disk was built from, and exposes the CPU-side GF(2^8) matrix
+kernel and CRC32C. This is the build's
 counterpart of the reference's native dependencies (klauspost/reedsolomon,
 klauspost/crc32 — seaweedfs go.mod:44-45).
 """
@@ -9,7 +11,10 @@ klauspost/crc32 — seaweedfs go.mod:44-45).
 from __future__ import annotations
 
 import ctypes
+import fcntl
+import hashlib
 import os
+import platform
 import subprocess
 import threading
 from typing import Optional
@@ -19,6 +24,7 @@ import numpy as np
 _NATIVE_DIR = os.path.join(os.path.dirname(os.path.dirname(
     os.path.dirname(os.path.abspath(__file__)))), "native")
 _SO_PATH = os.path.join(_NATIVE_DIR, "libseaweedtpu.so")
+_STAMP_PATH = _SO_PATH + ".stamp"
 
 _lib: Optional[ctypes.CDLL] = None
 _load_error: Optional[Exception] = None
@@ -27,6 +33,49 @@ _lock = threading.Lock()
 
 class NativeUnavailable(RuntimeError):
     pass
+
+
+def _build_stamp() -> str:
+    """What the library on disk must have been built from: the source,
+    the Makefile (its flags include -march=native) and this host's CPU.
+    A tree copied to another machine carries the old binary along, and a
+    -march=native build from a different CPU dies with SIGILL."""
+    h = hashlib.sha256()
+    for name in ("rs_core.cpp", "Makefile"):
+        with open(os.path.join(_NATIVE_DIR, name), "rb") as f:
+            h.update(f.read())
+    h.update(platform.machine().encode())
+    try:
+        with open("/proc/cpuinfo") as f:
+            for line in f:
+                if line.startswith(("model name", "flags", "Features")):
+                    h.update(line.encode())
+                    if line.startswith(("flags", "Features")):
+                        break  # first core is enough
+    except OSError:
+        h.update(platform.processor().encode())
+    return h.hexdigest()
+
+
+def _ensure_built() -> None:
+    """Rebuild unless the stamp beside the library names the present
+    source on the present host. The flock serialises concurrent first
+    uses (a test run spawning several servers) so nobody dlopens a
+    half-written file."""
+    want = _build_stamp()
+    with open(_STAMP_PATH + ".lock", "w") as lock:
+        fcntl.flock(lock, fcntl.LOCK_EX)
+        try:
+            with open(_STAMP_PATH) as f:
+                have = f.read().strip()
+        except OSError:
+            have = ""
+        if have == want and os.path.exists(_SO_PATH):
+            return
+        subprocess.run(["make", "-B", "-C", _NATIVE_DIR, "libseaweedtpu.so"],
+                       check=True, capture_output=True, text=True)
+        with open(_STAMP_PATH, "w") as f:
+            f.write(want + "\n")
 
 
 def _load() -> ctypes.CDLL:
@@ -38,17 +87,13 @@ def _load() -> ctypes.CDLL:
             # failed once (missing toolchain etc.) — don't re-spawn make on
             # every coder resolution
             raise NativeUnavailable(str(_load_error)) from _load_error
-        if not os.path.exists(_SO_PATH) or (
-                os.path.getmtime(_SO_PATH)
-                < os.path.getmtime(os.path.join(_NATIVE_DIR, "rs_core.cpp"))):
-            try:
-                subprocess.run(["make", "-C", _NATIVE_DIR],
-                               check=True, capture_output=True, text=True)
-            except (subprocess.CalledProcessError, FileNotFoundError) as e:
-                detail = getattr(e, "stderr", str(e))
-                _load_error = NativeUnavailable(
-                    f"cannot build native core: {detail}")
-                raise _load_error from e
+        try:
+            _ensure_built()
+        except (subprocess.CalledProcessError, OSError) as e:
+            detail = getattr(e, "stderr", None) or str(e)
+            _load_error = NativeUnavailable(
+                f"cannot build native core: {detail}")
+            raise _load_error from e
         lib = ctypes.CDLL(_SO_PATH)
         lib.gf_matrix_apply.argtypes = [
             ctypes.POINTER(ctypes.c_uint8), ctypes.c_int, ctypes.c_int,

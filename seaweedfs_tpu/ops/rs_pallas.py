@@ -1,10 +1,10 @@
 """Hand-tiled Pallas TPU kernel for bulk GF(2^8) matrix application.
 
 The XLA path in rs_jax.py materializes the 8x bit-plane expansion and the
-int32 accumulator in HBM (~25x the input traffic), capping it near 27 GB/s on
-a v5e. This kernel keeps the whole expand -> MXU matmul -> mod-2 -> repack
-chain inside VMEM per tile, so HBM sees only the 10 input bytes and 4 parity
-bytes per column — the hot loop the reference runs on CPU SIMD
+int32 accumulator in HBM (~25x the input traffic). This kernel keeps the
+whole expand -> MXU matmul -> mod-2 -> repack chain inside VMEM per tile,
+so HBM sees only the 10 input bytes and 4 parity bytes per column — the
+hot loop the reference runs on CPU SIMD
 (seaweedfs weed/storage/erasure_coding/ec_encoder.go:162-192 via
 klauspost/reedsolomon assembly), rebuilt for the TPU memory hierarchy.
 
@@ -12,6 +12,11 @@ Bit-plane layouts are pre-permuted so the kernel only does cheap sublane
 concatenation / static row slices:
   input rows:  plane-major  j*C + c  == bit j of input byte c
   output rows: plane-major  i*R + r  == bit i of output byte r
+
+The coefficient matrix is a runtime operand of the compiled program, so
+one executable per (rows, cols, width) serves encode and every
+reconstruction pattern — a degraded read with a new loss pattern reuses
+the program (and the persistent compile cache entry) of the last one.
 """
 
 from __future__ import annotations
@@ -27,11 +32,22 @@ from jax.experimental.pallas import tpu as pltpu
 from . import gf256
 from .rs_jax import bitplane_matrix
 
-# 256K columns/tile ≈ 70MB VMEM for RS(10,4) — comfortably inside a v5e
-# core's 128MB and ~30% faster than small tiles (fewer grid steps, deeper
-# DMA pipelining); PallasCoder falls back to smaller tiles on chips where
-# the compile exceeds VMEM
-DEFAULT_TILE = 262144
+# Columns per grid step, and the scoped-VMEM limit every pallas_call here
+# states. Found on a v5e (TPU v5 lite, jax 0.9.0 / libtpu 0.0.34, PR 21):
+# at RS(10,4) over an [10, 8 MiB] batch every tile from 4096 to 262144
+# compiled under this limit and ran at 43.0-45.8 GB/s of input, the spread
+# of one tile's own repeats, while the first call (compile) grew from 0.5 s
+# at 16384 to 2.4 s at 65536 and 13.7 s at 262144. The served path meets a
+# new width with every ragged last batch and every degraded-read bucket,
+# so the tile is the largest one that still compiles in half a second.
+# VMEM at T = 16384, uint8 blocks padded to 32 sublanes: in 32*T and out
+# 32*T, each double-buffered = 2 MiB; the body's values if fully live
+# (int32 data 16*T*4, int8 bit rows 96*T, int32 accumulator 32*T*4, int32
+# output 8*T*4) = 5 MiB more. 32 MiB leaves room for RS(20,4) (compiled)
+# and is a quarter of the chip's 128 MiB. The same kernel also compiled
+# with no limit stated at 16384, 65536 and 262144.
+TILE = 16384
+VMEM_LIMIT_BYTES = 32 * 1024 * 1024
 
 
 def _plane_major_matrix(matrix: np.ndarray) -> np.ndarray:
@@ -43,7 +59,7 @@ def _plane_major_matrix(matrix: np.ndarray) -> np.ndarray:
     return w[np.ix_(row_perm, col_perm)]
 
 
-def _gf_kernel(w_ref, data_ref, out_ref, *, rows: int, cols: int):
+def _gf_kernel(w_ref, data_ref, out_ref, *, rows: int):
     # widen to int32 for the bit extraction: Mosaic has no uint8 shift
     # (arith.shrui) or uint8 elementwise lowering; VPU lanes are 32-bit
     # anyway so the widening is layout-only
@@ -62,196 +78,112 @@ def _gf_kernel(w_ref, data_ref, out_ref, *, rows: int, cols: int):
     out_ref[:] = out.astype(jnp.uint8)
 
 
-def _gf_kernel_xorsched(data_ref, out_ref, *, sched, rows: int,
-                        cols: int):
-    """Schedule-driven twin of _gf_kernel (formulation="xorsched"): the
-    precomputed XOR schedule (ops/xor_schedule.py, greedy shared-pair
-    CSE) replaces the 8x int8 plane concat + MXU dot_general + mod-2
-    entirely — each scheduled XOR is ONE VPU op on a 0/1 plane row, and
-    the CSE'd count sits ~60% below the dense popcount bound. The matrix
-    never enters the kernel: the schedule IS the matrix, baked in as
-    straight-line code. int32 widening as in _gf_kernel (Mosaic has no
-    uint8 shift); on-chip the win over the bitplane kernel is the removed
-    expansion/accumulator traffic — chip-side GB/s lands at the next
-    TPU-host bench round (this container drives it interpret-mode only).
-    """
-    data = data_ref[:].astype(jnp.int32)  # [C, T]
-    vals = []
-    for c in range(cols):
-        row = data[c:c + 1, :]
-        for j in range(8):
-            vals.append((row >> j) & 1)
-    for a, b in sched.ops:
-        vals.append(vals[a] ^ vals[b])
-    zero = jnp.zeros_like(vals[0])
-    outs = []
-    for r in range(rows):
-        acc = zero
-        for i in range(8):
-            oid = sched.out_ids[r * 8 + i]
-            if oid is not None:
-                acc = acc | (vals[oid] << i)
-        outs.append(acc)
-    out_ref[:] = jnp.concatenate(outs, axis=0).astype(jnp.uint8)
-
-
-def _nibble_weights(rows: int) -> np.ndarray:
-    """[rows, 4*rows] int8 selector: out[r] = sum_i 2^i * planes[i*rows+r]
-    for 4 planes — the byte-repack as an MXU contraction (two of these
-    cover the 8 planes; 2^i stays <= 8, inside int8)."""
-    w2 = np.zeros((rows, 4 * rows), dtype=np.int8)
-    for i in range(4):
-        for r in range(rows):
-            w2[r, i * rows + r] = 1 << i
-    return w2
-
-
-def _gf_kernel_mxu_repack(w_ref, w2_ref, data_ref, out_ref, *, rows: int,
-                          cols: int):
-    """_gf_kernel with the 8-iteration VPU repack chain replaced by two
-    tiny nibble matmuls: the kernel self-diagnosed VPU-bound (bench round
-    3: 4.3% MXU, repack ~10 of ~18 VPU ops/byte), so the byte
-    reconstruction out[r] = sum_i 2^i * plane_i[r] — linear in the planes
-    — rides the idle MXU instead.
-
-    MEASURED (v5e, RS(10,4), 64M cols): 32.4 GB/s at tile 64K (the extra
-    VMEM temps OOM larger tiles) vs 35.4 GB/s for the VPU chain at 256K.
-    The [rows, 4*rows] contraction has M=4 output rows — ~3% occupancy of
-    the 128x128 systolic array — so the int8 cast + second VMEM pass cost
-    more than the VPU ops they replace. Structural conclusion: for small
-    m, no matmul formulation of the repack can win, and without an int4/
-    packed-plane MXU operand (not available via Mosaic on v5e) the
-    bitplane kernel's ~35 GB/s VPU bound stands; wider geometries already
-    scale past it (RS(20,4) measures 61-66 GB/s, 3x the 20 GB/s target).
-    Kept for A/B regression testing (bit-exact, tests cover it)."""
-    data = data_ref[:].astype(jnp.int32)  # [C, T]
-    planes = [((data >> j) & 1).astype(jnp.int8) for j in range(8)]
-    bits = jnp.concatenate(planes, axis=0)
-    acc = jax.lax.dot_general(
-        w_ref[:], bits,
-        dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.int32,
-    )  # [8*R, T] plane-major
-    lsb = (acc & 1).astype(jnp.int8)  # [8R, T] one op
-    w2 = w2_ref[:]
-    lo = jax.lax.dot_general(
-        w2, lsb[: 4 * rows, :],
-        dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.int32)
-    hi = jax.lax.dot_general(
-        w2, lsb[4 * rows:, :],
-        dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.int32)
-    out_ref[:] = (lo | (hi << 4)).astype(jnp.uint8)
-
-
-@functools.lru_cache(maxsize=128)
-def _build_apply(matrix_bytes: bytes, rows: int, cols: int, tile: int,
-                 interpret: bool, repack: str = "vpu",
-                 formulation: str = "bitplane"):
-    w = np.frombuffer(matrix_bytes, dtype=np.uint8).reshape(rows, cols)
-
-    if formulation == "xorsched":
-        from .xor_schedule import schedule_for_matrix
-        kernel = functools.partial(_gf_kernel_xorsched,
-                                   sched=schedule_for_matrix(w),
-                                   rows=rows, cols=cols)
-
-        @jax.jit
-        def apply_sched(data: jnp.ndarray) -> jnp.ndarray:
-            n = data.shape[1]
-            assert n % tile == 0, (n, tile)
-            return pl.pallas_call(
-                kernel,
-                out_shape=jax.ShapeDtypeStruct((rows, n), jnp.uint8),
-                grid=(n // tile,),
-                in_specs=[
-                    pl.BlockSpec((cols, tile), lambda i: (0, i),
-                                 memory_space=pltpu.VMEM),
-                ],
-                out_specs=pl.BlockSpec((rows, tile), lambda i: (0, i),
-                                       memory_space=pltpu.VMEM),
-                interpret=interpret,
-            )(data)
-
-        return apply_sched
-
-    wp = jnp.asarray(_plane_major_matrix(w))  # [8R, 8C] int8
-
-    if repack == "mxu":
-        kernel = functools.partial(_gf_kernel_mxu_repack, rows=rows,
-                                   cols=cols)
-        w2 = jnp.asarray(_nibble_weights(rows))
-        extra_specs = [pl.BlockSpec((rows, 4 * rows), lambda i: (0, 0),
-                                    memory_space=pltpu.VMEM)]
-        extra_args = (w2,)
-    else:
-        kernel = functools.partial(_gf_kernel, rows=rows, cols=cols)
-        extra_specs = []
-        extra_args = ()
+@functools.lru_cache(maxsize=None)
+def _build_apply(rows: int, cols: int, tile: int, interpret: bool,
+                 vmem_limit_bytes: int):
+    """jit fn (w [8R, 8C] int8, data [C, n] uint8) -> [R, n] uint8, n a
+    multiple of tile."""
+    kernel = functools.partial(_gf_kernel, rows=rows)
 
     @jax.jit
-    def apply_fn(data: jnp.ndarray) -> jnp.ndarray:
+    def apply_fn(w: jnp.ndarray, data: jnp.ndarray) -> jnp.ndarray:
         n = data.shape[1]
         assert n % tile == 0, (n, tile)
-        grid = (n // tile,)
         return pl.pallas_call(
             kernel,
             out_shape=jax.ShapeDtypeStruct((rows, n), jnp.uint8),
-            grid=grid,
+            grid=(n // tile,),
             in_specs=[
                 pl.BlockSpec((8 * rows, 8 * cols), lambda i: (0, 0),
                              memory_space=pltpu.VMEM),
-                *extra_specs,
                 pl.BlockSpec((cols, tile), lambda i: (0, i),
                              memory_space=pltpu.VMEM),
             ],
             out_specs=pl.BlockSpec((rows, tile), lambda i: (0, i),
                                    memory_space=pltpu.VMEM),
+            compiler_params=pltpu.CompilerParams(
+                vmem_limit_bytes=vmem_limit_bytes),
             interpret=interpret,
-        )(wp, *extra_args, data)
+            name="gf_apply",
+        )(w, data)
 
     return apply_fn
 
 
-def gf_apply_pallas(matrix: np.ndarray, tile: int = DEFAULT_TILE,
-                    interpret: bool | None = None, repack: str = "vpu",
-                    formulation: str = "bitplane"):
-    """Return fn: data [C, n] uint8 -> [R, n] uint8; n padded to tile inside.
-
-    repack: "vpu" (8-iteration or/shift chain) or "mxu" (two nibble
-    matmuls — see _gf_kernel_mxu_repack); formulation: "bitplane" (the
-    expand/dot/repack kernel) or "xorsched" (the CSE'd XOR-schedule
-    kernel, _gf_kernel_xorsched — repack is moot there)."""
+def _bind(matrix: np.ndarray, tile: int, interpret: bool,
+          vmem_limit_bytes: int):
+    """(kernel fn over whole-tile widths with `matrix` bound, cols)."""
     matrix = np.asarray(matrix, dtype=np.uint8)
     rows, cols = matrix.shape
-    if interpret is None:
-        interpret = jax.default_backend() not in ("tpu",)
-    if interpret:
-        # the interpreter pads every call to the tile width; big TPU tiles
-        # would turn small test inputs into quarter-million-column runs
-        tile = min(tile, 16384)
-    raw = _build_apply(matrix.tobytes(), rows, cols, tile, interpret,
-                       repack, formulation)
+    raw = _build_apply(rows, cols, tile, interpret, vmem_limit_bytes)
+    w = jnp.asarray(_plane_major_matrix(matrix))  # [8R, 8C] int8
+    return (lambda data: raw(w, data)), cols
+
+
+def gf_apply_pallas(matrix: np.ndarray, tile: int = TILE,
+                    interpret: bool = False,
+                    vmem_limit_bytes: int = VMEM_LIMIT_BYTES):
+    """Return fn: data [C, n] uint8 -> [R, n] uint8 ON THE DEVICE; n is
+    padded to whole tiles and sliced back inside (two small XLA programs
+    per distinct n beside the kernel's one — right for a pipeline that
+    dispatches a few batch shapes and keeps results in flight).
+
+    interpret is the caller's decision (tests pass True to run on the CPU
+    mesh); nothing here infers it from the backend, so a process that
+    lost its TPU fails in the compiler instead of interpreting."""
+    kernel, _ = _bind(matrix, tile, interpret, vmem_limit_bytes)
 
     def apply_fn(data: jnp.ndarray) -> jnp.ndarray:
         n = data.shape[1]
         pad = (-n) % tile
         if pad:
             data = jnp.pad(data, ((0, 0), (0, pad)))
-        out = raw(data)
+        out = kernel(data)
         return out[:, :n] if pad else out
 
     return apply_fn
 
 
+# below this many tiles a host-side call runs at the next power of two
+_BUCKET_TILES = 64
+
+
+def gf_apply_pallas_host(matrix: np.ndarray, tile: int = TILE,
+                         interpret: bool = False,
+                         vmem_limit_bytes: int = VMEM_LIMIT_BYTES):
+    """Return fn: numpy [C, n] -> numpy [R, n], for callers that block on
+    the answer anyway (a degraded read's interval, a synchronous encode).
+
+    Pads and slices on the HOST, so the kernel is the only device program,
+    and widths under 64 tiles round up to a power of two of tiles: reads
+    of any size up to 1 MiB share seven executables instead of compiling
+    one per size inside the GET (chip run of PR 21: ~1 s per degraded GET
+    before, each new interval length a fresh compile)."""
+    kernel, cols = _bind(matrix, tile, interpret, vmem_limit_bytes)
+
+    def apply_fn(data: np.ndarray) -> np.ndarray:
+        n = data.shape[1]
+        tiles = -(-n // tile)
+        if tiles < _BUCKET_TILES:
+            tiles = 1 << (tiles - 1).bit_length()
+        if tiles * tile != n:
+            padded = np.zeros((cols, tiles * tile), dtype=np.uint8)
+            padded[:, :n] = data
+            data = padded
+        return np.asarray(kernel(data))[:, :n]
+
+    return apply_fn
+
+
 @functools.lru_cache(maxsize=64)
-def _encode_fn(data_shards: int, parity_shards: int, tile: int):
+def _encode_fn(data_shards: int, parity_shards: int, tile: int,
+               interpret: bool):
     pm = gf256.parity_matrix(data_shards, parity_shards)
-    return gf_apply_pallas(pm, tile=tile)
+    return gf_apply_pallas(pm, tile=tile, interpret=interpret)
 
 
 def encode_parity(data: jnp.ndarray, parity_shards: int,
-                  tile: int = DEFAULT_TILE) -> jnp.ndarray:
+                  tile: int = TILE, interpret: bool = False) -> jnp.ndarray:
     """data [k, n] uint8 -> parity [m, n] uint8 via the fused TPU kernel."""
-    return _encode_fn(int(data.shape[0]), parity_shards, tile)(data)
+    return _encode_fn(int(data.shape[0]), parity_shards, tile,
+                      interpret)(data)

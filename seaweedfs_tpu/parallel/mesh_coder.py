@@ -15,11 +15,13 @@ B-byte stripe batch):
   runs the same GF kernel on its B/n column slice. No collectives — the
   property the MULTICHIP dryruns verify — so aggregate throughput is
   n * per-chip throughput on ICI-attached chips.
-- rebuild: survivor rows land row-sharded P("batch", None) (the natural
-  layout when shards stream in per-chip), are all_gather'd over ICI so
-  every chip holds all k survivor rows, and each chip reconstructs the
-  missing rows for its own column slice — the ICI analog of the
-  reference's parallel shard fetch (weed/storage/store_ec.go:322-376).
+- rebuild: the same shape with the reconstruction matrix — the host feed
+  already holds all k survivor rows of a batch, so they column-shard
+  exactly like encode input and each chip reconstructs the missing rows
+  of its own column slice, collective-free. (Until PR 21 survivors went
+  up row-sharded and were all_gather'd over ICI; on four real v5e chips
+  XLA did not finish compiling that uint8 all_gather of a [12, 4 MiB]
+  batch in 90 s, and ec.rebuild timed out.)
 
 Batch widths not divisible by the mesh size zero-pad to the next multiple
 (GF parity of zero columns is zero, so padding never changes real bytes;
@@ -53,7 +55,6 @@ from .. import observe
 from ..ec.coder import JaxCoder
 from ..ops import gf256, rs_jax
 from ..utils import metrics as metrics_mod
-from ..utils.jax_compat import shard_map_compat
 
 
 def mesh_device_count() -> int:
@@ -125,11 +126,14 @@ class MeshCoder(JaxCoder):
     sinks, reconstruct) works here, mesh-sharded where it counts."""
 
     _VALID_METHODS = frozenset(rs_jax.FORMULATIONS) | {"pallas"}
+    _TPU_METHODS = JaxCoder._TPU_METHODS | {"pallas"}
 
     def __init__(self, data_shards: int, parity_shards: int,
                  n_devices: Optional[int] = None,
-                 method: Optional[str] = None):
+                 method: Optional[str] = None, interpret: bool = False):
         method = method or rs_jax.formulation_env() or "bitplane"
+        # method="pallas" in Pallas interpret mode: the CPU test mesh only
+        self._interpret = interpret
         if method not in self._VALID_METHODS:
             raise ValueError(f"unknown mesh coder method {method!r}")
         # always pass the resolved method down: a mesh coder's sharded
@@ -152,6 +156,9 @@ class MeshCoder(JaxCoder):
         self._rec_sharded: dict = {}
         self._lock = threading.Lock()
         metrics_mod.shared("ec").gauge("feed_mesh_devices", n)
+
+    def describe(self) -> dict:
+        return {**super().describe(), "mesh_devices": self.mesh_devices}
 
     # --- staging: per-chip sub-batches ---
 
@@ -205,7 +212,8 @@ class MeshCoder(JaxCoder):
         _apply_fn shape), bitplane/lut ride the rs_jax formulations."""
         if self.method == "pallas":
             from ..ops import rs_pallas
-            return rs_pallas.gf_apply_pallas(matrix)
+            return rs_pallas.gf_apply_pallas(matrix,
+                                             interpret=self._interpret)
         if self.method == "bitplane":
             return rs_jax.gf_apply_bitplane(matrix)
         if self.method == "xorsched":
@@ -230,17 +238,38 @@ class MeshCoder(JaxCoder):
                 self.k, self.m, tuple(present), tuple(missing)))
         return super()._rec_apply(present, missing)
 
+    def _rec_apply_sync(self, present, missing):
+        # a degraded read's interval: one chip, host-side pad/slice and
+        # bucketed widths, exactly as PallasCoder does it
+        if self.method != "pallas":
+            return super()._rec_apply_sync(present, missing)
+        key = ("sync", present, missing)
+        with self._lock:
+            fn = self._rec_sharded.get(key)
+            if fn is None:
+                from ..ops import rs_pallas
+                fn = self._rec_sharded[key] = rs_pallas.gf_apply_pallas_host(
+                    gf256.reconstruction_matrix(self.k, self.m, present,
+                                                missing),
+                    interpret=self._interpret)
+            return fn
+
+    def _sharded(self, matrix: np.ndarray):
+        """jit(shard_map) of the per-chip kernel for `matrix`, columns
+        sharded in and out — the one program shape encode and rebuild
+        share. check_vma off: pallas_call outputs carry no vma metadata."""
+        import jax
+        from jax.sharding import PartitionSpec as P
+        return jax.jit(jax.shard_map(
+            self._apply_matrix_fn(matrix), mesh=self.mesh,
+            in_specs=P(None, "batch"), out_specs=P(None, "batch"),
+            check_vma=False))
+
     def _enc_fn(self):
         with self._lock:
             if self._enc_sharded is None:
-                import jax
-                from jax.sharding import PartitionSpec as P
-                apply_fn = self._apply_matrix_fn(
+                self._enc_sharded = self._sharded(
                     gf256.parity_matrix(self.k, self.m))
-                step = shard_map_compat(apply_fn, self.mesh,
-                                        P(None, "batch"),
-                                        P(None, "batch"))
-                self._enc_sharded = jax.jit(step)
             return self._enc_sharded
 
     def encode_async(self, data: np.ndarray):
@@ -274,51 +303,17 @@ class MeshCoder(JaxCoder):
                        ("all-reduce", "all-gather", "collective-permute",
                         "all-to-all"))
 
-    # --- rebuild: row-sharded survivors, all_gather over ICI ---
+    # --- rebuild: column-sharded survivors, collective-free ---
 
     def _rec_fn(self, present: tuple, missing: tuple):
         key = (present, missing)
         with self._lock:
             fn = self._rec_sharded.get(key)
             if fn is None:
-                import jax
-                import jax.numpy as jnp
-                from jax.sharding import PartitionSpec as P
-                rec = gf256.reconstruction_matrix(self.k, self.m, present,
-                                                  missing)
-                apply_fn = self._apply_matrix_fn(rec)
-                n_dev = self.mesh_devices
-                k = self.k
-
-                def step(survivors):  # [k_pad/n, B] rows on each chip
-                    full = jax.lax.all_gather(survivors, "batch", axis=0,
-                                              tiled=True)[:k]
-                    cols = full.shape[1] // n_dev
-                    idx = jax.lax.axis_index("batch")
-                    local = jax.lax.dynamic_slice(
-                        full, (0, idx * cols), (k, cols))
-                    return apply_fn(local)
-
-                fn = jax.jit(shard_map_compat(
-                    step, self.mesh, P("batch", None), P(None, "batch")))
-                self._rec_sharded[key] = fn
+                fn = self._rec_sharded[key] = self._sharded(
+                    gf256.reconstruction_matrix(self.k, self.m, present,
+                                                missing))
             return fn
-
-    def _stage_rows(self, arr: np.ndarray):
-        """Row-shard [k_pad, B] survivors over the mesh (pad rows to a
-        mesh multiple; the all_gather drops the pad)."""
-        import jax
-        from jax.sharding import NamedSharding, PartitionSpec as P
-        n = self.mesh_devices
-        pad = (-arr.shape[0]) % n
-        if pad:
-            arr = np.pad(arr, ((0, pad), (0, 0)))
-        rows = arr.shape[0] // n
-        shards = [jax.device_put(
-            np.ascontiguousarray(arr[i * rows:(i + 1) * rows]), dev)
-            for i, dev in enumerate(self._devices)]
-        return jax.make_array_from_single_device_arrays(
-            arr.shape, NamedSharding(self.mesh, P("batch", None)), shards)
 
     def rec_apply_async(self, present, missing):
         present, missing = tuple(present), tuple(missing)
@@ -327,7 +322,7 @@ class MeshCoder(JaxCoder):
         def run(survivors: np.ndarray):
             width = int(survivors.shape[1])
             arr = self._pad_cols(np.asarray(survivors, dtype=np.uint8))
-            return _MeshHandle(fn(self._stage_rows(arr)), width)
+            return _MeshHandle(fn(self._stage_cols(arr)), width)
 
         return run
 
@@ -336,9 +331,9 @@ class MeshCoder(JaxCoder):
     # batches arrive column-sharded from stage_async and GSPMD partitions
     # the dynamic-matrix digest program along the batch axis (the final
     # [m] digest sum is the only cross-chip reduction, 4*m bytes). AOT
-    # warming is a tunneled-link optimization whose unsharded abstract
-    # shapes would compile a single-device program the sharded call
-    # could not reuse — on a mesh the compile happens at first dispatch.
+    # warming from unsharded abstract shapes would compile a single-device
+    # program the sharded call could not reuse — on a mesh the compile
+    # happens at first dispatch.
 
     def _dyn_window_builder(self):
         # mesh staging is per-chip BYTE column slices (the packed

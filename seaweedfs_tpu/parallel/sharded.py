@@ -32,7 +32,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..ops import gf256, rs_jax, rs_pallas
-from ..utils.jax_compat import shard_map_compat
 
 
 def make_mesh(n_devices: int | None = None,
@@ -44,9 +43,9 @@ def make_mesh(n_devices: int | None = None,
     return Mesh(np.array(devs[:n]), (axis_name,))
 
 
-def _apply_fn(matrix: np.ndarray, use_pallas: bool):
+def _apply_fn(matrix: np.ndarray, use_pallas: bool, interpret: bool):
     if use_pallas:
-        return rs_pallas.gf_apply_pallas(matrix)
+        return rs_pallas.gf_apply_pallas(matrix, interpret=interpret)
     return rs_jax.gf_apply_bitplane(matrix)
 
 
@@ -57,9 +56,10 @@ def _apply_fn(matrix: np.ndarray, use_pallas: bool):
 # entries' meshes alive forever.)
 
 @functools.lru_cache(maxsize=32)
-def _sharded_encode_fn(k: int, m: int, mesh: Mesh, use_pallas: bool):
+def _sharded_encode_fn(k: int, m: int, mesh: Mesh, use_pallas: bool,
+                       interpret: bool = False):
     pm = gf256.parity_matrix(k, m)
-    apply_fn = _apply_fn(pm, use_pallas)
+    apply_fn = _apply_fn(pm, use_pallas, interpret)
 
     def step(data):  # [b_local, k, n] uint8 per device
         b, kk, n = data.shape
@@ -68,23 +68,29 @@ def _sharded_encode_fn(k: int, m: int, mesh: Mesh, use_pallas: bool):
         parity = apply_fn(flat)
         return jnp.transpose(parity.reshape(-1, b, n), (1, 0, 2))
 
-    shard_step = shard_map_compat(step, mesh, P("batch", None, None),
-                                  P("batch", None, None))
+    # check_vma off: pallas_call outputs carry no vma metadata
+    shard_step = jax.shard_map(step, mesh=mesh,
+                               in_specs=P("batch", None, None),
+                               out_specs=P("batch", None, None),
+                               check_vma=False)
     return jax.jit(shard_step)
 
 
 def sharded_encode(mesh: Mesh, data, parity_shards: int = 4,
-                   use_pallas: bool | None = None):
+                   use_pallas: bool | None = None,
+                   interpret: bool = False):
     """data [B, k, n] uint8 (B divisible by mesh size) -> parity [B, m, n].
 
     B is sharded over the mesh "batch" axis; each chip runs the fused kernel
-    on its local rows.
+    on its local rows. use_pallas defaults to the platform (the Pallas
+    kernel on a TPU, the XLA program elsewhere); interpret=True runs the
+    Pallas kernel on the CPU test mesh.
     """
     if use_pallas is None:
         use_pallas = jax.default_backend() == "tpu"
     b, k, n = data.shape
     assert b % mesh.devices.size == 0, (b, mesh.devices.size)
-    fn = _sharded_encode_fn(k, parity_shards, mesh, use_pallas)
+    fn = _sharded_encode_fn(k, parity_shards, mesh, use_pallas, interpret)
     spec = NamedSharding(mesh, P("batch", None, None))
     data = jax.device_put(data, spec)
     return fn(data)
@@ -93,10 +99,10 @@ def sharded_encode(mesh: Mesh, data, parity_shards: int = 4,
 @functools.lru_cache(maxsize=32)
 def _sharded_rebuild_fn(k: int, m: int, present: tuple[int, ...],
                         missing: tuple[int, ...], mesh: Mesh,
-                        use_pallas: bool):
+                        use_pallas: bool, interpret: bool = False):
     """Survivor shards sharded over chips; all_gather + GF matmul rebuild."""
     rec = gf256.reconstruction_matrix(k, m, present, missing)
-    apply_fn = _apply_fn(rec, use_pallas)
+    apply_fn = _apply_fn(rec, use_pallas, interpret)
     n_dev = mesh.devices.size
 
     def step(survivors):  # [k_padded, n] rows sharded over "batch"
@@ -110,13 +116,14 @@ def _sharded_rebuild_fn(k: int, m: int, present: tuple[int, ...],
         local = jax.lax.dynamic_slice(full, (0, idx * cols), (k, cols))
         return apply_fn(local)
 
-    shard_step = shard_map_compat(step, mesh, P("batch", None),
-                                  P(None, "batch"))
+    shard_step = jax.shard_map(step, mesh=mesh, in_specs=P("batch", None),
+                               out_specs=P(None, "batch"), check_vma=False)
     return jax.jit(shard_step)
 
 
 def sharded_rebuild(mesh: Mesh, shards: list, k: int, m: int,
-                    use_pallas: bool | None = None):
+                    use_pallas: bool | None = None,
+                    interpret: bool = False):
     """Rebuild missing shards with survivors distributed across the mesh.
 
     shards: length k+m list with None for missing. Survivor rows are laid out
@@ -138,7 +145,8 @@ def sharded_rebuild(mesh: Mesh, shards: list, k: int, m: int,
     pad_cols = (-n) % n_dev  # each chip rebuilds an equal column slice
     if pad_rows or pad_cols:
         survivors = np.pad(survivors, ((0, pad_rows), (0, pad_cols)))
-    fn = _sharded_rebuild_fn(k, m, basis, missing, mesh, use_pallas)
+    fn = _sharded_rebuild_fn(k, m, basis, missing, mesh, use_pallas,
+                             interpret)
     spec = NamedSharding(mesh, P("batch", None))
     out = fn(jax.device_put(jnp.asarray(survivors), spec))
     result = list(shards)

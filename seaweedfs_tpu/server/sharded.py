@@ -489,13 +489,19 @@ def run_sharded(ctx: ShardContext,
         if pid == 0:
             ctx.index = i
             ctx.child_pids = []
+            code = 0
             try:
                 child_main(ctx)
             except KeyboardInterrupt:
                 pass
+            except BaseException:
+                # os._exit below skips the interpreter's own traceback:
+                # a shard that cannot boot must say why
+                log.exception("shard %d died", i)
+                code = 1
             finally:
                 ctx.mark_dead()
-                os._exit(0)
+                os._exit(code)
         pids.append(pid)
     ctx.index = 0
     ctx.child_pids = pids

@@ -1729,14 +1729,18 @@ class VolumeServer:
 
     async def admin_ec_mesh_status(self,
                                    request: web.Request) -> web.Response:
-        """This process's device-mesh view: configured WEED_EC_MESH_
-        DEVICES, live devices, and the per-chip staging counters +
-        governor gauges from the shared "ec" registry (the JSON twin of
-        what /metrics exposes, for the ec.mesh.status shell command)."""
+        """This process's EC device view: which coder the store's name
+        resolved to and on which device (`coder`, empty until a coder
+        exists — asking initialises nothing), the configured
+        WEED_EC_MESH_DEVICES, live devices, and the per-chip staging
+        counters + governor gauges from the shared "ec" registry (the
+        JSON twin of what /metrics exposes, for the ec.mesh.status shell
+        command)."""
         from ..parallel.mesh_coder import mesh_status
-        return web.json_response(
-            await asyncio.get_event_loop().run_in_executor(
-                None, mesh_status))
+        out = await asyncio.get_event_loop().run_in_executor(
+            None, mesh_status)
+        out["coder"] = self.store.coder_status()
+        return web.json_response(out)
 
     async def admin_ec_scrub(self, request: web.Request) -> web.Response:
         """Run one scrub pass now (operators / chaos tests)."""

@@ -181,6 +181,15 @@ class Store:
             c = self._coders[key] = self._maybe_mesh(c, g)
         return c
 
+    def coder_status(self) -> dict:
+        """The configured coder name and what it resolved to, per
+        geometry, for the boot log and /admin/ec/mesh_status. Reads only
+        coders that already exist: asking never initialises a JAX
+        backend, so `resolved` is empty on a server that has not yet
+        encoded, rebuilt or mounted an EC volume."""
+        return {"name": self.coder_name,
+                "resolved": [c.describe() for c in self._coders.values()]}
+
     def _maybe_mesh(self, c: ErasureCoder,
                     g: ec_mod.Geometry) -> ErasureCoder:
         """WEED_EC_MESH_DEVICES >= 2 lifts auto-selected device coders

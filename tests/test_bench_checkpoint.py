@@ -68,11 +68,6 @@ def test_main_checkpoints_every_phase(monkeypatch, tmp_path):
     monkeypatch.setattr(bench, "phase_saturation",
                         lambda w, **k: {"host_cores": 1, "shards": 2})
     monkeypatch.setattr(bench, "HARD_BUDGET_S", 10_000.0)
-    # main() imports ec.pipeline for parent-side shard gen: stub the
-    # real module attribute (patching sys.modules is not enough once the
-    # package attribute is already bound by an earlier import)
-    import seaweedfs_tpu.ec.pipeline as _pl
-    monkeypatch.setattr(_pl, "stream_encode", lambda *a, **k: None)
     bench.main()
 
     # the kernel phase saw encode's checkpoint; rebuild saw kernel's

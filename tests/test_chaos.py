@@ -188,7 +188,7 @@ def _kill_volume_server(c, vs) -> None:
 # --- (c) filer dies mid-autochunk; fsck finds no surviving orphans ---
 
 def _spawn(args, cwd, log_name):
-    env = dict(os.environ, SEAWEEDFS_FORCE_CPU="1")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     env["PYTHONPATH"] = ":".join(
         p for p in (env.get("PYTHONPATH", ""), _REPO_ROOT) if p)
     log = open(os.path.join(cwd, f"{log_name}.log"), "ab")

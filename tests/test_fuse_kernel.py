@@ -106,7 +106,7 @@ def _require_functional_fuse(tmp_path):
 def _probe_fuse(tmp_path) -> bool:
     mnt = tmp_path / "fuse_probe"
     mnt.mkdir()
-    env = dict(os.environ, SEAWEEDFS_FORCE_CPU="1", JAX_PLATFORMS="cpu")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     env["PYTHONPATH"] = ":".join(
         p for p in (env.get("PYTHONPATH", ""), _REPO_ROOT) if p)
     proc = subprocess.Popen(
@@ -144,8 +144,7 @@ def test_kernel_mount_end_to_end(tmp_path):
     try:
         filer = c.add_filer(chunk_size=64 * 1024)
         time.sleep(0.3)
-        env = dict(os.environ)
-        env["SEAWEEDFS_FORCE_CPU"] = "1"
+        env = dict(os.environ, JAX_PLATFORMS="cpu")
         env["PYTHONPATH"] = ":".join(
             p for p in (env.get("PYTHONPATH", ""), _REPO_ROOT) if p)
         proc = subprocess.Popen(

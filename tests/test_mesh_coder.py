@@ -65,9 +65,9 @@ def test_mesh_encode_matches_single_chip(mesh8, width):
     assert np.array_equal(got, gf256.encode_parity(data, 4))
 
 
-def test_mesh_rebuild_all_gather_matches(mesh8):
-    """Row-sharded survivors all_gather over the mesh and reconstruct
-    the exact missing rows (odd width -> padded column slices too)."""
+def test_mesh_rebuild_matches(mesh8):
+    """Column-sharded survivors reconstruct the exact missing rows on
+    each chip's slice (odd width -> padded column slices too)."""
     rng = np.random.default_rng(21)
     data = rng.integers(0, 256, (10, 4999), dtype=np.uint8)
     parity = gf256.encode_parity(data, 4)
@@ -83,9 +83,9 @@ def test_mesh_rebuild_all_gather_matches(mesh8):
 
 def test_mesh_pallas_method_matches_single_chip():
     """method='pallas' keeps the hand-tiled kernel inside the shard_map
-    step (interpret mode on CPU) — the path a TPU host's auto coder
-    lifts onto — and stays byte-identical."""
-    mc = MeshCoder(10, 4, n_devices=8, method="pallas")
+    step (interpret mode, asked for here) — the path a TPU host's auto
+    coder lifts onto — and stays byte-identical."""
+    mc = MeshCoder(10, 4, n_devices=8, method="pallas", interpret=True)
     rng = np.random.default_rng(33)
     data = rng.integers(0, 256, (10, 512), dtype=np.uint8)
     assert np.array_equal(mc.encode(data), gf256.encode_parity(data, 4))

@@ -278,8 +278,7 @@ def test_broker_sigkill_ack_durability_contract(cluster, tmp_path):
     import seaweedfs_tpu
     pkg_root = os_mod.path.dirname(
         os_mod.path.dirname(seaweedfs_tpu.__file__))
-    env = dict(os_mod.environ, JAX_PLATFORMS="cpu",
-               SEAWEEDFS_FORCE_CPU="1")
+    env = dict(os_mod.environ, JAX_PLATFORMS="cpu")
     env["PYTHONPATH"] = pkg_root + os_mod.pathsep + env.get(
         "PYTHONPATH", "")
     proc = subprocess.Popen(

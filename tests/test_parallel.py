@@ -26,7 +26,8 @@ def test_sharded_encode_matches_single(mesh):
 def test_sharded_encode_pallas_interpret(mesh):
     rng = np.random.default_rng(31)
     data = rng.integers(0, 256, (8, 10, 512), dtype=np.uint8)
-    parity = np.asarray(sharded.sharded_encode(mesh, data, use_pallas=True))
+    parity = np.asarray(sharded.sharded_encode(mesh, data, use_pallas=True,
+                                               interpret=True))
     for b in range(8):
         assert np.array_equal(parity[b], gf256.encode_parity(data[b], 4)), b
 

@@ -473,8 +473,7 @@ def test_shell_repl_smoke(cluster, s3):
     """The interactive REPL accepts piped commands and emits JSON lines."""
     import subprocess
     import sys
-    env = dict(__import__("os").environ)
-    env["SEAWEEDFS_FORCE_CPU"] = "1"
+    env = dict(__import__("os").environ, JAX_PLATFORMS="cpu")
     repo = __import__("os").path.dirname(
         __import__("os").path.dirname(__import__("os").path.abspath(
             __file__)))

@@ -111,13 +111,20 @@ def test_mixed_tier_layout_consistency(tmp_path, dat_blocks):
     assert open(base + ".dat", "rb").read() == dat
 
 
+def _coder(name: str):
+    if name == "pallas":  # interpret mode is the test's explicit request
+        from seaweedfs_tpu.ec.coder import PallasCoder
+        return PallasCoder(10, 4, interpret=True)
+    return ec.get_coder(name, 10, 4)
+
+
 @pytest.mark.parametrize("coder_name", ["numpy", "jax", "pallas"])
 def test_device_sink_digest_matches_shard_files(tmp_path, coder_name):
     # the on-device parity sink (bench mode) must be the same computation
     # as the file-writing path: its [m] uint32 wrapping byte-sum digest has
     # to equal the sums over the parity shard files stream_encode writes
     build_volume(tmp_path)
-    coder = ec.get_coder(coder_name, 10, 4)
+    coder = _coder(coder_name)
     base = os.path.join(str(tmp_path), "1")
     pipeline.stream_encode(base, coder, GEO, batch_size=4096)
     want = pipeline.parity_file_digest(base, GEO)
@@ -135,7 +142,7 @@ def test_device_sink_windowed_schedule(tmp_path, coder_name):
     # a window smaller than the volume forces multiple window dispatches;
     # the chained digest must still equal the shard-file ground truth
     build_volume(tmp_path)
-    coder = ec.get_coder(coder_name, 10, 4)
+    coder = _coder(coder_name)
     base = os.path.join(str(tmp_path), "1")
     pipeline.stream_encode(base, coder, GEO, batch_size=4096)
     want = pipeline.parity_file_digest(base, GEO)
@@ -154,7 +161,7 @@ def test_rebuild_device_sink_digest(tmp_path, coder_name):
     # the reconstruction digest sink must reproduce the byte sums of the
     # real shard files for the victim ids WITHOUT writing anything
     build_volume(tmp_path)
-    coder = ec.get_coder(coder_name, 10, 4)
+    coder = _coder(coder_name)
     base = os.path.join(str(tmp_path), "1")
     pipeline.stream_encode(base, coder, GEO, batch_size=4096)
     victims = [0, 3, 7, 12]

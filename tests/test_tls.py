@@ -57,7 +57,7 @@ verify_client = true
 https = true
 """)
     port = free_port_with_grpc_twin()
-    env = dict(os.environ, JAX_PLATFORMS="cpu", SEAWEEDFS_FORCE_CPU="1")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     env["PYTHONPATH"] = (os.path.dirname(os.path.dirname(__file__))
                          + os.pathsep + env.get("PYTHONPATH", ""))
     proc = subprocess.Popen(
