@@ -55,7 +55,9 @@ import socket
 import subprocess
 import sys
 import tempfile
+import threading
 import time
+import urllib.error
 import urllib.request
 from importlib import metadata
 
@@ -293,6 +295,42 @@ def check_reads(client: Client, seed: int, blobs: dict, sample: list,
     return times
 
 
+def device_view(cluster: "Cluster", client: Client, fids: list,
+                require_tpu: bool, seconds: float = 5.0) -> dict:
+    """What an operator sees of a running `cli volume`: a device trace
+    window (`GET /debug/xprof`) while degraded GETs are served. On the
+    chip the answer has to show the kernel and the GETs' stages; on a
+    host coder the endpoint says 501 and that is recorded."""
+    stop = threading.Event()
+
+    def reads() -> None:
+        while not stop.is_set():
+            for fid in fids:
+                client.download(fid)
+                if stop.is_set():
+                    return
+
+    reader = threading.Thread(target=reads, daemon=True)
+    reader.start()
+    try:
+        view = http_json(f"http://{cluster.volume}/debug/xprof"
+                         f"?seconds={seconds}", timeout=seconds + 120)
+    except urllib.error.HTTPError as e:
+        if require_tpu or e.code != 501:
+            raise SystemExit(f"/debug/xprof answered {e.code}: "
+                             f"{e.read()[:300]!r}")
+        return {"status": 501}
+    finally:
+        stop.set()
+        reader.join(60)
+    if (view["device_busy_s"] <= 0
+            or not any("gf_apply" in op for op in view["device_ops"])
+            or not view["stages"].get("ec.get.d2h_wait")):
+        raise SystemExit(f"/debug/xprof saw no degraded read on the "
+                         f"device: {view}")
+    return view
+
+
 def shard_hashes(base: str) -> list[str]:
     return [sha_file(f"{base}.ec{sid:02d}") for sid in range(TOTAL_SHARDS)]
 
@@ -451,6 +489,9 @@ def main() -> None:
                 sorted(rec_times)[len(rec_times) // 2] * 1e3, 2),
             "get_s_max": round(max(rec_times), 3)}
         log(f"degraded reads: {info['degraded']}")
+        info["xprof"] = device_view(cluster, client, cold_sample,
+                                    require_tpu)
+        log(f"/debug/xprof while degraded GETs ran: {info['xprof']}")
 
         deadline = time.time() + 60
         while True:  # ec.rebuild plans from the master's view of the loss

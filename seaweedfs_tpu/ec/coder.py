@@ -23,11 +23,14 @@ when the backend really is the CPU (get_coder).
 
 from __future__ import annotations
 
+import contextlib
+import functools
 import os
 from typing import Callable, Optional, Sequence
 
 import numpy as np
 
+from .. import observe
 from ..ops import gf256, rs_jax
 
 # fn(survivors [k, n] uint8) -> rebuilt rows [len(missing), n] uint8
@@ -64,10 +67,12 @@ class ErasureCoder:
         """Like _rec_apply but the returned fn may defer computation."""
         return self._rec_apply(present, missing)
 
-    def _rec_apply_sync(self, present: tuple, missing: tuple) -> ApplyFn:
+    def _rec_apply_sync(self, present: tuple, missing: tuple,
+                        stage: str = "") -> ApplyFn:
         """Like _rec_apply for reconstruct(), whose caller blocks on the
         answer (a degraded read): backends may trade the in-flight result
-        for fewer device programs."""
+        for fewer device programs, and time their steps as observe
+        stages under the caller's `stage` prefix, if it gives one."""
         return self._rec_apply(present, missing)
 
     def materialize(self, handle) -> np.ndarray:
@@ -134,7 +139,8 @@ class ErasureCoder:
 
     def reconstruct(self, shards: Sequence[Optional[np.ndarray]],
                     data_only: bool = False,
-                    targets: Optional[Sequence[int]] = None
+                    targets: Optional[Sequence[int]] = None,
+                    stage: str = ""
                     ) -> list[Optional[np.ndarray]]:
         """Fill missing (None) entries from any k survivors.
 
@@ -142,6 +148,11 @@ class ErasureCoder:
         rebuilds every absent shard (all of them, or data shards only with
         data_only=True) — matching the reference coder's
         Reconstruct/ReconstructData split.
+
+        stage: a caller that names its steps (a degraded read passes
+        "ec.get") has the host copies before the device timed as the
+        observe stage `<stage>.stack_pad` and, where the backend's
+        synchronous apply has them, its dispatch and its wait.
         """
         total = self.k + self.m
         assert len(shards) == total
@@ -156,10 +167,12 @@ class ErasureCoder:
             return list(shards)
         if len(present) < self.k:
             raise ValueError("too few shards to reconstruct")
-        survivors = np.stack([np.asarray(shards[i], dtype=np.uint8)
-                              for i in present[:self.k]])
+        with (observe.stage(stage + ".stack_pad") if stage
+              else contextlib.nullcontext()):
+            survivors = np.stack([np.asarray(shards[i], dtype=np.uint8)
+                                  for i in present[:self.k]])
         rebuilt = np.asarray(
-            self._rec_apply_sync(present[:self.k], missing)(survivors))
+            self._rec_apply_sync(present[:self.k], missing, stage)(survivors))
         out = list(shards)
         for row, tgt in enumerate(missing):
             out[tgt] = rebuilt[row]
@@ -696,8 +709,9 @@ class PallasCoder(ErasureCoder):
                                             missing), host=host)
         return fn
 
-    def _rec_apply_sync(self, present, missing):
-        return self._rec_apply(present, missing, host=True)
+    def _rec_apply_sync(self, present, missing, stage=""):
+        fn = self._rec_apply(present, missing, host=True)
+        return functools.partial(fn, stage=stage) if stage else fn
 
     def encode_async(self, data: np.ndarray):
         return self._encode(_jax_stage(data))

@@ -17,11 +17,13 @@ from __future__ import annotations
 
 import os
 import threading
+import time
 from concurrent.futures import ThreadPoolExecutor
 from typing import Callable, Optional
 
 import numpy as np
 
+from .. import observe
 from ..cache import Singleflight
 from ..storage import idx as idx_mod
 from ..storage import types as t
@@ -216,14 +218,18 @@ class EcVolume:
     # --- read path ---
     def read_needle(self, needle_id: int, cookie: Optional[int] = None,
                     shard_reader: Optional[ShardReader] = None) -> Needle:
-        offset, size, intervals = self.locate(needle_id)
+        """One EC needle read. Its parts are observe stages (`ec.get.*`:
+        PERF.md has the table), each exclusive of the others, under
+        whatever request context is ambient."""
+        with observe.stage("ec.get.ecx"):
+            offset, size, intervals = self.locate(needle_id)
         if t.size_is_deleted(size):
             raise KeyError(f"needle {needle_id:x} deleted")
         parts = [self._read_interval(iv, shard_reader) for iv in intervals]
-        record = b"".join(parts)
-        n = Needle.from_bytes(record, self.version)
-        if cookie is not None and n.cookie != cookie:
-            raise KeyError(f"needle {needle_id:x} cookie mismatch")
+        with observe.stage("ec.get.parse"):
+            n = Needle.from_bytes(b"".join(parts), self.version)
+            if cookie is not None and n.cookie != cookie:
+                raise KeyError(f"needle {needle_id:x} cookie mismatch")
         return n
 
     def _read_interval(self, iv: Interval,
@@ -231,21 +237,36 @@ class EcVolume:
         shard_id, offset = iv.to_shard_id_and_offset(self.g)
         shard = self.shards.get(shard_id)
         if shard is not None:
-            data = shard.read_at(offset, iv.size)
+            with observe.stage("ec.get.shard_read"):
+                data = shard.read_at(offset, iv.size)
             if len(data) == iv.size:
                 return data
         # non-local interval: peer fetch or (worst case) an on-line
         # reconstruction from k shards — N concurrent readers of the
         # same cold interval share one flight
+        led = []
+
         def fetch() -> bytes:
+            led.append(True)
             if shard_reader is not None:
-                data = shard_reader(shard_id, offset, iv.size)
+                # a holder of the shard itself, its location looked up
+                with observe.stage("ec.get.peer_fetch"):
+                    data = shard_reader(shard_id, offset, iv.size)
                 if data is not None and len(data) == iv.size:
                     return data
             return self._reconstruct_interval(shard_id, offset, iv.size,
                                               shard_reader)
 
-        return self.read_flight.do((shard_id, offset, iv.size), fetch)
+        t0 = time.perf_counter()
+        data = self.read_flight.do((shard_id, offset, iv.size), fetch)
+        if not led:
+            # another GET's flight brought the interval: all of do() was
+            # the wait for it
+            waited = time.perf_counter() - t0
+            observe.record_span(
+                "ec.get.flight_wait", None,
+                int((time.time() - waited) * 1e6), int(waited * 1e6))
+        return data
 
     def _reconstruct_interval(self, missing_shard: int, offset: int,
                               size: int,
@@ -258,40 +279,42 @@ class EcVolume:
             raise IOError(
                 f"shard {missing_shard} missing and no coder to reconstruct")
         shards: list[Optional[np.ndarray]] = [None] * self.g.total_shards
-        have = 0
-        remote_candidates: list[int] = []
-        for sid in range(self.g.total_shards):
-            if sid == missing_shard:
-                continue
-            local = self.shards.get(sid)
-            if local is not None and have < self.g.data_shards:
-                b = local.read_at(offset, size)
-                if len(b) == size:
-                    shards[sid] = np.frombuffer(b, dtype=np.uint8)
-                    have += 1
+        with observe.stage("ec.get.survivors"):
+            have = 0
+            remote_candidates: list[int] = []
+            for sid in range(self.g.total_shards):
+                if sid == missing_shard:
                     continue
-            remote_candidates.append(sid)
-        need = self.g.data_shards - have
-        if need > 0 and shard_reader is not None and remote_candidates:
-            futs = {sid: _SURVIVOR_POOL.submit(shard_reader, sid, offset,
-                                               size)
-                    for sid in remote_candidates}
-            for sid, fut in futs.items():
-                if have >= self.g.data_shards:
-                    fut.cancel()
-                    continue
-                try:
-                    b = fut.result()
-                except Exception:
-                    continue
-                if b is not None and len(b) == size:
-                    shards[sid] = np.frombuffer(b, dtype=np.uint8)
-                    have += 1
+                local = self.shards.get(sid)
+                if local is not None and have < self.g.data_shards:
+                    b = local.read_at(offset, size)
+                    if len(b) == size:
+                        shards[sid] = np.frombuffer(b, dtype=np.uint8)
+                        have += 1
+                        continue
+                remote_candidates.append(sid)
+            need = self.g.data_shards - have
+            if need > 0 and shard_reader is not None and remote_candidates:
+                futs = {sid: _SURVIVOR_POOL.submit(shard_reader, sid,
+                                                   offset, size)
+                        for sid in remote_candidates}
+                for sid, fut in futs.items():
+                    if have >= self.g.data_shards:
+                        fut.cancel()
+                        continue
+                    try:
+                        b = fut.result()
+                    except Exception:
+                        continue
+                    if b is not None and len(b) == size:
+                        shards[sid] = np.frombuffer(b, dtype=np.uint8)
+                        have += 1
         if have < self.g.data_shards:
             raise IOError(
                 f"cannot reconstruct shard {missing_shard}: "
                 f"only {have} of {self.g.data_shards} shards reachable")
-        rebuilt = self.coder.reconstruct(shards, targets=(missing_shard,))
+        rebuilt = self.coder.reconstruct(shards, targets=(missing_shard,),
+                                         stage="ec.get")
         reg = metrics_mod.shared("ec")
         reg.count("reconstruct_intervals")
         reg.count("reconstruct_bytes", value=size)

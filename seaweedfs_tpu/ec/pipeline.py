@@ -307,14 +307,9 @@ def _run_pipeline(batches: Iterator[np.ndarray], dispatch, consume,
             if batch is _SENTINEL:
                 drained = True
                 break
-            from ..observe.profiler import trace_annotation
-            with contextlib.ExitStack() as stack:
-                if trace_ctx is not None:
-                    stack.enter_context(observe.stage(
-                        "ec.dispatch", trace_ctx,
-                        tags={"batch": batch_i}))
-                stack.enter_context(
-                    trace_annotation("ec_pipeline_dispatch"))
+            with (observe.stage("ec.dispatch", trace_ctx,
+                                tags={"batch": batch_i})
+                  if trace_ctx is not None else contextlib.nullcontext()):
                 try:
                     handle = dispatch(batch)
                 except BaseException:
@@ -374,9 +369,7 @@ def _stream_encode_core(batches: Iterator[np.ndarray], coder: ErasureCoder,
     fan = _FanOut(list(shard_paths), op.write_depth)
 
     def consume(data: np.ndarray, handle) -> None:
-        from ..observe.profiler import trace_annotation
-        with observe.stage("ec.kernel", tctx), \
-                trace_annotation("ec_pipeline_kernel_wait"):
+        with observe.stage("ec.kernel", tctx):
             parity = coder.materialize(handle)
         rows = [*data, *parity]
         if digests is not None:
@@ -398,7 +391,9 @@ def _stream_encode_core(batches: Iterator[np.ndarray], coder: ErasureCoder,
             coder.encode_async, consume, op.depth, trace_ctx=tctx,
             recycle=recycle)
     finally:
-        fan.close()
+        # what is still queued, then each shard file's fsync and close
+        with observe.stage("ec.fsync", tctx):
+            fan.close()
     if fan.errors:
         raise fan.errors[0]
 
@@ -889,9 +884,7 @@ def stream_rebuild(base_file_name: str, coder: ErasureCoder,
     tctx = observe.ensure_ctx("ec")
 
     def consume(survivors: np.ndarray, handle) -> None:
-        from ..observe.profiler import trace_annotation
-        with observe.stage("ec.kernel", tctx), \
-                trace_annotation("ec_pipeline_kernel_wait"):
+        with observe.stage("ec.kernel", tctx):
             rebuilt = coder.materialize(handle)
         # the kernel has consumed the survivor batch: recycle it now —
         # the rebuilt rows fanned out below are device-materialized
@@ -905,7 +898,8 @@ def stream_rebuild(base_file_name: str, coder: ErasureCoder,
             _traced_batches(src.batches(op.batch_size), tctx), fn,
             consume, op.depth, trace_ctx=tctx, recycle=src.recycle)
     finally:
-        fan.close()
+        with observe.stage("ec.fsync", tctx):
+            fan.close()
         src.close()
     if fan.errors:
         raise fan.errors[0]

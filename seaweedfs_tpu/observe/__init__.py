@@ -24,6 +24,7 @@ from __future__ import annotations
 
 import contextlib
 import contextvars
+import itertools
 import os
 import random
 import threading
@@ -34,6 +35,7 @@ from typing import Iterable, NamedTuple, Optional
 TRACE_HEADER = "X-Seaweed-Trace"
 GRPC_TRACE_KEY = "x-seaweed-trace"
 
+from ..utils import metrics as _metrics  # noqa: E402
 from . import profiler, wideevents  # noqa: E402  (no circular import:
 # neither submodule imports this package's namespace back)
 
@@ -60,7 +62,6 @@ _instance: contextvars.ContextVar[str] = contextvars.ContextVar(
     "sw_instance", default="")
 
 _ring: deque = deque(maxlen=RING_SIZE)
-_ring_lock = threading.Lock()
 
 
 def slow_threshold_ms() -> float:
@@ -76,12 +77,11 @@ def slow_threshold_ms() -> float:
 # PRNG hex is ~60x cheaper than os.urandom per id on this host class,
 # which matters on the fastpath (one trace id + one span id per request)
 _id_rng = random.Random(random.SystemRandom().getrandbits(64))
-_id_lock = threading.Lock()
 
 
 def new_id() -> str:
-    with _id_lock:
-        return f"{_id_rng.getrandbits(64):016x}"
+    # one C call under the GIL: there is nothing for a lock to guard
+    return "%016x" % _id_rng.getrandbits(64)
 
 
 class TraceCtx(NamedTuple):
@@ -160,7 +160,7 @@ class Span:
     explicit ctx= — in plain threads."""
 
     __slots__ = ("name", "tags", "_ctx", "_root", "trace_id", "span_id",
-                 "parent_id", "_service", "_instance", "_t0", "_start_us",
+                 "parent_id", "_service", "_instance", "_t0", "_start",
                  "_tokens", "dur_us")
 
     def __init__(self, name: str, tags: Optional[dict] = None,
@@ -174,41 +174,37 @@ class Span:
         self._tokens = None
 
     def __enter__(self) -> "Span":
-        ctx = self._ctx if self._ctx is not None else capture()
-        self.trace_id = ctx.trace_id or new_id()
-        self.parent_id = "" if self._root else ctx.span_id
+        ctx = self._ctx
+        if ctx is None:
+            trace, parent, svc, inst = (_trace_id.get(), _span_id.get(),
+                                        _service.get(), _instance.get())
+        else:
+            trace, parent, svc, inst = ctx
+        self.trace_id = trace = trace or new_id()
+        self.parent_id = "" if self._root else parent
         self.span_id = new_id()
-        svc = self._service or ctx.service
-        self._service = svc
-        self._instance = ctx.instance
-        self._tokens = (_trace_id.set(self.trace_id),
-                        _span_id.set(self.span_id),
-                        _service.set(svc),
-                        _instance.set(ctx.instance))
-        self._start_us = int(time.time() * 1e6)
+        self._service = svc = self._service or svc
+        self._instance = inst
+        self._tokens = (_trace_id.set(trace), _span_id.set(self.span_id),
+                        _service.set(svc), _instance.set(inst))
+        self._start = time.time()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
-        dur_us = int((time.perf_counter() - self._t0) * 1e6)
-        self.dur_us = dur_us
-        for var, tok in zip((_trace_id, _span_id, _service, _instance),
-                            self._tokens):
-            var.reset(tok)
+        seconds = time.perf_counter() - self._t0
+        self.dur_us = dur_us = int(seconds * 1e6)
+        t_trace, t_span, t_svc, t_inst = self._tokens
+        _trace_id.reset(t_trace)
+        _span_id.reset(t_span)
+        _service.reset(t_svc)
+        _instance.reset(t_inst)
         if exc_type is not None:
             self.tags.setdefault("error", exc_type.__name__)
-        record({
-            "trace": self.trace_id,
-            "id": self.span_id,
-            "parent": self.parent_id,
-            "name": self.name,
-            "svc": self._service,
-            "inst": self._instance,
-            "start_us": self._start_us,
-            "dur_us": dur_us,
-            "tid": threading.get_ident() & 0x7FFFFFFF,
-            "tags": self.tags,
-        })
+        wideevents.absorb(self.name, self.span_id, dur_us)
+        _ring.append((self.name, seconds, self._start, self.trace_id,
+                      self.span_id, self.parent_id, self._service,
+                      self._instance, threading.get_ident(), self.tags))
 
     @property
     def dur_ms(self) -> float:
@@ -220,47 +216,150 @@ def span(name: str, tags: Optional[dict] = None,
     return Span(name, tags=tags, ctx=ctx, service=service)
 
 
-def record(span_dict: dict) -> None:
-    # feed the ambient request's wide-event stage accumulator BEFORE
-    # taking the ring lock (absorb is contextvar-local, lock-free)
-    wideevents.absorb(span_dict)
-    with _ring_lock:
-        _ring.append(span_dict)
+# --- the ring's rows ---------------------------------------------------
+# One completed span is one tuple in the ring, name and seconds first:
+#   (name, seconds, start_s, trace, id, parent, svc, inst, thread, tags)
+# which spans() unfolds into the span dict every reader knows. A hot
+# path pays for a tuple and an append (atomic: no lock); whoever reads
+# pays for the dict, the microseconds and the id's sixteen hex digits.
+# A Span's id is a string made when it opens, since its children name
+# it; a stage's id stays the integer it took from this process's
+# sequence (_id_seq) until somebody reads it, also where it is the
+# parent of the stages that ended under it.
+
+_id_base = _id_rng.getrandbits(64)
+_id_seq = itertools.count(1)
 
 
-def record_span(name: str, ctx: TraceCtx, start_us: int, dur_us: int,
-                tags: Optional[dict] = None) -> str:
-    """Record a completed span against an explicit context — the
-    zero-contextvar path for hot worker threads (EC pipeline stages).
-    Returns the span id so callers can chain children if they need to."""
-    sid = new_id()
-    record({
-        "trace": ctx.trace_id,
-        "id": sid,
-        "parent": ctx.span_id,
-        "name": name,
-        "svc": ctx.service,
-        "inst": ctx.instance,
-        "start_us": start_us,
-        "dur_us": dur_us,
-        "tid": threading.get_ident() & 0x7FFFFFFF,
-        "tags": dict(tags) if tags else {},
-    })
-    return sid
+def _hex(sid) -> str:
+    return sid if type(sid) is str else "%016x" % (
+        (_id_base + sid) & 0xFFFFFFFFFFFFFFFF)
 
 
-@contextlib.contextmanager
-def stage(name: str, ctx: TraceCtx, tags: Optional[dict] = None):
-    """Time a block and record_span it against an explicit context — the
-    with-form of record_span for hot worker threads (EC pipeline stages),
-    no contextvar traffic."""
-    start_us = int(time.time() * 1e6)
-    t0 = time.perf_counter()
-    try:
-        yield
-    finally:
-        record_span(name, ctx, start_us,
-                    int((time.perf_counter() - t0) * 1e6), tags)
+def _unfold(row: tuple) -> dict:
+    name, seconds, start_s, trace, sid, parent, svc, inst, tid, tags = row
+    return {"trace": trace, "id": _hex(sid), "parent": _hex(parent),
+            "name": name, "svc": svc, "inst": inst,
+            "start_us": int(start_s * 1e6), "dur_us": int(seconds * 1e6),
+            "tid": tid & 0x7FFFFFFF, "tags": dict(tags) if tags else {}}
+
+
+# --- stages: one pair of clock reads, three sinks ----------------------
+# 1. the ring (above); 2. the shared `ec` registry, one family
+# seaweedfs_tpu_ec_stage_seconds{stage="<name>"} whose _sum and _count
+# never truncate as the ring does; 3. (with-form only) a profiler
+# TraceAnnotation, so that a device trace shows the stage on its own
+# clock. The family is the EC tier's: only `ec.*` names accumulate, and
+# the plain-volume data plane's record_span calls pay one prefix test.
+#
+# A served GET takes a dozen of these on one interpreter that runs at
+# four fifths of what it sustains, where a microsecond of a request's
+# path costs some twenty at the median (PERF.md, PR 25). So a request's
+# stages pay sinks 1 and 2 once a request, not once a stage: while an
+# enclosing stage of the request is open, a stage that ends under it
+# (in the request's task, or in a worker that runs under a copy of its
+# context) is one row appended to that stage's list. The enclosing
+# stage's exit folds the list into the request's wide event, adds it to
+# the registry under one lock and to the ring in one call.
+
+_EC_STAGES = _metrics.shared("ec")
+# the enclosing stage that is open in this context: its id, the rows of
+# the stages that have ended under it, and what every row of its request
+# repeats (trace id, service, instance)
+_open: contextvars.ContextVar[Optional[tuple]] = contextvars.ContextVar(
+    "sw_stages", default=None)
+
+
+def _emit(name: str, ctx: Optional[TraceCtx], start_s: float,
+          seconds: float, tags: Optional[dict],
+          enclosing: bool = False) -> None:
+    """One ended stage to the ring and the counters: by way of the
+    enclosing stage open in the ambient context, whose child it then is,
+    if the stage is the ambient context's (`ctx` None) and wraps nothing
+    itself; else at once."""
+    if ctx is None:
+        over = _open.get()
+        if over is not None and not enclosing:
+            sid, under, trace, svc, inst = over
+            under.append((name, seconds, start_s, trace, next(_id_seq), sid,
+                          svc, inst, threading.get_ident(), tags))
+            return
+        ctx = (_trace_id.get(), _span_id.get(), _service.get(),
+               _instance.get())
+    row = (name, seconds, start_s, ctx[0], next(_id_seq), ctx[1], ctx[2],
+           ctx[3], threading.get_ident(), tags)
+    if not enclosing:
+        wideevents.absorb(name, "", int(seconds * 1e6))
+    _ring.append(row)
+    if name.startswith("ec."):
+        _EC_STAGES.add_seconds("stage", "stage", (row,))
+
+
+def record_span(name: str, ctx: Optional[TraceCtx], start_us: int,
+                dur_us: int, tags: Optional[dict] = None) -> None:
+    """Record a completed span — the record form of a stage, for a block
+    the caller timed itself (one that ends on another thread than it
+    began, or whose last pull must not count). Against an explicit
+    context, or the ambient one (`ctx` None). Feeds the ring and the
+    stage counters; no trace annotation, which needs the with-form."""
+    _emit(name, ctx, start_us / 1e6, dur_us / 1e6, tags)
+
+
+class stage:
+    """Time a block as one stage: a span in the ring under ``ctx`` (the
+    ambient context when None: a request's own thread, or a worker that
+    runs under a copy of it), a count and its seconds in
+    seaweedfs_tpu_ec_stage_seconds{stage=name}, and a profiler
+    annotation of the same block. Sets no span id: stages are siblings
+    under the span that is ambient. ``enclosing`` marks a stage
+    that only wraps stages named on their own: it is kept out of the
+    request's wide event, where the largest entry is taken for the
+    dominant one and an enclosing one always is; and the outermost such
+    stage of a context gathers the stages that end under it as its
+    children, and pays for them all at its exit."""
+
+    __slots__ = ("_name", "_ctx", "_tags", "_enclosing", "_note",
+                 "_start", "_t0", "_over")
+
+    def __init__(self, name: str, ctx: Optional[TraceCtx] = None,
+                 tags: Optional[dict] = None, enclosing: bool = False):
+        self._name = name
+        self._ctx = ctx
+        self._tags = tags
+        self._enclosing = enclosing
+
+    def __enter__(self) -> "stage":
+        if self._enclosing and self._ctx is None and _open.get() is None:
+            self._over = _open.set((next(_id_seq), [], _trace_id.get(),
+                                    _service.get(), _instance.get()))
+        else:
+            self._over = None
+        self._note = profiler.trace_annotation(self._name)
+        self._start = time.time()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        seconds = time.perf_counter() - self._t0
+        if self._note is not None:
+            self._note.__exit__(exc_type, exc, tb)
+        if self._over is None:
+            _emit(self._name, self._ctx, self._start, seconds, self._tags,
+                  self._enclosing)
+            return
+        sid, under, trace, svc, inst = _open.get()
+        _open.reset(self._over)
+        acc = wideevents.current()
+        if acc is not None:
+            stages = acc["stages"]
+            for row in under:
+                stages[row[0]] = stages.get(row[0], 0) + int(row[1] * 1e6)
+        under.append((self._name, seconds, self._start, trace, sid,
+                      _span_id.get(), svc, inst, threading.get_ident(),
+                      self._tags))
+        _ring.extend(under)
+        if self._name.startswith("ec."):
+            _EC_STAGES.add_seconds("stage", "stage", under)
 
 
 def ensure_ctx(service: str = "") -> TraceCtx:
@@ -273,21 +372,29 @@ def ensure_ctx(service: str = "") -> TraceCtx:
     return TraceCtx(new_id(), "", ctx.service or service, ctx.instance)
 
 
+def _rows() -> list[tuple]:
+    while True:
+        try:
+            return list(_ring)
+        except RuntimeError:
+            # an append (which takes no lock) fell between two steps of
+            # the copy; all but impossible, since the copy is one call
+            continue
+
+
 def spans(trace_id: str = "", limit: int = 0) -> list[dict]:
     """Completed spans, oldest first, optionally filtered by trace id."""
-    with _ring_lock:
-        out = list(_ring)
+    rows = _rows()
     if trace_id:
-        out = [s for s in out if s["trace"] == trace_id]
-    if limit and len(out) > limit:
-        out = out[-limit:]
-    return out
+        rows = [row for row in rows if row[3] == trace_id]
+    if limit and len(rows) > limit:
+        rows = rows[-limit:]
+    return [_unfold(row) for row in rows]
 
 
 def reset() -> None:
     """Drop all recorded spans (tests)."""
-    with _ring_lock:
-        _ring.clear()
+    _ring.clear()
 
 
 def stage_totals(trace_id: str = "",
@@ -321,8 +428,6 @@ def maybe_log_slow(span_obj: Span) -> None:
 # histogram exemplars: every metrics.observe() made under a traced
 # request stamps its bucket with the ambient trace id, so a p99 bucket
 # on /metrics?exemplars=1 links straight to its /debug/trace span
-from ..utils import metrics as _metrics  # noqa: E402
-
 _metrics.set_exemplar_source(lambda: _trace_id.get(""))
 
 
@@ -424,10 +529,11 @@ def trace_middleware(service: str, instance: str = ""):
                 # a bare StreamResponse is a long-lived stream
                 # (/cluster/watch, meta subscribe, tail): its lifetime is
                 # not latency — same exemption the gRPC stream wrapper
-                # makes. /debug/profile blocks for its sample window by
-                # design.
+                # makes. /debug/profile and /debug/xprof block for their
+                # sample window by design.
                 streamed = (not isinstance(resp, web.Response)
-                            or request.path == "/debug/profile")
+                            or request.path in ("/debug/profile",
+                                                "/debug/xprof"))
                 return resp
         finally:
             _retry.reset_deadline(_dl_token)

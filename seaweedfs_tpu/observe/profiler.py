@@ -37,6 +37,7 @@ import cProfile
 import io
 import os
 import pstats
+import re
 import sys
 import threading
 import time
@@ -374,11 +375,182 @@ def profile_handler():
     return handler
 
 
+# --- the device profiler (jax.profiler): stage annotations and the
+# operator's windowed device trace. The one place that imports it. -----
+
+_annotation_cls = None
+
+
 def trace_annotation(name: str):
-    """JAX trace annotation around kernel launches; inert without an
-    active profiler session."""
-    try:
-        import jax.profiler
-        return jax.profiler.TraceAnnotation(name)
-    except Exception:
-        return contextlib.nullcontext()
+    """While a device profiler session is open: an entered
+    jax.profiler.TraceAnnotation, a host event named `name` on the
+    trace's own clock that lasts until the caller's `__exit__` of it
+    (observe.stage holds one per stage). With no session open, None,
+    for the price of one flag test in the profiler; in a process that
+    never imported JAX no session can be open, and JAX is not imported
+    for it."""
+    global _annotation_cls
+    cls = _annotation_cls
+    if cls is None:
+        if "jax" not in sys.modules:
+            return None
+        try:
+            import jax.profiler
+        except ImportError:
+            return None
+        cls = _annotation_cls = jax.profiler.TraceAnnotation
+    if not cls.is_enabled():
+        return None
+    note = cls(name)
+    note.__enter__()
+    return note
+
+
+XPROF_MAX_SECONDS = 60.0
+_DEVICE_PLANE = "/device:"
+_OPS_LINE = "XLA Ops"
+_HOST_PLANE = "/host:CPU"
+_HLO_OP = re.compile(r"%?([\w.\-]+) = ([^{ ]+)")
+
+
+def _merged_seconds(spans: list[tuple[float, float]]) -> float:
+    """Nanosecond intervals -> seconds covered by any of them."""
+    total, end = 0.0, float("-inf")
+    for lo, hi in sorted(spans):
+        if hi > end:
+            total += hi - max(lo, end)
+            end = hi
+    return total / 1e9
+
+
+def summarize_xplane(path: str) -> dict:
+    """What one recorded window says, from the trace alone: the
+    session's length, the seconds in which any operation ran on a device
+    (the union over its `XLA Ops` line, averaged over the devices that
+    ran any), each device operation and each `ec.*` stage with its count
+    and seconds (a name's overlapping repeats on one thread counted
+    once)."""
+    from jax.profiler import ProfileData
+    start = stop = None
+    busy: list[float] = []
+    ops: dict[str, list] = {}
+    stages: dict[str, list] = {}
+    for plane in ProfileData.from_file(path).planes:
+        for key, value in plane.stats:
+            if key == "profile_start_time":
+                start = float(value)
+            elif key == "profile_stop_time":
+                stop = float(value)
+        if plane.name.startswith(_DEVICE_PLANE):
+            spans = []
+            for line in plane.lines:
+                if line.name != _OPS_LINE:
+                    continue
+                for e in line.events:
+                    spans.append((e.start_ns, e.start_ns + e.duration_ns))
+                    # `%gf_apply.1 = u8[1,16384]{..} custom-call(..)` ->
+                    # `gf_apply.1 u8[1,16384]`: the op and its result
+                    m = _HLO_OP.match(e.name)
+                    rec = ops.setdefault(
+                        f"{m.group(1)} {m.group(2)}" if m else e.name[:80],
+                        [0, 0.0])
+                    rec[0] += 1
+                    rec[1] += e.duration_ns / 1e9
+            if spans:
+                busy.append(_merged_seconds(spans))
+        elif plane.name == _HOST_PLANE:
+            for line in plane.lines:
+                by_name: dict[str, list] = {}
+                for e in line.events:
+                    if e.name.startswith("ec."):
+                        by_name.setdefault(e.name, []).append(
+                            (e.start_ns, e.start_ns + e.duration_ns))
+                for name, spans in by_name.items():
+                    rec = stages.setdefault(name, [0, 0.0])
+                    rec[0] += len(spans)
+                    rec[1] += _merged_seconds(spans)
+    window = (stop - start) / 1e9 if start is not None \
+        and stop is not None else 0.0
+    return {"window_s": window,
+            "device_busy_s": sum(busy) / len(busy) if busy else 0.0,
+            "devices_traced": len(busy),
+            "device_ops": dict(sorted(ops.items(),
+                                      key=lambda kv: -kv[1][1])),
+            "stages": dict(sorted(stages.items()))}
+
+
+def xprof_handler(on_device):
+    """aiohttp handler: GET /debug/xprof?seconds=N[&keep=1] puts a
+    jax.profiler session (python tracer off, host tracer level 2: what
+    benchmark/run.py traces with) around the next N seconds of the live
+    server and answers what summarize_xplane reads from it; keep=1
+    leaves the trace directory and returns its path. `on_device()` says
+    whether this server computes on an accelerator: where it does not
+    there is no device to trace, 501. One session a process: 409 while
+    a window is open here or another owner holds the profiler."""
+    import asyncio
+    import glob
+    import shutil
+    import tempfile
+
+    from aiohttp import web
+
+    busy = threading.Lock()
+
+    async def handler(request: web.Request) -> web.Response:
+        if not on_device():
+            return web.json_response(
+                {"error": "this server computes on no accelerator: "
+                          "there is no device to trace"}, status=501)
+        if not busy.acquire(blocking=False):
+            return web.json_response(
+                {"error": "another device trace window is open"},
+                status=409)
+        trace_dir = ""
+        kept = False
+        try:
+            try:
+                seconds = min(max(float(request.query.get("seconds", 5)),
+                                  0.0), XPROF_MAX_SECONDS)
+            except ValueError:
+                return web.json_response({"error": "seconds: a number"},
+                                         status=400)
+            import jax.profiler
+            trace_dir = tempfile.mkdtemp(prefix="weed-xprof.")
+            opts = jax.profiler.ProfileOptions()
+            opts.python_tracer_level = 0
+            opts.host_tracer_level = 2
+            loop = asyncio.get_event_loop()
+            try:
+                # opening a session takes a while too: off the loop
+                await loop.run_in_executor(
+                    None, lambda: jax.profiler.start_trace(
+                        trace_dir, profiler_options=opts))
+            except RuntimeError as e:
+                # a session somebody else opened (a benchmark harness
+                # that hosts this server, a debugger)
+                return web.json_response(
+                    {"error": f"the profiler is held elsewhere: {e}"},
+                    status=409)
+            try:
+                await asyncio.sleep(seconds)
+            finally:
+                # so does writing the trace out
+                await loop.run_in_executor(None, jax.profiler.stop_trace)
+            found = sorted(glob.glob(os.path.join(
+                trace_dir, "plugins", "profile", "*", "*.xplane.pb")))
+            if not found:
+                return web.json_response(
+                    {"error": "the profiler wrote no trace"}, status=500)
+            out = await loop.run_in_executor(
+                None, summarize_xplane, found[-1])
+            if request.query.get("keep", "") in ("1", "true"):
+                out["trace_dir"] = trace_dir
+                kept = True
+            return web.json_response(out)
+        finally:
+            busy.release()
+            if trace_dir and not kept:
+                shutil.rmtree(trace_dir, ignore_errors=True)
+
+    return handler

@@ -12,10 +12,12 @@ lock reads, the corrected span-ring pattern) plus an optional ndjson
 sink.  ``/debug/events`` serves the ring with filters; ``cluster.tail``
 merges the slow tail cluster-wide and ranks where p99 actually goes.
 
-The per-request stage accumulator is a contextvar: ``observe.record()``
-feeds every completed span's duration into the ambient request's
+The per-request stage accumulator is a contextvar: observe feeds
+every completed span's duration into the ambient request's
 accumulator (worker-thread spans recorded against an explicit ctx don't
-cross — the EC pipeline emits its own records via ``emit_stages``).
+cross — the EC pipeline emits its own records via ``emit_stages``; an
+EC GET's worker runs under a copy of the request's context, so its
+stages do).
 Code anywhere under the request can attach fields with ``annotate()`` /
 ``annotate_add()`` (utils/retry counts retries, the chunk cache counts
 hits/misses) without plumbing a context object through every layer.
@@ -84,16 +86,15 @@ def current() -> Optional[dict]:
     return _acc.get()
 
 
-def absorb(span_dict: dict) -> None:
+def absorb(name: str, span_id: str, dur_us: int) -> None:
     """Fold a completed span into the ambient request accumulator —
-    called by observe.record() for every span, so stage timings cost
+    called by observe for every span that closes, so stage timings cost
     nothing extra at the span call sites."""
     acc = _acc.get()
-    if acc is None or span_dict.get("id") == acc["root"]:
+    if acc is None or span_id == acc["root"]:
         return
-    name = span_dict.get("name", "")
     stages = acc["stages"]
-    stages[name] = stages.get(name, 0) + int(span_dict.get("dur_us", 0))
+    stages[name] = stages.get(name, 0) + dur_us
 
 
 def annotate(key: str, value) -> None:
@@ -244,11 +245,29 @@ _STAGE_BUCKETS: tuple[tuple[str, str], ...] = (
     ("volume.read", "disk"),
     ("volume.write", "disk"),
     ("volume.scrub", "disk"),
+    # the EC GET's stages (observe.stage; PERF.md has the table), each
+    # named: a host-side ec.* stage is not the kernel's for having no row
+    ("ec.get.ecx", "disk"),
+    ("ec.get.shard_read", "disk"),
+    ("ec.get.survivors", "disk"),
+    ("ec.get.parse", "disk"),
+    ("ec.get.peer_fetch", "remote-hop"),
+    ("ec.get.queue", "admission-queue"),
+    ("ec.get.resume", "admission-queue"),
+    ("ec.get.flight_wait", "lock"),
+    ("ec.get.stack_pad", "kernel"),
+    ("ec.get.dispatch", "kernel"),
+    ("ec.get.d2h_wait", "kernel"),
+    ("ec.get.handler", "handler"),
     ("ec.read", "disk"),
     ("ec.write", "disk"),
+    ("ec.fsync", "disk"),
+    ("ec.seal", "disk"),
+    ("ec.ecx", "disk"),
+    ("ec.stamp", "disk"),
     ("ec.kernel", "kernel"),
     ("ec.dispatch", "kernel"),
-    ("ec.", "kernel"),
+    ("ec.stage.chip", "kernel"),
     ("filer.fetch_chunk", "remote-hop"),
     ("filer.upload_chunk", "remote-hop"),
     ("filer.upload", "remote-hop"),

@@ -21,6 +21,7 @@ the program (and the persistent compile cache entry) of the last one.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 
 import jax
@@ -29,6 +30,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .. import observe
 from . import gf256
 from .rs_jax import bitplane_matrix
 
@@ -161,16 +163,29 @@ def gf_apply_pallas_host(matrix: np.ndarray, tile: int = TILE,
     before, each new interval length a fresh compile)."""
     kernel, cols = _bind(matrix, tile, interpret, vmem_limit_bytes)
 
-    def apply_fn(data: np.ndarray) -> np.ndarray:
+    def apply_fn(data: np.ndarray, stage: str = "") -> np.ndarray:
+        """`stage`: the prefix under which a caller that has one (a
+        degraded read: "ec.get") wants the three steps of this call
+        timed as observe stages (`<stage>.stack_pad`, `.dispatch`,
+        `.d2h_wait`); without one nothing is timed."""
+        def timed(step: str):
+            return (observe.stage(stage + step) if stage
+                    else contextlib.nullcontext())
+
         n = data.shape[1]
         tiles = -(-n // tile)
         if tiles < _BUCKET_TILES:
             tiles = 1 << (tiles - 1).bit_length()
         if tiles * tile != n:
-            padded = np.zeros((cols, tiles * tile), dtype=np.uint8)
-            padded[:, :n] = data
-            data = padded
-        return np.asarray(kernel(data))[:, :n]
+            with timed(".stack_pad"):
+                padded = np.zeros((cols, tiles * tile), dtype=np.uint8)
+                padded[:, :n] = data
+                data = padded
+        with timed(".dispatch"):
+            out = kernel(data)  # H2D + launch; returns before the device
+        with timed(".d2h_wait"):
+            out = np.asarray(out)  # the kernel, D2H, delinearize
+        return out[:, :n]
 
     return apply_fn
 

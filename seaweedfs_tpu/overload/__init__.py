@@ -103,7 +103,7 @@ MASTER_SYSTEM_PATHS = OPS_PATHS | {
 }
 # volume fids always contain "," so these can't collide with data paths
 VOLUME_SYSTEM_PATHS = OPS_PATHS | {"/admin/faults", "/ui", "/status",
-                                   "/admin/tail"}
+                                   "/admin/tail", "/debug/xprof"}
 # filer: exact ops routes + the long-lived meta streams (both
 # registered ahead of the path catch-all, so a user file with the same
 # name is shadowed by the route anyway)

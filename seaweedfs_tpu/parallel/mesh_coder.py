@@ -44,6 +44,7 @@ wrapping one chip.
 
 from __future__ import annotations
 
+import functools
 import os
 import threading
 import time
@@ -238,11 +239,11 @@ class MeshCoder(JaxCoder):
                 self.k, self.m, tuple(present), tuple(missing)))
         return super()._rec_apply(present, missing)
 
-    def _rec_apply_sync(self, present, missing):
+    def _rec_apply_sync(self, present, missing, stage=""):
         # a degraded read's interval: one chip, host-side pad/slice and
         # bucketed widths, exactly as PallasCoder does it
         if self.method != "pallas":
-            return super()._rec_apply_sync(present, missing)
+            return super()._rec_apply_sync(present, missing, stage)
         key = ("sync", present, missing)
         with self._lock:
             fn = self._rec_sharded.get(key)
@@ -252,7 +253,7 @@ class MeshCoder(JaxCoder):
                     gf256.reconstruction_matrix(self.k, self.m, present,
                                                 missing),
                     interpret=self._interpret)
-            return fn
+            return functools.partial(fn, stage=stage) if stage else fn
 
     def _sharded(self, matrix: np.ndarray):
         """jit(shard_map) of the per-chip kernel for `matrix`, columns

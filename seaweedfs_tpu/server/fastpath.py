@@ -496,12 +496,19 @@ class FastVolumeProtocol(asyncio.Protocol):
     async def _read(self, method: str, fid: FileId, q: dict,
                     headers: dict, raw: bytes) -> None:
         server = self.server
+        vol = server.store.find_volume(fid.volume_id)
+        if (vol is None
+                and server.store.find_ec_volume(fid.volume_id) is not None):
+            # an EC GET is served by the aiohttp side: `ec.get` is its
+            # whole residence here, `ec.get.handler` the part over there
+            with observe.stage("ec.get", enclosing=True):
+                await self._proxy(raw)
+            return
         if (b"range" in headers or q.get("width") or q.get("height")):
             await self._proxy(raw)  # rare shapes: aiohttp path
             return
-        vol = server.store.find_volume(fid.volume_id)
         if vol is None:
-            await self._proxy(raw)  # EC volume / redirect logic
+            await self._proxy(raw)  # redirect logic
             return
         # zero-copy GET: whole plain-shape needle bodies go straight
         # from the .dat fd to the socket via the kernel (os.sendfile).

@@ -28,6 +28,8 @@ then POSTs the same body to every replica; all must ack.
 from __future__ import annotations
 
 import asyncio
+import contextlib
+import contextvars
 import email.parser
 import functools
 import logging
@@ -401,6 +403,9 @@ class VolumeServer:
         app.router.add_get("/debug/profile", profiler.profile_handler())
         app.router.add_get("/debug/trace", observe.trace_handler())
         overload.reserve_ops(app, "/debug/pprof", profiler.pprof_handler())
+        overload.reserve_ops(app, "/debug/xprof",
+                             profiler.xprof_handler(
+                                 lambda: self._ec_on_device()))
         overload.reserve_ops(app, "/debug/events",
                              wideevents.events_handler())
         app.router.add_get("/ui", self.status_ui)
@@ -408,6 +413,12 @@ class VolumeServer:
         app.on_startup.append(self._on_startup)
         app.on_cleanup.append(self._on_cleanup)
         return app
+
+    def _ec_on_device(self) -> bool:
+        """Whether an EC coder of this server computes on an accelerator
+        (as each reports it: the status surface's `coder.resolved`)."""
+        return any((c.get("device") or {}).get("platform", "cpu") != "cpu"
+                   for c in self.store.coder_status()["resolved"])
 
     async def _on_startup(self, app) -> None:
         from ..observe import profiler
@@ -679,6 +690,41 @@ class VolumeServer:
     async def _read(self, request: web.Request, fid: FileId) -> web.Response:
         """GetOrHeadHandler (volume_server_handlers_read.go:28-272)."""
         self.metrics.count("read")
+        if (self.store.find_volume(fid.volume_id) is not None
+                or self.store.find_ec_volume(fid.volume_id) is None):
+            return await self._read_needle(request, fid)
+        # an EC GET: every stage of it is named (observe.stage; PERF.md
+        # has the table). `ec.get.handler` is this side of the fast
+        # path's hop, and the whole of `ec.get` where there was no hop.
+        if request.headers.get("X-Swfs-Internal") == self._internal_token:
+            with observe.stage("ec.get.handler", enclosing=True):
+                return await self._read_needle(request, fid, ec=True)
+        with observe.stage("ec.get", enclosing=True), \
+                observe.stage("ec.get.handler", enclosing=True):
+            return await self._read_needle(request, fid, ec=True)
+
+    def _ec_read_needle(self, fid: FileId) -> asyncio.Future:
+        """The EC read in the executor, under a copy of the request's
+        context: its stages are children of the open `ec.get.handler`
+        and reach the request's wide event. The hand-off is a stage,
+        `ec.get.queue` from the submit to the worker's first line. The
+        future gives the needle and the instant of the worker's last
+        line (wall clock, then perf_counter), for `ec.get.resume`."""
+        submit_us = int(time.time() * 1e6)
+        t_submit = time.perf_counter()
+
+        def work():
+            observe.record_span(
+                "ec.get.queue", None, submit_us,
+                int((time.perf_counter() - t_submit) * 1e6))
+            n = self.store.read_needle(fid.volume_id, fid.key, fid.cookie)
+            return n, (time.time(), time.perf_counter())
+
+        return asyncio.get_event_loop().run_in_executor(
+            None, contextvars.copy_context().run, work)
+
+    async def _read_needle(self, request: web.Request, fid: FileId,
+                           ec: bool = False) -> web.Response:
         try:
             if await faults.fire_async("volume.read"):
                 # injected drop: the needle "isn't here" — clients fall
@@ -687,8 +733,13 @@ class VolumeServer:
                                          status=404)
         except faults.FaultError as e:
             return web.json_response({"error": str(e)}, status=500)
+        worker_done = None
+        # (an EC GET's `ec.get.handler` stands where `volume.read` would:
+        # a span that wraps the stages would be the largest entry of the
+        # wide event whatever the GET waited for)
         with self.metrics.timed("read"), \
-                observe.span("volume.read", tags={"fid": str(fid)}):
+                (contextlib.nullcontext() if ec else
+                 observe.span("volume.read", tags={"fid": str(fid)})):
             try:
                 # small needles (the request-rate-bound workload) read
                 # inline: a page-cache pread is microseconds while the
@@ -696,10 +747,12 @@ class VolumeServer:
                 # variant declines (None) for big needles, contended locks
                 # (vacuum), or non-local backends (tiered volumes) so the
                 # loop never blocks on real IO.
-                vol = self.store.find_volume(fid.volume_id)
+                vol = None if ec else self.store.find_volume(fid.volume_id)
                 n = (vol.read_needle_nowait(fid.key, fid.cookie)
                      if vol is not None else None)
-                if n is None:
+                if n is None and ec:
+                    n, worker_done = await self._ec_read_needle(fid)
+                elif n is None:
                     n = await asyncio.get_event_loop().run_in_executor(
                         None, lambda: self.store.read_needle(
                             fid.volume_id, fid.key, fid.cookie))
@@ -756,6 +809,18 @@ class VolumeServer:
         # lifecycle heat: one dict update per served read (EC reads —
         # the warm tier's un-EC signal — land here too)
         self.heat.record_read(fid.volume_id)
+        resp = self._respond(request, n)
+        if worker_done is not None:
+            # the way back: from the worker's last line, over the wait
+            # for the loop, to the response in hand
+            observe.record_span(
+                "ec.get.resume", None, int(worker_done[0] * 1e6),
+                int((time.perf_counter() - worker_done[1]) * 1e6))
+        return resp
+
+    @staticmethod
+    def _respond(request: web.Request, n) -> web.Response:
+        """A read needle as the response: etag, headers, body."""
         etag = f'"{n.etag()}"'
         if request.headers.get("If-None-Match") == etag:
             return web.Response(status=304)

@@ -107,6 +107,25 @@ def cluster_profile(env: CommandEnv, argv: list[str]):
     return out
 
 
+def _shares(parts: list, places: int = 3) -> list[float]:
+    """Each part's share of their sum to `places` decimals, and still
+    summing to 1: every share rounded down, the units of the last place
+    left over handed to the largest remainders (three equal buckets read
+    0.334 0.333 0.333, not 0.333 three times)."""
+    scale = 10 ** places
+    total = sum(parts)
+    if total <= 0:
+        return [0.0] * len(parts)
+    exact = [p * scale / total for p in parts]
+    units = [int(x) for x in exact]
+    left = scale - sum(units)
+    by_remainder = sorted(range(len(parts)),
+                          key=lambda i: units[i] - exact[i])
+    for i in by_remainder[:left]:
+        units[i] += 1
+    return [u / scale for u in units]
+
+
 @command("cluster.tail",
          "rank where the cluster's tail latency goes by dominant stage "
          "(cluster.tail [-minMs N] [-pct 99] [-limit N] [-class fg|bg] "
@@ -196,15 +215,15 @@ def cluster_tail(env: CommandEnv, argv: list[str]):
             b["example_trace"] = e.get("trace", "")
             b["example_node"] = e.get("_node", "")
     ranked = sorted(buckets.values(), key=lambda b: -b["total_us"])
-    total_us = sum(b["total_us"] for b in ranked) or 1
+    shares = _shares([b["total_us"] for b in ranked])
     table = []
-    for b in ranked:
+    for b, share in zip(ranked, shares):
         top_stages = sorted(b["stages"].items(), key=lambda kv: -kv[1])
         table.append({
             "stage": b["bucket"],
             "count": b["count"],
             "total_ms": round(b["total_us"] / 1000.0, 2),
-            "share": round(b["total_us"] / total_us, 3),
+            "share": share,
             "top_stages": [s for s, _ in top_stages[:3]],
             "example_trace": b["example_trace"],
             "example_node": b["example_node"],

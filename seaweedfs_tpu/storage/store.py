@@ -14,6 +14,7 @@ import threading
 from typing import Optional
 
 from .. import ec as ec_mod
+from .. import observe
 from ..ec import fused as ec_fused
 from ..ec import pipeline as ec_pipeline
 from ..utils import durable
@@ -498,16 +499,20 @@ class Store:
         v = self.find_volume(vid)
         if v is None:
             raise KeyError(f"volume {vid} not found")
-        v.read_only = True
-        v.sync()
+        with observe.stage("ec.seal"):
+            v.read_only = True
+            v.sync()
         return v, v.base_file_name(), self.geometry_for(v.collection)
 
     def _ec_finish_generate(self, v, base: str,
                             g: ec_mod.Geometry) -> list[int]:
-        ec_mod.write_sorted_ecx_from_idx(base, offset_size=v.offset_size)
+        with observe.stage("ec.ecx"):
+            ec_mod.write_sorted_ecx_from_idx(base,
+                                             offset_size=v.offset_size)
         # record per-shard digests into the .ecm while the bytes are
         # known-good — the EC scrubber's bit-rot reference
-        ec_pipeline.stamp_shard_digests(base, g)
+        with observe.stage("ec.stamp"):
+            ec_pipeline.stamp_shard_digests(base, g)
         return list(range(g.total_shards))
 
     def ec_generate(self, vid: int) -> list[int]:
@@ -647,7 +652,7 @@ class Store:
 
     def ec_mount(self, vid: int, collection: str,
                  shard_ids: list[int]) -> list[int]:
-        with self._lock:
+        with observe.stage("ec.mount"), self._lock:
             ev = self.find_ec_volume(vid)
             if ev is None:
                 loc = self._location_with_ec_files(vid, collection)

@@ -4,14 +4,11 @@ Counterpart of the reference's central registry (weed/stats/metrics.go:19-118)
 — counters, gauges and duration histograms rendered in Prometheus exposition
 format at /metrics, with optional label sets
 (`count("read", labels={"collection": "c"})`,
-`observe("read", dt, labels={"collection": "c"})`) and a push-gateway loop
-(LoopPushingMetric, metrics.go:140).
+`observe("read", dt, labels={"collection": "c"})`).
 """
 
 from __future__ import annotations
 
-import asyncio
-import random
 import threading
 import time
 from collections import defaultdict
@@ -78,6 +75,9 @@ class Registry:
         # key -> per-bucket [(trace_id, seconds) | None]: the most recent
         # traced observation that landed in each bucket
         self._hist_ex: dict[str, list] = {}
+        # family -> (label, {label value: [count, seconds]}): durations
+        # kept as a count and a sum alone (add_seconds)
+        self._seconds: dict[str, tuple[str, dict[str, list]]] = {}
 
     def count(self, name: str, value: float = 1.0,
               labels: dict | None = None) -> None:
@@ -117,33 +117,25 @@ class Registry:
                     key, [None] * (len(_BUCKETS) + 1))
                 ex[idx] = (trace, seconds)
 
-    async def push_loop(self, gateway_url: str, job: str,
-                        interval_seconds: float = 15.0) -> None:
-        """Push-gateway mode (LoopPushingMetric, weed/stats/metrics.go:140):
-        POST the exposition text to <gateway>/metrics/job/<job> forever.
-        Failures back off exponentially with jitter so a flapping gateway
-        isn't hammered in lockstep by every server in the cluster."""
-        import aiohttp
-        failures = 0
-        # the push gateway lives OUTSIDE the trace domain: no request
-        # context exists in this daemon and the gateway would only see
-        # (and store) meaningless per-push trace ids
-        async with aiohttp.ClientSession(  # weedlint: disable=ctx-propagation
-                timeout=aiohttp.ClientTimeout(total=30)) as session:
-            while True:
-                try:
-                    async with session.post(
-                            f"{gateway_url.rstrip('/')}/metrics/job/{job}",
-                            data=self.render(),
-                            headers={"Content-Type": "text/plain"}) as r:
-                        await r.read()
-                    failures = 0
-                except Exception:
-                    # the gateway being down must never hurt serving
-                    failures = min(failures + 1, 5)
-                delay = interval_seconds * (2 ** failures if failures else 1)
-                # +/-25% jitter de-synchronizes the fleet after an outage
-                await asyncio.sleep(delay * (0.75 + 0.5 * random.random()))
+    def add_seconds(self, name: str, label: str, entries) -> None:
+        """Durations of family `name`_seconds that is kept as `_count`
+        and `_sum` alone (a summary without quantiles), one series per
+        value of its one `label`: each of `entries` starts (label value,
+        seconds). Any number of them under one lock and with no key to
+        format: for a path too hot for a histogram's buckets and
+        exemplars, which nothing reads for such a family."""
+        with self._lock:
+            fam = self._seconds.get(name)
+            if fam is None:
+                fam = self._seconds[name] = (label, {})
+            totals = fam[1]
+            for entry in entries:
+                rec = totals.get(entry[0])
+                if rec is None:
+                    totals[entry[0]] = [1, entry[1]]
+                else:
+                    rec[0] += 1
+                    rec[1] += entry[1]
 
     def timed(self, name: str, labels: dict | None = None):
         return _Timer(self, name, labels)
@@ -255,11 +247,18 @@ class Registry:
                                  f"{self._hist_sum[key]}")
                     lines.append(f"{p}_{name}_seconds_count{lbl} "
                                  f"{self._hist_count[key]}")
+            for name, (label, totals) in sorted(self._seconds.items()):
+                lines.append(f"# TYPE {p}_{name}_seconds summary")
+                for value, (count, seconds) in sorted(totals.items()):
+                    lbl = f'{{{label}="{_escape(value)}"}}'
+                    lines.append(f"{p}_{name}_seconds_sum{lbl} {seconds}")
+                    lines.append(f"{p}_{name}_seconds_count{lbl} {count}")
             return "\n".join(lines) + "\n"
 
     def is_empty(self) -> bool:
         with self._lock:
-            return not (self._counters or self._gauges or self._hist)
+            return not (self._counters or self._gauges or self._hist
+                        or self._seconds)
 
 
 # --- process-wide shared registries ---

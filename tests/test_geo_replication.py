@@ -458,6 +458,10 @@ def test_active_active_pair_converges_without_looping(pair):
         # ping-pong would keep both sides' counters climbing
         jobs = (pair.primary.master.geo.jobs[bucket],
                 rmaster.geo.jobs[bucket])
+        # a job counts an event a moment after the object it wrote can
+        # be read: let each side count its one before the snapshot
+        wait_until(lambda: all(j.status()["applied"] >= 1 for j in jobs),
+                   timeout=30, what="both sides counted their apply")
         counts = [j.status()["applied"] for j in jobs]
         time.sleep(2.0)
         assert [j.status()["applied"] for j in jobs] == counts

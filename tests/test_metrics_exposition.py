@@ -92,3 +92,22 @@ def test_type_lines_once_per_family_and_contiguous():
                 name = name[:-len(suffix)]
                 break
         assert name == current, f"sample {ln!r} outside family {current}"
+
+
+def test_seconds_family_is_a_count_and_a_sum_a_label_value():
+    """add_seconds: any number of durations under one lock, rendered as
+    a summary without quantiles; longer entries are read by their first
+    two fields."""
+    r = Registry("t")
+    assert r.is_empty()
+    r.add_seconds("stage", "stage", [("a.b", 0.25), ("a.c", 1.0, "more"),
+                                     ("a.b", 0.5)])
+    r.add_seconds("stage", "stage", (('q"x', 2.0),))
+    assert not r.is_empty()
+    text = r.render()
+    assert text.count("# TYPE seaweedfs_tpu_t_stage_seconds summary") == 1
+    assert 'seaweedfs_tpu_t_stage_seconds_sum{stage="a.b"} 0.75' in text
+    assert 'seaweedfs_tpu_t_stage_seconds_count{stage="a.b"} 2' in text
+    assert 'seaweedfs_tpu_t_stage_seconds_count{stage="a.c"} 1' in text
+    assert 'seaweedfs_tpu_t_stage_seconds_sum{stage="q\\"x"} 2.0' in text
+    assert "_bucket" not in text
