@@ -467,6 +467,8 @@ def main() -> None:
             raise SystemExit(f"shards {lost} not all removed: {gone}")
         counter = "seaweedfs_tpu_ec_reconstruct_intervals_total"
         seen = [cluster.metric(counter)]
+        inline = "seaweedfs_tpu_volume_ec_read_inline_total"
+        inline_before = cluster.metric(inline)
         rec_times: list[float] = []  # GETs that reconstructed an interval
 
         def after_get(seconds: float) -> None:
@@ -480,8 +482,18 @@ def main() -> None:
             raise SystemExit(f"{len(rec_times)} of {len(cold_sample)} "
                              "degraded GETs reconstructed an interval, "
                              "wanted >= 2")
+        # the fast path answers an EC GET itself: none took the hop to
+        # the aiohttp plane
+        answered_inline = int(cluster.metric(inline) - inline_before)
+        proxied = int(cluster.metric(
+            "seaweedfs_tpu_volume_ec_read_proxied_total"))
+        if proxied or answered_inline < len(cold_sample):
+            raise SystemExit(f"{answered_inline} of {len(cold_sample)} "
+                             f"degraded GETs answered on the fast path, "
+                             f"{proxied} EC GETs proxied")
         info["degraded"] = {
             "lost": lost, "reconstructed_intervals": int(seen[-1] - seen[0]),
+            "answered_inline": answered_inline, "proxied": proxied,
             "reconstructing_gets": len(rec_times),
             "first_get_s": round(rec_times[0], 3),
             "second_get_s": round(rec_times[1], 3),

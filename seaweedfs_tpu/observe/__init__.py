@@ -314,9 +314,10 @@ class stage:
     under the span that is ambient. ``enclosing`` marks a stage
     that only wraps stages named on their own: it is kept out of the
     request's wide event, where the largest entry is taken for the
-    dominant one and an enclosing one always is; and the outermost such
-    stage of a context gathers the stages that end under it as its
-    children, and pays for them all at its exit."""
+    dominant one and an enclosing one always is; and it gathers the
+    stages that end under it as its children, and pays for them all at
+    its exit. One opened under another (`ec.get.handler` in `ec.get`)
+    is that one's child and gathers in its place until it closes."""
 
     __slots__ = ("_name", "_ctx", "_tags", "_enclosing", "_note",
                  "_start", "_t0", "_over")
@@ -329,7 +330,7 @@ class stage:
         self._enclosing = enclosing
 
     def __enter__(self) -> "stage":
-        if self._enclosing and self._ctx is None and _open.get() is None:
+        if self._enclosing and self._ctx is None:
             self._over = _open.set((next(_id_seq), [], _trace_id.get(),
                                     _service.get(), _instance.get()))
         else:
@@ -349,14 +350,15 @@ class stage:
             return
         sid, under, trace, svc, inst = _open.get()
         _open.reset(self._over)
+        outer = _open.get()
         acc = wideevents.current()
         if acc is not None:
             stages = acc["stages"]
             for row in under:
                 stages[row[0]] = stages.get(row[0], 0) + int(row[1] * 1e6)
         under.append((self._name, seconds, self._start, trace, sid,
-                      _span_id.get(), svc, inst, threading.get_ident(),
-                      self._tags))
+                      _span_id.get() if outer is None else outer[0], svc,
+                      inst, threading.get_ident(), self._tags))
         _ring.extend(under)
         if self._name.startswith("ec."):
             _EC_STAGES.add_seconds("stage", "stage", under)

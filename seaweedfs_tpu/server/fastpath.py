@@ -12,9 +12,16 @@ protocol sharing the SAME store/batcher/guard objects as the aiohttp app.
 Everything that is not the hot common case transparently proxies over a
 loopback connection to the unchanged aiohttp app: the admin/EC/status
 surface, and rare data-path shapes (Range requests, image resize,
-chunked/Expect bodies, replicated-volume writes, EC volumes, read
-repair/redirect on miss). Correctness stays in exactly one place; the
-fast path only re-implements the straight-line read and write.
+chunked/Expect bodies, replicated-volume writes, EC writes and deletes,
+read repair/redirect on miss). Correctness stays in exactly one place;
+the fast path only re-implements the straight-line read and write.
+
+An EC GET of the plain shape (GET or HEAD, no Range, no resize) is
+answered here as well: `VolumeServer.read_ec_needle`, the one EC read
+both planes call, and the response code of a plain needle. A Range or a
+resize of an EC needle, and one whose CRC fails (the repair logic is the
+aiohttp side's), take the hop; `ec_read_inline` / `ec_read_proxied` on
+/metrics count the two.
 """
 
 from __future__ import annotations
@@ -33,7 +40,7 @@ from ..security.guard import token_from_request
 from ..storage.file_id import FileId
 from ..storage.needle import (FLAG_HAS_LAST_MODIFIED, FLAG_HAS_MIME,
                               FLAG_HAS_NAME, FLAG_HAS_TTL,
-                              FLAG_IS_COMPRESSED, Needle)
+                              FLAG_IS_COMPRESSED, CrcError, Needle)
 from ..storage.volume import NeedleDeleted, NeedleExpired, NeedleNotFound
 from ..storage import types as t
 from ..utils import compression, fast_multipart
@@ -46,6 +53,8 @@ _PROXY_EXACT = {"/status", "/metrics", "/healthz", "/ui", "", "/"}
 _PROXY_PREFIX = ("/admin/", "/debug/")
 
 _E404 = json.dumps({"error": "not found"}).encode()
+_E404_DELETED = json.dumps({"error": "deleted"}).encode()
+_E404_DROP = json.dumps({"error": "injected drop"}).encode()
 _E400 = json.dumps({"error": "missing file id"}).encode()
 
 # _admission_gate answered a shed response itself; no ticket to release
@@ -497,14 +506,19 @@ class FastVolumeProtocol(asyncio.Protocol):
                     headers: dict, raw: bytes) -> None:
         server = self.server
         vol = server.store.find_volume(fid.volume_id)
+        rare = b"range" in headers or q.get("width") or q.get("height")
         if (vol is None
                 and server.store.find_ec_volume(fid.volume_id) is not None):
-            # an EC GET is served by the aiohttp side: `ec.get` is its
-            # whole residence here, `ec.get.handler` the part over there
+            # an EC GET: `ec.get` is its whole residence here. The plain
+            # shape is answered in place; the rare ones, and a needle
+            # whose CRC failed, by the aiohttp side, whose
+            # `ec.get.handler` is then the part over there
             with observe.stage("ec.get", enclosing=True):
-                await self._proxy(raw)
+                if rare or not await self._read_ec(method, fid, headers):
+                    server.metrics.count("ec_read_proxied")
+                    await self._proxy(raw)
             return
-        if (b"range" in headers or q.get("width") or q.get("height")):
+        if rare:
             await self._proxy(raw)  # rare shapes: aiohttp path
             return
         if vol is None:
@@ -524,7 +538,7 @@ class FastVolumeProtocol(asyncio.Protocol):
                 return
             except NeedleDeleted:
                 server.metrics.count("read")
-                self._send(404, json.dumps({"error": "deleted"}).encode())
+                self._send(404, _E404_DELETED)
                 return
             except (NeedleNotFound, KeyError):
                 await self._proxy(raw)  # read-repair / replica logic
@@ -544,7 +558,7 @@ class FastVolumeProtocol(asyncio.Protocol):
             return
         except NeedleDeleted:
             server.metrics.count("read")
-            self._send(404, json.dumps({"error": "deleted"}).encode())
+            self._send(404, _E404_DELETED)
             return
         except (NeedleNotFound, KeyError):
             await self._proxy(raw)  # read-repair / replica logic counts
@@ -564,8 +578,7 @@ class FastVolumeProtocol(asyncio.Protocol):
         try:
             if await faults.fire_async("volume.read"):
                 server.metrics.count("read")
-                self._send(404, json.dumps({"error": "injected drop"}
-                                           ).encode())
+                self._send(404, _E404_DROP)
                 return
         except faults.FaultError as e:
             server.metrics.count("read")
@@ -584,6 +597,38 @@ class FastVolumeProtocol(asyncio.Protocol):
         # lifecycle heat: the inline fast shape must feed the same
         # tracker as the aiohttp handler or hot volumes look cold
         server.heat.record_read(fid.volume_id)
+        self._send_needle(method, n, headers)
+
+    async def _read_ec(self, method: str, fid: FileId,
+                       headers: dict) -> bool:
+        """An EC GET of the plain shape, answered in place under
+        `ec.get.handler` (which here closes within `ec.get`, so what the
+        two differ by is what a second plane would cost). False, with
+        nothing sent, for a needle whose CRC failed: the caller proxies
+        it to the repair logic of the aiohttp side, which reads it again
+        (that rare GET meets the fault point and the counters twice)."""
+        server = self.server
+        with observe.stage("ec.get.handler", enclosing=True):
+            try:
+                if await server.read_ec_needle(
+                        fid, lambda n: self._send_needle(
+                            method, n, headers) or True) is None:
+                    self._send(404, _E404_DROP)
+            except CrcError:
+                return False
+            except faults.FaultError as e:
+                self._send(500, json.dumps({"error": str(e)}).encode())
+            except NeedleDeleted:
+                self._send(404, _E404_DELETED)
+            except (NeedleExpired, NeedleNotFound, KeyError):
+                self._send(404, _E404)
+        server.metrics.count("ec_read_inline")
+        return True
+
+    def _send_needle(self, method: str, n: Needle, headers: dict) -> None:
+        """A read needle as the response (the aiohttp side's `_respond`
+        less Range and resize, which never come here): etag / 304,
+        headers, gzip verbatim or decompressed, HEAD."""
         etag = f'"{n.etag()}"'
         if headers.get(b"if-none-match", b"").decode("latin-1") == etag:
             self._send(304, b"")
@@ -608,6 +653,7 @@ class FastVolumeProtocol(asyncio.Protocol):
             head = (f"HTTP/1.1 200 OK\r\nContent-Type: {mime}\r\n"
                     f"Content-Length: {len(body)}\r\n"
                     f"{''.join(extra)}\r\n")
+            self._status = 200
             self.transport.write(head.encode("latin-1"))
             return
         self._send(200, body, ctype=mime, extra="".join(extra))
@@ -632,8 +678,7 @@ class FastVolumeProtocol(asyncio.Protocol):
         try:
             if await faults.fire_async("volume.read"):
                 server.metrics.count("read")
-                self._send(404, json.dumps({"error": "injected drop"}
-                                           ).encode())
+                self._send(404, _E404_DROP)
                 return
         except faults.FaultError as e:
             server.metrics.count("read")

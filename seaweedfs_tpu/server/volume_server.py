@@ -28,7 +28,6 @@ then POSTs the same body to every replica; all must ack.
 from __future__ import annotations
 
 import asyncio
-import contextlib
 import contextvars
 import email.parser
 import functools
@@ -303,6 +302,10 @@ class VolumeServer:
         self.admission = overload.AdmissionController(
             "volume", metrics=self.metrics,
             system_paths=overload.VOLUME_SYSTEM_PATHS)
+        # which plane answered an EC GET (server/fastpath.py): both
+        # counters on /metrics from the start, a 0 and not an absence
+        self.metrics.count("ec_read_inline", 0)
+        self.metrics.count("ec_read_proxied", 0)
         self.app = self._build_app()
         # the EC read path fetches missing shards from peers through this
         store._remote_shard_reader = self._make_shard_reader
@@ -689,27 +692,43 @@ class VolumeServer:
 
     async def _read(self, request: web.Request, fid: FileId) -> web.Response:
         """GetOrHeadHandler (volume_server_handlers_read.go:28-272)."""
-        self.metrics.count("read")
         if (self.store.find_volume(fid.volume_id) is not None
                 or self.store.find_ec_volume(fid.volume_id) is None):
+            self.metrics.count("read")
             return await self._read_needle(request, fid)
         # an EC GET: every stage of it is named (observe.stage; PERF.md
-        # has the table). `ec.get.handler` is this side of the fast
-        # path's hop, and the whole of `ec.get` where there was no hop.
+        # has the table). The fast path answers the plain shape itself
+        # through the same `read_ec_needle`; what arrives here is a
+        # Range, a resize, a needle whose CRC failed over there, a shard
+        # fleet sibling's request, or every EC GET of a server that runs
+        # without a fast path. Behind the fast path's hop `ec.get` is
+        # open over there and `ec.get.handler` is this side of it.
         if request.headers.get("X-Swfs-Internal") == self._internal_token:
             with observe.stage("ec.get.handler", enclosing=True):
-                return await self._read_needle(request, fid, ec=True)
+                return await self._read_ec(request, fid)
         with observe.stage("ec.get", enclosing=True), \
                 observe.stage("ec.get.handler", enclosing=True):
-            return await self._read_needle(request, fid, ec=True)
+            return await self._read_ec(request, fid)
 
-    def _ec_read_needle(self, fid: FileId) -> asyncio.Future:
-        """The EC read in the executor, under a copy of the request's
+    async def read_ec_needle(self, fid: FileId, respond):
+        """The read of one EC GET, for whichever data plane answers it
+        (nothing of aiohttp in here; the caller holds `ec.get.handler`
+        open): the `volume.read` fault point, the read counter and
+        timer, the read itself, heat, and `respond(needle)`, which makes
+        the answer of the caller's plane. Gives what `respond` gave, or
+        None for an injected drop; raises FaultError, or what the store
+        raises (NeedleExpired, NeedleNotFound / KeyError, NeedleDeleted,
+        CrcError).
+
+        The read runs in the executor under a copy of the request's
         context: its stages are children of the open `ec.get.handler`
-        and reach the request's wide event. The hand-off is a stage,
-        `ec.get.queue` from the submit to the worker's first line. The
-        future gives the needle and the instant of the worker's last
-        line (wall clock, then perf_counter), for `ec.get.resume`."""
+        and reach the request's wide event. Both hand-offs are stages:
+        `ec.get.queue` from the submit to the worker's first line,
+        `ec.get.resume` from the worker's last line, over the wait for
+        the loop, to the response in hand."""
+        self.metrics.count("read")
+        if await faults.fire_async("volume.read"):
+            return None
         submit_us = int(time.time() * 1e6)
         t_submit = time.perf_counter()
 
@@ -718,13 +737,45 @@ class VolumeServer:
                 "ec.get.queue", None, submit_us,
                 int((time.perf_counter() - t_submit) * 1e6))
             n = self.store.read_needle(fid.volume_id, fid.key, fid.cookie)
-            return n, (time.time(), time.perf_counter())
+            return n, time.time(), time.perf_counter()
 
-        return asyncio.get_event_loop().run_in_executor(
-            None, contextvars.copy_context().run, work)
+        with self.metrics.timed("read"):
+            n, done_s, t_done = await asyncio.get_event_loop(
+                ).run_in_executor(None, contextvars.copy_context().run, work)
+        # lifecycle heat: EC reads are the warm tier's un-EC signal
+        self.heat.record_read(fid.volume_id)
+        resp = respond(n)
+        observe.record_span("ec.get.resume", None, int(done_s * 1e6),
+                            int((time.perf_counter() - t_done) * 1e6))
+        return resp
 
-    async def _read_needle(self, request: web.Request, fid: FileId,
-                           ec: bool = False) -> web.Response:
+    async def _read_ec(self, request: web.Request,
+                       fid: FileId) -> web.Response:
+        """`read_ec_needle` for the aiohttp plane (`ec.get.handler`
+        stands where `volume.read` does for a plain volume: a span that
+        wraps the stages would be the largest entry of the wide event
+        whatever the GET waited for)."""
+        try:
+            resp = await self.read_ec_needle(
+                fid, lambda n: self._respond(request, n))
+        except faults.FaultError as e:
+            return web.json_response({"error": str(e)}, status=500)
+        except NeedleDeleted:  # (a KeyError too: ahead of it)
+            return web.json_response({"error": "deleted"}, status=404)
+        except (NeedleExpired, NeedleNotFound, KeyError):
+            return web.json_response({"error": "not found"}, status=404)
+        except CrcError as rot:
+            n = await self._repair_rot(fid, rot)
+            if n is None:
+                return web.json_response({"error": "data corruption"},
+                                         status=500)
+            return self._respond(request, n)
+        if resp is None:
+            return web.json_response({"error": "injected drop"}, status=404)
+        return resp
+
+    async def _read_needle(self, request: web.Request,
+                           fid: FileId) -> web.Response:
         try:
             if await faults.fire_async("volume.read"):
                 # injected drop: the needle "isn't here" — clients fall
@@ -733,13 +784,8 @@ class VolumeServer:
                                          status=404)
         except faults.FaultError as e:
             return web.json_response({"error": str(e)}, status=500)
-        worker_done = None
-        # (an EC GET's `ec.get.handler` stands where `volume.read` would:
-        # a span that wraps the stages would be the largest entry of the
-        # wide event whatever the GET waited for)
         with self.metrics.timed("read"), \
-                (contextlib.nullcontext() if ec else
-                 observe.span("volume.read", tags={"fid": str(fid)})):
+                observe.span("volume.read", tags={"fid": str(fid)}):
             try:
                 # small needles (the request-rate-bound workload) read
                 # inline: a page-cache pread is microseconds while the
@@ -747,12 +793,10 @@ class VolumeServer:
                 # variant declines (None) for big needles, contended locks
                 # (vacuum), or non-local backends (tiered volumes) so the
                 # loop never blocks on real IO.
-                vol = None if ec else self.store.find_volume(fid.volume_id)
+                vol = self.store.find_volume(fid.volume_id)
                 n = (vol.read_needle_nowait(fid.key, fid.cookie)
                      if vol is not None else None)
-                if n is None and ec:
-                    n, worker_done = await self._ec_read_needle(fid)
-                elif n is None:
+                if n is None:
                     n = await asyncio.get_event_loop().run_in_executor(
                         None, lambda: self.store.read_needle(
                             fid.volume_id, fid.key, fid.cookie))
@@ -789,34 +833,28 @@ class VolumeServer:
             except NeedleDeleted:
                 return web.json_response({"error": "deleted"}, status=404)
             except CrcError as rot:
-                # on-disk corruption (bit-rot / torn write) on a volume
-                # we host: repair from a healthy replica and serve the
-                # good copy instead of surfacing the rot to the client.
-                # The repair re-appends the intact needle locally (the
-                # corrupt bytes become vacuumable garbage) and the event
-                # is reported for the scrubber/operators via metric+log.
-                self.metrics.count("read_crc_repair")
-                log.error("volume %d: CRC mismatch on needle %s (%s); "
-                          "attempting read-repair from replicas",
-                          fid.volume_id, fid, rot)
-                repaired = None
-                if self._repair_permitted(str(fid)):
-                    repaired = await self._read_repair(fid)
-                if repaired is None:
+                n = await self._repair_rot(fid, rot)
+                if n is None:
                     return web.json_response(
                         {"error": "data corruption"}, status=500)
-                n = repaired
-        # lifecycle heat: one dict update per served read (EC reads —
-        # the warm tier's un-EC signal — land here too)
+        # lifecycle heat: one dict update per served read
         self.heat.record_read(fid.volume_id)
-        resp = self._respond(request, n)
-        if worker_done is not None:
-            # the way back: from the worker's last line, over the wait
-            # for the loop, to the response in hand
-            observe.record_span(
-                "ec.get.resume", None, int(worker_done[0] * 1e6),
-                int((time.perf_counter() - worker_done[1]) * 1e6))
-        return resp
+        return self._respond(request, n)
+
+    async def _repair_rot(self, fid: FileId, rot: CrcError):
+        """On-disk corruption (bit-rot / torn write) on a volume we
+        host: repair from a healthy replica and serve the good copy
+        instead of surfacing the rot to the client. The repair
+        re-appends the intact needle locally (the corrupt bytes become
+        vacuumable garbage) and the event is reported for the
+        scrubber/operators via metric+log. None when nothing repaired."""
+        self.metrics.count("read_crc_repair")
+        log.error("volume %d: CRC mismatch on needle %s (%s); "
+                  "attempting read-repair from replicas",
+                  fid.volume_id, fid, rot)
+        if self._repair_permitted(str(fid)):
+            return await self._read_repair(fid)
+        return None
 
     @staticmethod
     def _respond(request: web.Request, n) -> web.Response:

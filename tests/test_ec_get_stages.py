@@ -107,10 +107,12 @@ class Served:
     def fid(needle_id: int, vid: int = 1) -> str:
         return str(FileId(vid, needle_id, COOKIE))
 
-    def get(self, path: str, trace: str = "") -> bytes:
+    def get(self, path: str, trace: str = "", headers=None) -> bytes:
+        headers = dict(headers or {})
+        if trace:
+            headers["X-Seaweed-Trace"] = trace + ":"
         req = urllib.request.Request(
-            f"http://127.0.0.1:{self.port}/{path}",
-            headers={"X-Seaweed-Trace": trace + ":"} if trace else {})
+            f"http://127.0.0.1:{self.port}/{path}", headers=headers)
         with urllib.request.urlopen(req, timeout=60) as r:
             return r.read()
 
@@ -119,6 +121,14 @@ class Served:
         return {m.group(1): int(float(m.group(2))) for m in re.finditer(
             r'^seaweedfs_tpu_ec_stage_seconds_count\{stage="([^"]+)"\} '
             r'(\S+)$', text, re.M)}
+
+    def plane_counts(self) -> tuple[int, int]:
+        """EC GETs answered by the fast path itself, and handed on."""
+        text = self.get("metrics").decode()
+        return tuple(int(float(m.group(1))) if m else 0 for m in (
+            re.search(rf"^seaweedfs_tpu_volume_ec_read_{which}_total "
+                      r"(\S+)$", text, re.M)
+            for which in ("inline", "proxied")))
 
     def stop(self) -> None:
         asyncio.run_coroutine_threadsafe(self.runner.cleanup(),
@@ -166,10 +176,84 @@ def test_degraded_get_is_one_tree_with_every_stage(served):
     ids = {s["id"] for s in spans}
     assert all(s["parent"] in ids for s in spans if s["parent"])
     # every exclusive stage, those of the worker thread too, is a child
-    # of the enclosing stage of its side of the hop, which stands where
-    # `volume.read` does for a plain volume
+    # of `ec.get.handler`, which stands where `volume.read` does for a
+    # plain volume
     by_id = {s["id"]: s for s in spans}
     assert got["volume.read"] == 0
+    for s in spans:
+        if s["name"].startswith("ec.get.") and s["name"] != "ec.get.handler":
+            assert by_id[s["parent"]]["name"] == "ec.get.handler", s
+    # one plane: the fast path answers, and nothing of this GET reached
+    # the aiohttp listener, whose middleware would have left a `GET /..`
+    handler = next(s for s in spans if s["name"] == "ec.get.handler")
+    assert by_id[handler["parent"]]["name"] == "ec.get"
+    whole = next(s for s in spans if s["name"] == "ec.get")
+    assert by_id[whole["parent"]]["name"].startswith("fast GET /")
+    assert not any(s["name"].startswith("GET /") for s in spans)
+
+
+def test_inline_get_pays_for_no_second_plane(served):
+    """`ec.get` less `ec.get.handler` is what a second plane costs a
+    GET: where the fast path answers, next to nothing."""
+    gaps = []
+    for i in range(5):
+        trace = f"gap{i}"
+        served.get(served.fid(served.present), trace=trace)
+        by_name = {s["name"]: s for s in trace_of(trace)}
+        gaps.append(by_name["ec.get"]["dur_us"]
+                    - by_name["ec.get.handler"]["dur_us"])
+    assert all(g >= 0 for g in gaps)
+    # (the least of five: a loaded machine may take the thread away
+    # between the two exits once)
+    assert min(gaps) < 300, gaps
+
+
+def test_plane_counters_say_which_plane_answered(served, monkeypatch):
+    from seaweedfs_tpu.server.fastpath import FastVolumeProtocol
+    hops = []
+    real = FastVolumeProtocol._proxy
+
+    async def counted(self, raw, port=None):
+        hops.append(raw.split(b"\r\n", 1)[0])
+        return await real(self, raw, port=port)
+
+    monkeypatch.setattr(FastVolumeProtocol, "_proxy", counted)
+    inline0, proxied0 = served.plane_counts()
+    served.get(served.fid(served.lost))
+    served.get(served.fid(served.present))
+    with pytest.raises(urllib.error.HTTPError) as miss:
+        served.get(served.fid(N_NEEDLES + 7))
+    assert miss.value.code == 404
+    # (the error holds its connection, which the server's stop waits for)
+    miss.value.close()
+    # the three /metrics reads take the hop; no EC GET did
+    assert [h for h in hops if b"/metrics" not in h] == []
+    assert served.plane_counts() == (inline0 + 3, proxied0)
+    assert served.get(served.fid(served.present),
+                      headers={"Range": "bytes=10-19"}) \
+        == payload(served.present - 1)[10:20]
+    assert served.plane_counts() == (inline0 + 3, proxied0 + 1)
+    assert sum(1 for h in hops if b"/metrics" not in h) == 1
+    # a plain volume's GET is neither
+    served.get(served.fid(1, vid=2))
+    assert served.plane_counts() == (inline0 + 3, proxied0 + 1)
+
+
+def test_range_get_takes_the_hop_and_records_both_sides(served):
+    """The rare shapes stay with the aiohttp plane: `ec.get` is the
+    residence on the fast path's side of the hop, `ec.get.handler` the
+    part over there, as for every EC GET before the fast path answered
+    any."""
+    assert served.get(served.fid(served.lost), trace="range1",
+                      headers={"Range": "bytes=0-99"}) \
+        == payload(served.lost - 1)[:100]
+    spans = trace_of("range1")
+    got = names(spans)
+    for stage, count in {**PER_GET, **PER_LOST_INTERVAL}.items():
+        assert got[stage] == count, (stage, got)
+    by_id = {s["id"]: s for s in spans}
+    roots = [s for s in spans if not s["parent"]]
+    assert [s["name"].split()[0:2] for s in roots] == [["fast", "GET"]]
     for s in spans:
         if s["name"].startswith("ec.get.") and s["name"] != "ec.get.handler":
             assert by_id[s["parent"]]["name"] == "ec.get.handler", s
@@ -177,6 +261,7 @@ def test_degraded_get_is_one_tree_with_every_stage(served):
     assert by_id[handler["parent"]]["name"].startswith("GET /")
     whole = next(s for s in spans if s["name"] == "ec.get")
     assert by_id[whole["parent"]]["name"].startswith("fast GET /")
+    assert whole["dur_us"] > handler["dur_us"]
 
 
 def test_debug_trace_serves_the_same_tree(served):
@@ -371,7 +456,8 @@ def test_stages_under_an_enclosing_one_are_paid_for_at_its_exit():
     assert [s["parent"] for s in spans if s["name"] == "ec.test.under"] \
         == [by_name["ec.test.whole"]["id"]] * 2
     assert by_name["ec.test.whole"]["parent"] == "rootspan"
-    assert by_name["ec.test.inner"]["parent"] == "rootspan"
+    # an enclosing stage under another is that one's child
+    assert by_name["ec.test.inner"]["parent"] == by_name["ec.test.whole"]["id"]
     assert observe.spans(trace_id="fold1") == spans  # ids read the same
     assert stage_totals()["ec.test.under"][0] == before + 2
     assert set(acc["stages"]) == {"ec.test.under"}

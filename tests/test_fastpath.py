@@ -28,12 +28,13 @@ def _free_port() -> int:
 class _Srv:
     """run_volume_server in a background loop thread."""
 
-    def __init__(self, tmpdir: str, whitelist=None):
+    def __init__(self, tmpdir: str, whitelist=None, store=None, **kwargs):
         self.port = _free_port()
-        self.store = Store([tmpdir])
-        self.store.add_volume(1)
+        if store is None:
+            store = Store([tmpdir])
+            store.add_volume(1)
+        self.store = store
         self.loop = asyncio.new_event_loop()
-        kwargs = {}
         if whitelist is not None:
             from seaweedfs_tpu.security.guard import Guard
             kwargs["guard"] = Guard(whitelist=whitelist)
@@ -580,3 +581,232 @@ def test_hot_parse_allocations_pinned():
     # callers treat query dicts as read-only; the shared empty dict
     # must never pick up keys from a request
     assert len(fastpath._EMPTY_QUERY) == 0
+
+
+# --- EC GETs: the fast path answers the plain shape itself ---------------
+# One EC volume, built alike under two servers: the public listener of one
+# is the fast path, the other serves aiohttp alone (fastpath=False), which
+# is the answer the fast path's has to equal.
+
+EC_COOKIE = 0x1234
+EC_PRESENT, EC_LOST, EC_DELETED, EC_GZIP, EC_UNKNOWN = 0, 5, 7, 41, 999
+EC_TEXT = b"a needle stored gzipped\n" * 200
+
+
+def _ec_store(tmpdir: str) -> tuple[Store, dict]:
+    import gzip
+
+    from seaweedfs_tpu.ec.geometry import Geometry
+    from seaweedfs_tpu.storage.needle import (FLAG_HAS_LAST_MODIFIED,
+                                              FLAG_HAS_MIME, FLAG_HAS_NAME,
+                                              FLAG_IS_COMPRESSED, Needle)
+    geometry = Geometry(10, 4, large_block_size=64 * 1024,
+                        small_block_size=4 * 1024)
+    store = Store([tmpdir], coder_name="numpy", geometry=geometry)
+    store.add_volume(1)
+
+    def put(i: int, data: bytes, name: bytes, mime: bytes, gz=False):
+        n = Needle(id=i, cookie=EC_COOKIE, data=data)
+        for flag in (FLAG_HAS_NAME, FLAG_HAS_MIME, FLAG_HAS_LAST_MODIFIED):
+            n.set_flag(flag)
+        if gz:
+            n.set_flag(FLAG_IS_COMPRESSED)
+        n.name, n.mime, n.last_modified = name, mime, 1_700_000_000 + i
+        store.write_needle(1, n)
+
+    for i in range(1, 41):
+        put(i, bytes([i]) * 1000, b"n%d.bin" % i, b"application/x-test")
+    put(EC_GZIP, gzip.compress(EC_TEXT, mtime=0), b"t.txt", b"text/plain",
+        gz=True)
+    store.ec_generate(1)
+    store.ec_mount(1, "", list(range(14)))
+    store.delete_volume(1)
+    ev = store.find_ec_volume(1)
+
+    def shard_of(i: int) -> int:
+        return ev.locate(i)[2][0].to_shard_id_and_offset(geometry)[0]
+
+    lost = shard_of(EC_LOST)
+    present = next(i for i in range(1, 41) if shard_of(i) != lost
+                   and i != EC_DELETED)
+    ev.delete_shard(lost)
+    store.ec_blob_delete(1, EC_DELETED)
+    return store, {EC_PRESENT: present}
+
+
+@pytest.fixture(scope="module")
+def ec_planes(tmp_path_factory):
+    fast_store, ids = _ec_store(str(tmp_path_factory.mktemp("ecfast")))
+    slow_store, _ = _ec_store(str(tmp_path_factory.mktemp("ecslow")))
+    fast = _Srv("", store=fast_store)
+    slow = _Srv("", store=slow_store, fastpath=False)
+    yield fast, slow, ids
+    fast.stop()
+    slow.stop()
+    fast_store.close()
+    slow_store.close()
+
+
+def _ec_fid(needle: int, cookie: int = EC_COOKIE) -> str:
+    return f"/1,{needle:x}{cookie:08x}"
+
+
+def _plane_counts(port: int) -> dict:
+    import re
+    text = _req(port, "GET", "/metrics")[2].decode()
+    return {name: int(float(m.group(1))) if m else 0 for name, m in (
+        (name, re.search(rf"^seaweedfs_tpu_volume_{name}_total (\S+)$",
+                         text, re.M))
+        for name in ("ec_read_inline", "ec_read_proxied", "read"))}
+
+
+_EC_HEADERS = ("etag", "x-last-modified", "content-type", "content-length",
+               "content-disposition", "content-encoding")
+
+
+@pytest.mark.parametrize("case,method,needle,cookie,headers,status", [
+    ("present", "GET", EC_PRESENT, EC_COOKIE, {}, 200),
+    ("lost", "GET", EC_LOST, EC_COOKIE, {}, 200),
+    ("deleted", "GET", EC_DELETED, EC_COOKIE, {}, 404),
+    ("unknown", "GET", EC_UNKNOWN, EC_COOKIE, {}, 404),
+    ("wrong-cookie", "GET", EC_LOST, EC_COOKIE + 1, {}, 404),
+    ("head", "HEAD", EC_PRESENT, EC_COOKIE, {}, 200),
+    ("head-lost", "HEAD", EC_LOST, EC_COOKIE, {}, 200),
+    ("if-none-match", "GET", EC_PRESENT, EC_COOKIE,
+     {"If-None-Match": None}, 304),
+    ("gzip-accepted", "GET", EC_GZIP, EC_COOKIE,
+     {"Accept-Encoding": "gzip, deflate"}, 200),
+    ("gzip-not-accepted", "GET", EC_GZIP, EC_COOKIE, {}, 200),
+    ("head-gzip-not-accepted", "HEAD", EC_GZIP, EC_COOKIE, {}, 200),
+])
+def test_ec_get_answers_alike_on_both_planes(ec_planes, case, method,
+                                             needle, cookie, headers,
+                                             status):
+    fast, slow, ids = ec_planes
+    path = _ec_fid(ids.get(needle, needle), cookie)
+    if "If-None-Match" in headers:
+        headers = {"If-None-Match": _req(fast.port, "GET", path)[1]["etag"]}
+    before = _plane_counts(fast.port)
+    got = _req(fast.port, method, path, headers=headers)
+    after = _plane_counts(fast.port)
+    want = _req(slow.port, method, path, headers=headers)
+    assert got[0] == want[0] == status
+    assert got[2] == want[2]
+    # the fast path answered, and no EC GET went to the aiohttp listener
+    assert after["ec_read_inline"] == before["ec_read_inline"] + 1
+    assert after["ec_read_proxied"] == before["ec_read_proxied"]
+    assert after["read"] == before["read"] + 1
+    if status == 304:
+        # no representation, so no headers of one (the fast path frames
+        # every bodiless answer with `_send`'s Content-Type and a zero
+        # Content-Length, for a plain volume too)
+        assert "etag" not in got[1] and "etag" not in want[1]
+        return
+    for name in _EC_HEADERS:
+        a, b = got[1].get(name), want[1].get(name)
+        if name == "content-type":
+            # aiohttp's JSON errors add "; charset=utf-8"
+            a, b = a.split(";")[0], b.split(";")[0]
+        assert a == b, (name, a, b)
+    if status == 200:
+        assert got[1]["etag"] and got[1]["content-disposition"]
+        assert int(got[1]["x-last-modified"]) > 1_700_000_000
+        if method == "GET":
+            assert int(got[1]["content-length"]) == len(got[2])
+    if case == "gzip-accepted":
+        import gzip
+        assert got[1]["content-encoding"] == "gzip"
+        assert gzip.decompress(got[2]) == EC_TEXT
+    if case == "gzip-not-accepted":
+        assert "content-encoding" not in got[1] and got[2] == EC_TEXT
+    if status == 404:
+        # (a needle deleted from an EC volume reads as absent: its
+        # `.ecx` entry is a tombstone, which `find_needle` takes for none)
+        assert json.loads(got[2]) == {"error": "not found"}
+
+
+@pytest.mark.parametrize("action,status,body", [
+    ("drop", 404, {"error": "injected drop"}),
+    ("error", 500, {"error": "injected fault at volume.read"}),
+    ("delay", 200, None),
+])
+def test_volume_read_fault_acts_once_on_inline_ec_get(ec_planes, action,
+                                                      status, body):
+    import time
+
+    from seaweedfs_tpu import faults
+    fast, _, ids = ec_planes
+    before = _plane_counts(fast.port)
+    faults.clear()
+    faults.set_fault("volume.read", action, ms=200.0)
+    try:
+        t0 = time.time()
+        got = _req(fast.port, "GET", _ec_fid(ids[EC_PRESENT]))
+        took = time.time() - t0
+        fired = [f["fired"] for f in faults.active()]
+    finally:
+        faults.clear()
+    assert got[0] == status
+    # the point fired on the fast path, once, and not again behind a hop
+    assert fired == [1]
+    if body is None:
+        assert got[2] == bytes([ids[EC_PRESENT]]) * 1000
+        assert took >= 0.2
+    else:
+        assert json.loads(got[2]) == body
+    after = _plane_counts(fast.port)
+    assert after["ec_read_inline"] == before["ec_read_inline"] + 1
+    assert after["ec_read_proxied"] == before["ec_read_proxied"]
+    assert after["read"] == before["read"] + 1
+
+
+@pytest.mark.parametrize("raised,status,body,inline,proxied", [
+    ("NeedleDeleted", 404, {"error": "deleted"}, 1, 0),
+    ("NeedleExpired", 404, {"error": "not found"}, 1, 0),
+    # the repair logic is the aiohttp side's: the GET goes on to it
+    ("CrcError", 500, {"error": "data corruption"}, 0, 1),
+])
+def test_what_the_ec_read_raises_maps_as_on_the_aiohttp_plane(
+        ec_planes, monkeypatch, raised, status, body, inline, proxied):
+    from seaweedfs_tpu.storage import needle, volume
+    exc = getattr(volume, raised, None) or getattr(needle, raised)
+    fast, slow, ids = ec_planes
+
+    def read_needle(self, vid, needle_id, cookie=None):
+        raise exc("as the store would")
+
+    monkeypatch.setattr(Store, "read_needle", read_needle)
+    path = _ec_fid(ids[EC_PRESENT])
+    before = _plane_counts(fast.port)
+    got = _req(fast.port, "GET", path)
+    want = _req(slow.port, "GET", path)
+    after = _plane_counts(fast.port)
+    assert got[0] == want[0] == status
+    assert json.loads(got[2]) == json.loads(want[2]) == body
+    assert after["ec_read_inline"] - before["ec_read_inline"] == inline
+    assert after["ec_read_proxied"] - before["ec_read_proxied"] == proxied
+
+
+def test_read_jwt_is_enforced_on_inline_ec_get(tmp_path):
+    from seaweedfs_tpu.security.guard import Guard
+    store, ids = _ec_store(str(tmp_path))
+    guard = Guard(read_signing_key="read-secret")
+    s = _Srv("", store=store, guard=guard)
+    try:
+        path = _ec_fid(EC_LOST)
+        status, _, resp = _req(s.port, "GET", path)
+        assert status == 401 and json.loads(resp) == {
+            "error": "missing read jwt"}
+        status, _, _ = _req(s.port, "GET", path, headers={
+            "Authorization": "BEARER " + guard.sign_read("1,99aaaaaaaa")})
+        assert status == 401
+        assert _plane_counts(s.port)["ec_read_inline"] == 0
+        status, _, got = _req(s.port, "GET", path, headers={
+            "Authorization": "BEARER " + guard.sign_read(path[1:])})
+        assert status == 200 and got == bytes([EC_LOST]) * 1000
+        counts = _plane_counts(s.port)
+        assert counts["ec_read_inline"] == 1
+        assert counts["ec_read_proxied"] == 0
+    finally:
+        s.stop()
+        store.close()
