@@ -18,7 +18,7 @@ from __future__ import annotations
 import os
 import threading
 import time
-from concurrent.futures import ThreadPoolExecutor
+from concurrent.futures import ThreadPoolExecutor, as_completed
 from typing import Callable, Optional
 
 import numpy as np
@@ -295,20 +295,27 @@ class EcVolume:
                 remote_candidates.append(sid)
             need = self.g.data_shards - have
             if need > 0 and shard_reader is not None and remote_candidates:
-                futs = {sid: _SURVIVOR_POOL.submit(shard_reader, sid,
-                                                   offset, size)
+                # each fetch under the request's trace; taken as they
+                # complete, so that a shard nobody holds (its reader
+                # asks the master before it answers None) never holds
+                # up the survivors that are already in hand
+                ctx = observe.capture()
+                futs = {_SURVIVOR_POOL.submit(observe.run_with, ctx,
+                                              shard_reader, sid, offset,
+                                              size): sid
                         for sid in remote_candidates}
-                for sid, fut in futs.items():
-                    if have >= self.g.data_shards:
-                        fut.cancel()
-                        continue
+                for fut in as_completed(futs):
                     try:
                         b = fut.result()
                     except Exception:
                         continue
                     if b is not None and len(b) == size:
-                        shards[sid] = np.frombuffer(b, dtype=np.uint8)
+                        shards[futs[fut]] = np.frombuffer(b, dtype=np.uint8)
                         have += 1
+                        if have >= self.g.data_shards:
+                            break
+                for fut in futs:
+                    fut.cancel()
         if have < self.g.data_shards:
             raise IOError(
                 f"cannot reconstruct shard {missing_shard}: "

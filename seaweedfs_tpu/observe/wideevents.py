@@ -252,6 +252,7 @@ _STAGE_BUCKETS: tuple[tuple[str, str], ...] = (
     ("ec.get.survivors", "disk"),
     ("ec.get.parse", "disk"),
     ("ec.get.peer_fetch", "remote-hop"),
+    ("ec.get.remote_read", "remote-hop"),
     ("ec.get.queue", "admission-queue"),
     ("ec.get.resume", "admission-queue"),
     ("ec.get.flight_wait", "lock"),

@@ -278,11 +278,14 @@ async def serve_master_grpc(master, host: str, port: int, tls=None):
                                 guard=lambda: master.guard,
                                 trace_instance=master.url),))
     creds = tls.grpc_server_credentials() if tls is not None else None
+    # gRPC keeps the low 16 bits of a port, when it listens and when it
+    # dials: beside an HTTP port above 55535 the +10000 convention lands
+    # on (port + 10000) - 65536, which is what the log has to name
     if creds is not None:
-        server.add_secure_port(f"{host}:{port}", creds)
+        bound = server.add_secure_port(f"{host}:{port}", creds)
     else:
-        server.add_insecure_port(f"{host}:{port}")
+        bound = server.add_insecure_port(f"{host}:{port}")
     await server.start()
-    log.info("master gRPC on %s:%d%s", host, port,
+    log.info("master gRPC on %s:%d%s", host, bound,
              " (mtls)" if creds else "")
     return server

@@ -591,11 +591,12 @@ async def serve_volume_grpc(vs, host: str, port: int, tls=None):
                                 guard=lambda: vs.guard,
                                 trace_instance=vs.url),))
     creds = tls.grpc_server_credentials() if tls is not None else None
+    # the port bound, which is not `port` above 65535 (master_grpc.py)
     if creds is not None:
-        server.add_secure_port(f"{host}:{port}", creds)
+        bound = server.add_secure_port(f"{host}:{port}", creds)
     else:
-        server.add_insecure_port(f"{host}:{port}")
+        bound = server.add_insecure_port(f"{host}:{port}")
     await server.start()
-    log.info("volume gRPC on %s:%d%s", host, port,
+    log.info("volume gRPC on %s:%d%s", host, bound,
              " (mtls)" if creds else "")
     return server

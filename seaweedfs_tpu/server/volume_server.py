@@ -306,6 +306,15 @@ class VolumeServer:
         # counters on /metrics from the start, a 0 and not an absence
         self.metrics.count("ec_read_inline", 0)
         self.metrics.count("ec_read_proxied", 0)
+        # what an EC read took from peers (`_make_shard_reader`), and how
+        # often it asked the master where a shard is: born at 0 likewise
+        for via in ("grpc", "http"):
+            self.metrics.count("ec_remote_shard_reads", 0,
+                               labels={"via": via})
+        self.metrics.count("ec_remote_shard_read_bytes", 0)
+        for result in ("holder", "none"):
+            self.metrics.count("ec_shard_location_lookups", 0,
+                               labels={"result": result})
         self.app = self._build_app()
         # the EC read path fetches missing shards from peers through this
         store._remote_shard_reader = self._make_shard_reader
@@ -1675,7 +1684,11 @@ class VolumeServer:
         except Exception as e:
             log.warning("ec shard lookup for %d failed: %s", vid, e)
             shards = cached[0] if cached else {}
-        return [u for u in shards.get(str(shard_id), []) if u != self.url]
+        urls = [u for u in shards.get(str(shard_id), []) if u != self.url]
+        # one blocking round trip to the master, and what it was for
+        self.metrics.count("ec_shard_location_lookups", labels={
+            "result": "holder" if urls else "none"})
+        return urls
 
     def _make_shard_reader(self, ev):
         """Shard reader for non-local shards, used by the EC read path
@@ -1727,11 +1740,8 @@ class VolumeServer:
                     self._peer_grpc_dead[url] = time.time() + 60.0
                 return None
 
-        def fetch(url: str, shard_id: int, offset: int,
-                  size: int) -> Optional[bytes]:
-            data = fetch_grpc(url, shard_id, offset, size)
-            if data is not None:
-                return data
+        def fetch_http(url: str, shard_id: int, offset: int,
+                       size: int) -> Optional[bytes]:
             try:
                 from ..cache import shared_pool
                 r = shared_pool().request(
@@ -1744,6 +1754,27 @@ class VolumeServer:
                 return r.data if len(r.data) == size else None
             except Exception:
                 return None
+
+        def fetch(url: str, shard_id: int, offset: int,
+                  size: int) -> Optional[bytes]:
+            """One interval from one peer: the stage `ec.get.remote_read`
+            (record form: a survivor's fetch ends on a pool thread),
+            within `ec.get.survivors` or `ec.get.peer_fetch`."""
+            start_s, t0 = time.time(), time.perf_counter()
+            via = "grpc"
+            data = fetch_grpc(url, shard_id, offset, size)
+            if data is None:
+                via = "http"
+                data = fetch_http(url, shard_id, offset, size)
+            observe.record_span(
+                "ec.get.remote_read", None, int(start_s * 1e6),
+                int((time.perf_counter() - t0) * 1e6))
+            if data is not None:
+                self.metrics.count("ec_remote_shard_reads",
+                                   labels={"via": via})
+                self.metrics.count("ec_remote_shard_read_bytes",
+                                   value=size)
+            return data
 
         def read(shard_id: int, offset: int, size: int) -> Optional[bytes]:
             for force in (False, True):

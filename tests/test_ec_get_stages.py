@@ -554,6 +554,7 @@ def test_xprof_keep_leaves_the_trace(served, monkeypatch):
     ("ec.get.ecx", "disk"), ("ec.get.shard_read", "disk"),
     ("ec.get.survivors", "disk"), ("ec.get.parse", "disk"),
     ("ec.get.peer_fetch", "remote-hop"),
+    ("ec.get.remote_read", "remote-hop"),
     ("ec.get.queue", "admission-queue"),
     ("ec.get.resume", "admission-queue"),
     ("ec.get.flight_wait", "lock"),
