@@ -469,6 +469,8 @@ def main() -> None:
         seen = [cluster.metric(counter)]
         inline = "seaweedfs_tpu_volume_ec_read_inline_total"
         inline_before = cluster.metric(inline)
+        lookups = 'seaweedfs_tpu_volume_ecx_lookups_total{via="%s"}'
+        mapped_before = cluster.metric(lookups % "mmap")
         rec_times: list[float] = []  # GETs that reconstructed an interval
 
         def after_get(seconds: float) -> None:
@@ -491,9 +493,18 @@ def main() -> None:
             raise SystemExit(f"{answered_inline} of {len(cold_sample)} "
                              f"degraded GETs answered on the fast path, "
                              f"{proxied} EC GETs proxied")
+        # every GET looked its needle up in the mapped .ecx: none paid
+        # a pread a probe
+        mapped = int(cluster.metric(lookups % "mmap") - mapped_before)
+        by_pread = int(cluster.metric(lookups % "pread"))
+        if by_pread or mapped < len(cold_sample):
+            raise SystemExit(f"{mapped} of {len(cold_sample)} degraded "
+                             f"GETs looked the needle up in the mapped "
+                             f".ecx, {by_pread} lookups by pread")
         info["degraded"] = {
             "lost": lost, "reconstructed_intervals": int(seen[-1] - seen[0]),
             "answered_inline": answered_inline, "proxied": proxied,
+            "ecx_lookups_mmap": mapped, "ecx_lookups_pread": by_pread,
             "reconstructing_gets": len(rec_times),
             "first_get_s": round(rec_times[0], 3),
             "second_get_s": round(rec_times[1], 3),

@@ -3,7 +3,11 @@
 Mirrors the reference serving path (weed/storage/erasure_coding/ec_volume.go,
 ec_shard.go, ec_volume_delete.go and weed/storage/store_ec.go:122-376):
 
-- .ecx is binary-searched on disk per lookup (entries sorted by needle id)
+- .ecx (entries sorted by needle id) is binary-searched per lookup in a
+  shared read-only mapping of the file: no syscall a probe, and no copy
+  of the index on the Python side; the file stays the truth (a tombstone
+  is a pwrite through the descriptor, seen through the mapping at once).
+  One os.pread a probe where the file cannot be mapped or WEED_EC_MMAP=0
 - a needle decomposes into intervals (locate.py); each interval is read from
   the local shard file when present, fetched from a peer when not, or
   reconstructed on line from any k shards as the last resort
@@ -15,7 +19,9 @@ bytes | None`; the server layer plugs gRPC fetches in, tests plug files.
 
 from __future__ import annotations
 
+import mmap
 import os
+import struct
 import threading
 import time
 from concurrent.futures import ThreadPoolExecutor, as_completed
@@ -41,6 +47,35 @@ from .locate import Interval, locate_data
 
 ShardReader = Callable[[int, int, int], Optional[bytes]]
 
+# .ecx lookups by the way an entry was fetched, on the volume server's
+# /metrics (seaweedfs_tpu_volume_ecx_lookups_total{via=}); the label
+# dicts are made once, a lookup only counts
+_LOOKUPS = metrics_mod.shared("volume")
+_KEY = struct.Struct(">Q")  # an entry starts with its needle id
+_VIA_MMAP = {"via": "mmap"}
+_VIA_PREAD = {"via": "pread"}
+
+
+def _map_shared(f, size: int) -> Optional[mmap.mmap]:
+    """`f`'s first `size` bytes mapped shared and read-only (one
+    page-cache copy, what the file's writers do is seen at once), or None:
+    an empty file, WEED_EC_MMAP=0, a file that cannot be mapped."""
+    from .feed import use_mmap_default
+    if not size or not use_mmap_default():
+        return None
+    try:
+        return mmap.mmap(f.fileno(), size, mmap.MAP_SHARED, mmap.PROT_READ)
+    except (OSError, ValueError):
+        return None
+
+
+def _unmap(mm: Optional[mmap.mmap]) -> None:
+    if mm is not None:
+        try:
+            mm.close()
+        except BufferError:
+            pass
+
 
 class EcShard:
     """One local .ecNN file (EcVolumeShard, ec_shard.go:16-95).
@@ -55,16 +90,7 @@ class EcShard:
         self.path = base_file_name + to_ext(shard_id)
         self._f = open(self.path, "rb")
         self.size = os.path.getsize(self.path)
-        self._mm = None
-        from .feed import use_mmap_default
-        if self.size and use_mmap_default():
-            import mmap as mmap_mod
-            try:
-                self._mm = mmap_mod.mmap(self._f.fileno(), self.size,
-                                         mmap_mod.MAP_SHARED,
-                                         mmap_mod.PROT_READ)
-            except (OSError, ValueError):
-                self._mm = None
+        self._mm = _map_shared(self._f, self.size)
 
     def read_at(self, offset: int, size: int) -> bytes:
         if self._mm is not None and 0 <= offset and offset + size <= self.size:
@@ -74,12 +100,8 @@ class EcShard:
         return os.pread(self._f.fileno(), size, offset)
 
     def close(self) -> None:
-        if self._mm is not None:
-            try:
-                self._mm.close()
-            except BufferError:
-                pass
-            self._mm = None
+        _unmap(self._mm)
+        self._mm = None
         self._f.close()
 
 
@@ -109,6 +131,14 @@ class EcVolume:
             raise FileNotFoundError(base + ".ecx")
         self._ecx = open(base + ".ecx", "r+b")
         self.ecx_size = os.path.getsize(base + ".ecx")
+        # lookups read the index where it lies in the page cache; writes
+        # (tombstones, here and in rebuild_ecx_file) go through a
+        # descriptor of the same inode and show in the mapping at once.
+        # Whoever replaces the index of a mounted volume must swap the
+        # path and never truncate the inode: a probe past the new end of
+        # a mapped file is a SIGBUS (ec/copy leaves a mounted volume's
+        # index alone for that)
+        self._ecx_mm = _map_shared(self._ecx, self.ecx_size)
         self._ecj = open(base + ".ecj", "a+b")
         # volume version comes from the superblock at the head of .ec00
         # (readEcVolumeVersion, ec_decoder.go:73-90); default v3 if absent
@@ -155,38 +185,47 @@ class EcVolume:
     def live_entries(self) -> list[tuple[int, int]]:
         """Live (needle_id, size) pairs from the sorted .ecx, skipping
         tombstones (the fsck inventory for EC volumes)."""
-        out = []
         with self._lock:
-            n = self.ecx_size // self._entry_size
-            for i in range(n):
-                entry = os.pread(self._ecx.fileno(),
-                                 self._entry_size,
-                                 i * self._entry_size)
-                key, offset, size = idx_mod.unpack_entry(
-                    entry, offset_size=self.offset_size)
-                if not t.size_is_deleted(size):
-                    out.append((key, size))
-        return out
+            index = self._ecx_mm
+            if index is None:
+                index = os.pread(self._ecx.fileno(), self.ecx_size, 0)
+            return [(key, size) for key, _, size
+                    in idx_mod.iter_index_bytes(index, self.offset_size)
+                    if not t.size_is_deleted(size)]
 
     # --- index lookup ---
     def find_needle(self, needle_id: int) -> tuple[int, int]:
-        """(stored_offset, size) via on-disk binary search
+        """(stored_offset, size) via binary search of the sorted index
         (SearchNeedleFromSortedIndex, ec_volume.go:210-235)."""
         return self._search(needle_id)
 
     def _search(self, needle_id: int,
                 on_found: Optional[Callable[[int], None]] = None
                 ) -> tuple[int, int]:
-        lo, hi = 0, self.ecx_size // self._entry_size
+        """One bisect, two ways to fetch an entry: in place in the
+        mapping, which never gives the GIL away, or 16-17 bytes by one
+        os.pread a probe, which does every time. A probe reads the key;
+        offset and size are read at the entry found, now, so a tombstone
+        written a moment ago is what comes back."""
+        mm, fd, width = self._ecx_mm, self._ecx.fileno(), self._entry_size
+        _LOOKUPS.count("ecx_lookups",
+                       labels=_VIA_PREAD if mm is None else _VIA_MMAP)
+        key_at = _KEY.unpack_from
+        buf, off = mm, 0
+        lo, hi = 0, self.ecx_size // width
         while lo < hi:
             mid = (lo + hi) // 2
-            entry = os.pread(self._ecx.fileno(), self._entry_size,
-                             mid * self._entry_size)
-            key, offset, size = idx_mod.unpack_entry(
-                entry, offset_size=self.offset_size)
+            at = mid * width
+            if mm is None:
+                buf = os.pread(fd, width, at)
+            else:
+                off = at
+            key = key_at(buf, off)[0]
             if key == needle_id:
+                _, offset, size = idx_mod.unpack_entry(
+                    buf, off, self.offset_size)
                 if on_found is not None:
-                    on_found(mid * self._entry_size)
+                    on_found(at)
                 return offset, size
             if key < needle_id:
                 lo = mid + 1
@@ -351,6 +390,8 @@ class EcVolume:
             for shard in self.shards.values():
                 shard.close()
             self.shards.clear()
+            _unmap(self._ecx_mm)
+            self._ecx_mm = None
             self._ecx.close()
             self._ecj.close()
 

@@ -96,9 +96,14 @@ def stripe_segments(dat_size: int, g: Geometry,
 def write_sorted_ecx_from_idx(base_file_name: str, ext: str = ".ecx",
                               offset_size: int = t.OFFSET_SIZE) -> None:
     """Generate the sorted EC index from the .idx journal
-    (WriteSortedFileFromIdx, ec_encoder.go:27-54)."""
+    (WriteSortedFileFromIdx, ec_encoder.go:27-54). Written beside the
+    path and swapped in: an EcVolume mounted on this base (an encode
+    tried again after its mount) has the old index mapped, and a mapped
+    file that is truncated kills its readers with SIGBUS."""
     db = SortedNeedleMap.from_idx_file(base_file_name + ".idx", offset_size)
-    db.write_sorted_index(base_file_name + ext)
+    path = base_file_name + ext
+    db.write_sorted_index(path + ".tmp")
+    durable.replace_atomic(path + ".tmp", path)
 
 
 def write_ec_files(base_file_name: str, coder: ErasureCoder,

@@ -376,7 +376,11 @@ class VolumeGrpcServicer:
         base = os.path.join(loc.directory, f"{prefix}{vid}")
         try:
             exts = [ec_mod.to_ext(sid) for sid in request.shard_ids]
-            if request.copy_ecx_file:
+            # a mounted volume keeps its own index (admin_ec_copy says
+            # why); replacing the path would leave its descriptor on an
+            # unlinked inode and lose its later tombstones
+            if (request.copy_ecx_file
+                    and self.store.find_ec_volume(vid) is None):
                 exts += [".ecx", ".ecj"]
             for ext in exts:
                 try:

@@ -315,6 +315,12 @@ class VolumeServer:
         for result in ("holder", "none"):
             self.metrics.count("ec_shard_location_lookups", 0,
                                labels={"result": result})
+        # how an EC volume fetched the .ecx entries of a lookup
+        # (ec/ec_volume.py counts them, in the shared registry of this
+        # subsystem's name): born at 0 too
+        for via in ("mmap", "pread"):
+            metrics_mod.shared("volume").count("ecx_lookups", 0,
+                                               labels={"via": via})
         self.app = self._build_app()
         # the EC read path fetches missing shards from peers through this
         store._remote_shard_reader = self._make_shard_reader
@@ -1586,7 +1592,12 @@ class VolumeServer:
         base = os.path.join(loc.directory, f"{prefix}{vid}")
         try:
             exts = [ec_mod.to_ext(sid) for sid in shard_ids]
-            if copy_ecx:
+            # a server that has the volume mounted has its index and
+            # keeps it: its own tombstones and journal do not give way
+            # to the giver's, and the .ecx it has mapped is not
+            # rewritten under its readers (a truncated mapping is a
+            # SIGBUS at the next probe)
+            if copy_ecx and self.store.find_ec_volume(vid) is None:
                 exts += [".ecx", ".ecj", ".ecm"]
             for ext in exts:
                 async with self._session.get(
