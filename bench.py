@@ -1,42 +1,27 @@
 #!/usr/bin/env python3
-"""Headline benchmark: the RS(10,4) ec.encode PIPELINE on one chip.
+"""Operator checks: host-only and CPU-pinned phases around the EC tier.
 
-The parent orchestrates PHASES, each chip phase in its OWN subprocess
-(one process owns the chip at a time; the parent stays off jax until the
-last chip phase has run), and assembles exactly one JSON line at the end.
+The repo's benchmark is benchmark/run.py (BENCHMARK.json); this file is
+what is left of the pre-benchmark harness and measures nothing the driver
+reads. The parent orchestrates PHASES, the jax-touching ones each in its
+OWN subprocess (one process owns a device at a time), checkpoints every
+phase into BENCH_DETAIL.json as it completes, and prints one JSON line
+at the end.
 
-The chip phases keep a schedule inherited from an earlier accelerator
-link: stage everything first, compile lazily after staging, defer every
-device->host read to the end of the process, warm each staging shape with
-dummy puts. On the v5e of PR 21 none of these orderings moved a rate
-(H2D of a [10, 16 MiB] batch 3.5-3.7 GB/s before and after a compile, an
-AOT warm, a window execution and a D2H; first window dispatch 0.11 s
-against 0.019 s steady), so they are queued for removal together with the
-device-sink pipeline (ROADMAP C1, C6). The cold pass carries compile +
-first dispatch; the steady-state reps carry the per-volume number.
+Phases:
+  fused    compaction + gzip + RS in one pass against the chained path,
+           with per-stage seconds
+  system   req/s of the write/read data plane
+  saturation, largefile, degraded, overload, lifecycle, georepl,
+  multichip (virtual CPU mesh), metadata, observe, lint, scale,
+  recovery, needle_map: see each phase's docstring
 
-Phases / BASELINE configs:
-  encode   config 1/2: staged-window device-sink pipeline, digest-
-           verified vs an independent host coder; ledger of measured
-           components (read/stage/execute/materialize) + steady-state
-           per-volume rate (config 2's program-reuse regime) + healthy-
-           link projection from the measured parts
-  rebuild  config 3: same protocol over stream_rebuild_device_sink
-           (4 victims from 10 survivors), digest vs the real shard files
-  kernel   pinned RS(10,4) Pallas kernel + RS(k,m) sweep (config 4) +
-           tile sweep, ordered so every config reports >=1 number
-  fused    config 5: compaction + gzip + RS with per-phase seconds
-  system   req/s vs the reference's published benchmark (README.md:504)
-  needle_map  disk-backed index numbers
-
-Prints one JSON line:
-  {"metric": ..., "value": N, "unit": "GB/s", "vs_baseline": N, "extra"}
+Prints one JSON line: {"phases": [...], "extra": {...}}
 """
 
 import json
 import os
 import shutil
-import statistics
 import subprocess
 import sys
 import tempfile
@@ -44,33 +29,8 @@ import time
 
 import numpy as np
 
-BASELINE_GBPS = 20.0  # BASELINE.json: ec.encode >= 20 GB/s/chip on v5e
-
-# published peaks by jax device_kind (Google Cloud documentation,
-# "TPU v5e": 394 TOP/s int8, 819 GB/s HBM). A device that is not named
-# here gets no roofline fraction.
-DEVICE_PEAKS = {
-    "TPU v5 lite": {"int8_ops_per_s": 394e12, "hbm_gbps": 819.0},
-}
-
 HARD_BUDGET_S = 1400.0
 MB = 1024 * 1024
-
-# encode volume: shard width divides the batch width exactly so one
-# window shape covers the whole volume (10 x 16MB batches x 7)
-VOL_BYTES = 1120 * MB
-BATCH_W = 16 * MB          # per-row width -> 160MB per staged batch
-VICTIMS = [0, 3, 7, 12]
-
-
-def _make_volume(path: str, size: int) -> None:
-    rng = np.random.default_rng(7)
-    with open(path, "wb") as f:
-        left = size
-        while left > 0:
-            n = min(left, 64 * MB)
-            f.write(rng.integers(0, 256, n, dtype=np.uint8).tobytes())
-            left -= n
 
 
 def _host_coder():
@@ -79,33 +39,6 @@ def _host_coder():
         return ec.get_coder("cpp", 10, 4)
     except Exception:
         return ec.get_coder("numpy", 10, 4)
-
-
-def measure_link() -> dict:
-    """Host->device bandwidth in this process (incompressible data, 1-D
-    array). Does no device->host read; D2H latency is reported from the
-    pipeline ledger's wait_s instead (the final 16-byte digest
-    materialize)."""
-    import jax
-    x = np.random.default_rng(3).integers(0, 256, 64 * MB, dtype=np.uint8)
-    d = jax.device_put(x)
-    d.block_until_ready()  # warm
-    t0 = time.perf_counter()
-    d = jax.device_put(x)
-    d.block_until_ready()
-    h2d = x.nbytes / (time.perf_counter() - t0) / 1e9
-    return {"h2d_gbps": round(h2d, 3)}
-
-
-def _warm_stage(shape: tuple) -> None:
-    """Two dummy puts of the exact 2-D staging shape, so the first put of
-    a shape (2.6 against 3.6 GB/s on the v5e, PR 21) is not billed to the
-    first batch."""
-    import jax
-    z = np.zeros(shape, dtype=np.uint8)
-    for _ in range(2):
-        h = jax.device_put(z)
-        h.block_until_ready()
 
 
 # ----------------------------------------------------------------- phases
@@ -132,677 +65,6 @@ def _load_partial(work: str, name: str) -> dict:
         return d
     except Exception:
         return {}
-
-
-def phase_encode(work: str) -> dict:
-    """Config 1/2: the staged-window encode sink, fresh process.
-
-    Round 10: the steady state is measured over R full DISK->chip
-    re-feeds through the parallel host-feed tier (reader pool prefaults
-    pages concurrently, a stager pool keeps several H2D puts in flight)
-    with window dispatches pipelined across volumes — the multi-volume
-    encode-queue regime the production pipeline now runs
-    (pipeline.stream_encode_many). All re-feeds happen BEFORE the first
-    device->host read."""
-    import jax
-
-    from seaweedfs_tpu import ec
-    from seaweedfs_tpu.ec import pipeline
-
-    # force real parallelism even where cpu_count reports 1: the stages
-    # being overlapped are IO-bound (disk faults, H2D copies), so
-    # extra threads add outstanding IOs, not CPU contention
-    READERS = max(2, min(4, os.cpu_count() or 1))
-    STAGERS = max(2, min(4, os.cpu_count() or 1))
-
-    out: dict = {"backend": jax.default_backend(),
-                 "feed": {"readers": READERS, "stagers": STAGERS}}
-    out["link"] = measure_link()
-
-    base = os.path.join(work, "1")
-
-    # the Pallas coder on a chip; the XLA coder elsewhere (tests run the
-    # phase on the CPU, where Pallas would need interpret mode)
-    coder = ec.get_coder(
-        "pallas" if jax.default_backend() == "tpu" else "jax", 10, 4)
-    # no ahead-of-time compile: the window dispatch compiles lazily AFTER
-    # staging, so the cold pass includes compile + first dispatch and the
-    # steady-state reps below carry the per-volume number
-    _warm_stage((10, BATCH_W))
-    stats: dict = {}
-    t0 = time.perf_counter()
-    saved: dict = {}
-    orig = coder.encode_digest_window_async
-
-    def capture(staged, acc=None):
-        saved["staged"] = staged
-        return orig(staged, acc)
-
-    coder.encode_digest_window_async = capture
-    # materialize=False: hold the on-device acc and verify it with the
-    # other digests after the loop
-    acc_cold = pipeline.stream_encode_device_sink(
-        base, coder, batch_size=BATCH_W, window_bytes=2 * VOL_BYTES,
-        stats=stats, stagers=STAGERS, readers=READERS,
-        materialize=False)
-    block = getattr(acc_cold, "block_until_ready", None)
-    if block is not None:
-        block()
-    cold_total = time.perf_counter() - t0
-    out["ledger"] = stats
-    out["cold_pass_s"] = round(cold_total, 2)  # includes program load
-    _phase_checkpoint(work, "encode", out)
-
-    # ground truth from an independent host implementation (HOST coder:
-    # no device work, no D2H) — computed AFTER the timed staging so its
-    # full-volume read + host encode (~2s of cache/CPU churn) cannot
-    # perturb the measurement
-    t0 = time.perf_counter()
-    want = pipeline.stream_encode_device_sink(
-        base, _host_coder(), batch_size=BATCH_W, window_bytes=2 * VOL_BYTES)
-    out["host_digest_s"] = round(time.perf_counter() - t0, 2)
-
-    # steady state, round 10: R full disk -> host -> HBM -> kernel
-    # re-feeds back-to-back through the parallel feed tier with
-    # materialization deferred (multi-volume window batching: volume
-    # N+1's reads/stages overlap volume N's window execution). Digests
-    # verify after the loop.
-    R2 = 3
-    rep_stats: list = []
-    accs: list = []
-    t0 = time.perf_counter()
-    for _ in range(R2):
-        st: dict = {}
-        accs.append(pipeline.stream_encode_device_sink(
-            base, coder, batch_size=BATCH_W, window_bytes=2 * VOL_BYTES,
-            stats=st, materialize=False, stagers=STAGERS,
-            readers=READERS))
-        rep_stats.append(st)
-    block = getattr(accs[-1], "block_until_ready", None)
-    if block is not None:
-        block()  # device executes in dispatch order
-    refeed_wall = time.perf_counter() - t0
-    per_volume_s = refeed_wall / R2
-    out["steady_state_volume_s"] = round(per_volume_s, 3)
-    out["steady_state_reps"] = R2
-    out["value_gbps"] = round(VOL_BYTES / per_volume_s / 1e9, 2)
-    out["refeed_ledgers"] = rep_stats
-    _phase_checkpoint(work, "encode", out)
-
-    # in-window execution rate: the program is loaded, data staged —
-    # re-execute, PIPELINED (config 2's program-reuse regime). A single
-    # dispatch+block instead measures the per-sync round trip.
-    R = 5
-    acc_r = None
-    t0 = time.perf_counter()
-    for _ in range(R):
-        acc_r = orig(saved["staged"], acc_r)
-    acc_r.block_until_ready()
-    exec_s = (time.perf_counter() - t0) / R
-    out["exec_steady_s"] = round(exec_s, 4)
-    out["exec_steady_reps"] = R
-    # --- first D2H below; every rate above is already measured and
-    # checkpointed ---
-    d_cold = np.asarray(coder.materialize(acc_cold), dtype=np.uint32)
-    if d_cold.tolist() != want.tolist():
-        raise AssertionError(f"sink digest {d_cold} != host {want}")
-    # after R chained windows over the same data the wrapping digest is
-    # R * want mod 2^32 — a correctness check on the pipelined loop
-    d2 = np.asarray(coder.materialize(acc_r), dtype=np.uint32)
-    want_r = (want.astype(np.uint64) * R & 0xFFFFFFFF).astype(np.uint32)
-    if d2.tolist() != want_r.tolist():
-        raise AssertionError("pipelined steady digest mismatch")
-    # every re-feed's digest must equal the host digest (fresh acc per
-    # rep): the steady-state loop provably performed the full encode
-    for a in accs:
-        d = np.asarray(coder.materialize(a), dtype=np.uint32)
-        if d.tolist() != want.tolist():
-            raise AssertionError(f"re-feed digest {d} != host {want}")
-    # per-rep sync cost, reported for transparency (latency, not rate)
-    t0 = time.perf_counter()
-    acc1 = orig(saved["staged"])
-    d1 = np.asarray(coder.materialize(acc1), dtype=np.uint32)
-    out["single_rep_sync_s"] = round(time.perf_counter() - t0, 4)
-    if d1.tolist() != want.tolist():
-        raise AssertionError("steady-state digest mismatch")
-
-    # measured feed-stage breakdown, one number per pipeline stage, so
-    # future rounds see which stage binds without re-deriving it from the
-    # ledger (write is None here: the device sink writes no shard files);
-    # medians over the steady-state re-feeds
-    def med(key: str) -> float:
-        vals = sorted(s.get(key) or 0.0 for s in rep_stats)
-        return vals[len(vals) // 2]
-
-    read_s, h2d_s = med("read_wait_s"), med("stage_s")
-    out["feed_stages_s"] = {
-        "read": round(read_s, 3),
-        "h2d": round(h2d_s, 3),
-        "kernel": round(exec_s, 4),
-        "write": None,
-    }
-    _phase_checkpoint(work, "encode", out)
-
-    # arithmetic bound from measured parts: the pipeline cannot beat its
-    # slowest stage; on a healthy host H2D is not the binding stage
-    stage_gbps = (VOL_BYTES / h2d_s / 1e9) if h2d_s > 1e-3 else None
-    kernel_gbps = VOL_BYTES / exec_s / 1e9
-    disk_gbps = (VOL_BYTES / read_s / 1e9) if read_s > 1e-3 else None
-    out["component_rates_gbps"] = {
-        "disk_read": round(disk_gbps, 2) if disk_gbps else None,
-        "h2d_stage": round(stage_gbps, 2) if stage_gbps else None,
-        "kernel_window": round(kernel_gbps, 2),
-    }
-    # chip-side capability (the BASELINE north star is GB/s/CHIP): the
-    # window executable — H2D-fed compute incl. the digest reduction —
-    # measured with pipelined dispatches. Host-side stages are reported
-    # separately: the reader pool + stager pool now overlap disk reads
-    # with the H2D copies (the old 1-core serial feed is gone).
-    out["chip_encode_gbps"] = round(kernel_gbps, 2)
-    healthy = {
-        f"disk_read (reader pool x{READERS})": disk_gbps,
-        "kernel_window (chip)": kernel_gbps,
-    }
-    healthy = {k: v for k, v in healthy.items() if v}
-    if healthy:
-        binding = min(healthy, key=healthy.get)
-        out["healthy_link_projection_gbps"] = round(healthy[binding], 2)
-        out["healthy_link_binding_stage"] = binding
-    else:
-        out["healthy_link_projection_gbps"] = None
-    _phase_checkpoint(work, "encode", out)
-
-    # LAST, after every measurement: AOT-compile the dynamic-matrix
-    # window program into the persistent compilation cache. It is the
-    # SAME executable the rebuild phase dispatches (encode and rec
-    # windows share it, ec/coder.py), so phase_rebuild's cold compile
-    # becomes a disk-cache hit.
-    try:
-        n_batches = -(-VOL_BYTES // (10 * BATCH_W))
-        ec.get_coder("jax", 10, 4).warm_encode_digest_window(
-            n_batches, (10, BATCH_W))
-        out["rebuild_cache_warmed"] = True
-    except Exception as e:  # advisory: rebuild still runs, just colder
-        out["rebuild_cache_warmed"] = False
-        out["warm_cache_error"] = str(e)[:300]
-    return out
-
-
-def phase_shardgen(work: str) -> dict:
-    """The rebuild phase's input: shard files from the host coder."""
-    from seaweedfs_tpu.ec import pipeline
-    pipeline.stream_encode(os.path.join(work, "1"), _host_coder(),
-                           batch_size=BATCH_W)
-    return {"shards": 14}
-
-
-def phase_rebuild(work: str, budget_s: float = 580.0) -> dict:
-    """Config 3: reconstruction digest sink + batch amortization, fresh
-    process. Shard files must already exist in `work`.
-
-    Schedule: ALL staging for every volume in the batch happens BEFORE
-    the first dispatch, and every materialize (D2H) happens after the
-    last dispatch.
-
-    The rec window reuses the ENCODE program — the dynamic-matrix window
-    executable (ec/coder.py) is the same compiled program for encode and
-    reconstruction, and the shared persistent compilation cache
-    (_run_phase) carries it across the phase boundary — plus
-    WEED_EC_REC_WINDOW_BATCHES caps the window.
-    Every measured value checkpoints to rebuild_partial.json the moment
-    it exists, so even a stuck sub-step leaves real numbers, and
-    optional sub-steps are skipped when the phase budget runs low."""
-    import jax
-
-    from seaweedfs_tpu import ec
-    from seaweedfs_tpu.ec import feed as feed_mod
-    from seaweedfs_tpu.ec import pipeline
-
-    started = time.perf_counter()
-
-    def left() -> float:
-        return budget_s - (time.perf_counter() - started)
-
-    out: dict = {"backend": jax.default_backend(), "victims": VICTIMS,
-                 "digest_verified": False}
-
-    def ckpt() -> None:
-        _phase_checkpoint(work, "rebuild", out)
-
-    # checkpoint from second zero: a phase that dies ANYWHERE must still
-    # leave a partial record for the driver
-    ckpt()
-    base = os.path.join(work, "1")
-    want = pipeline.shard_file_digest(base, VICTIMS)
-
-    shard_size = os.path.getsize(base + ec.to_ext(0))
-    out["shard_size"] = shard_size
-    ckpt()
-
-    # jax (XLA bitplane) coder here: its dynamic-matrix rec window is the
-    # program the encode phase warmed into the compile cache
-    coder = ec.get_coder("jax", 10, 4)
-
-    present = [i for i in range(14) if i not in VICTIMS]
-    survivors = tuple(present[:10])
-    READERS = max(2, min(4, os.cpu_count() or 1))
-    src = feed_mod.ShardFeed([base + ec.to_ext(i) for i in survivors],
-                             BATCH_W, pooled=False, readers=READERS)
-
-    def read_batches() -> list:
-        """7 x [k, 16MB] batches per volume — the round-4-proven window
-        shape for the XLA rec program (a single [k, shard_size] batch
-        would blow HBM: the bitplane formulation materializes ~25x the
-        input in intermediates). Parallel feed: the reader pool splits
-        each batch's survivor-row reads across threads (ec/feed.py)."""
-        return list(src.batches(BATCH_W, pad_final=True))
-
-    # --- stage N volumes.
-    # A reader thread keeps one volume of host batches ahead, so disk
-    # reads overlap device staging (pread + device transfer both release
-    # the GIL); the steady per-volume cost is max(read, stage), as in
-    # the production pipeline's reader/stager split.
-    # Budget discipline (round 10): N scales down on a tight budget,
-    # each staged volume checkpoints IMMEDIATELY, and staging stops
-    # early (keeping >= 2 volumes) when the budget runs low. ---
-    import queue as queue_mod
-    import threading
-
-    # 6 x 1.12GB staged concurrently fits a v5e's HBM
-    N_BATCHED = 6 if left() > 300 else 3
-    _warm_stage((10, BATCH_W))
-    read_q: "queue_mod.Queue" = queue_mod.Queue(maxsize=2)
-    read_meter = {"s": 0.0}
-    stop_reading = threading.Event()
-
-    def reader_main() -> None:
-        for _ in range(N_BATCHED):
-            if stop_reading.is_set():
-                break
-            tr = time.perf_counter()
-            hb = read_batches()
-            read_meter["s"] += time.perf_counter() - tr
-            read_q.put(hb)
-        read_q.put(None)
-
-    t0 = time.perf_counter()
-    threading.Thread(target=reader_main, daemon=True).start()
-    staged_vols = []
-    while True:
-        host_batches = read_q.get()
-        if host_batches is None:
-            break
-        sv = []
-        for b in host_batches:
-            h = coder.stage_async(b)
-            block = getattr(h, "block_until_ready", None)
-            if block is not None:
-                block()
-            sv.append(h)
-        staged_vols.append(sv)
-        out["ledger"] = {
-            "n_volumes_staged": len(staged_vols),
-            "read_s": round(read_meter["s"], 2),
-            "stage_all_s": round(time.perf_counter() - t0, 2),
-        }
-        ckpt()
-        if len(staged_vols) >= 2 and left() < 150:
-            # the budget is running out: stop staging and measure with
-            # what we have (the numbers matter more than N)
-            stop_reading.set()
-            out.setdefault("skipped", []).append(
-                f"staging volumes {len(staged_vols) + 1}..{N_BATCHED} "
-                "(budget)")
-    n_staged = len(staged_vols)
-    stage_all_s = time.perf_counter() - t0
-    stage_per_volume_s = stage_all_s / max(n_staged, 1)
-    out["ledger"] = {
-        "n_volumes_staged": n_staged,
-        "read_s": round(read_meter["s"], 2),
-        "stage_all_s": round(stage_all_s, 2),
-        "stage_per_volume_s": round(stage_per_volume_s, 3),
-        "stage_gbps": round(
-            n_staged * 10 * shard_size / stage_all_s / 1e9, 2),
-    }
-    src.close()
-    ckpt()
-
-    # --- AOT-warm the rec window program, checkpointed as its own step:
-    # the dynamic-matrix window executable is the SAME program
-    # phase_encode compiled into the shared persistent cache, so this is
-    # normally a disk-cache hit — and when it isn't (cold cache), the
-    # step's own record says so ---
-    try:
-        t0 = time.perf_counter()
-        coder.warm_rec_digest_window(survivors, tuple(VICTIMS),
-                                     len(staged_vols[0]), (10, BATCH_W))
-        out["rec_warm_s"] = round(time.perf_counter() - t0, 2)
-    except Exception as e:  # advisory: dispatch compiles lazily instead
-        out["rec_warm_error"] = str(e)[:300]
-    ckpt()
-
-    # --- first dispatch: one window through the SHARED dynamic-matrix
-    # program (compile hits the persistent cache the encode phase
-    # already populated) ---
-    t0 = time.perf_counter()
-    acc0 = coder.rec_digest_window_async(survivors, tuple(VICTIMS),
-                                         staged_vols[0])
-    acc0.block_until_ready()
-    cold_exec_s = time.perf_counter() - t0
-    out["cold_pass_s"] = round(stage_per_volume_s + cold_exec_s, 2)
-    out["cold_exec_s"] = round(cold_exec_s, 2)
-    ckpt()
-
-    # --- steady: remaining volumes through the loaded program,
-    # dispatches pipelined, one block at the end ---
-    accs = [acc0]
-    t0 = time.perf_counter()
-    for sv in staged_vols[1:]:
-        accs.append(coder.rec_digest_window_async(
-            survivors, tuple(VICTIMS), sv))
-    accs[-1].block_until_ready()  # TPU executes in dispatch order
-    exec_s = ((time.perf_counter() - t0) / (n_staged - 1)
-              if n_staged > 1 else cold_exec_s)
-    out["exec_steady_s"] = round(exec_s, 4)
-
-    p50 = stage_per_volume_s + exec_s
-    out["rebuild_p50_s"] = round(p50, 3)
-    out["rebuild_is_cold"] = False
-    # rate over the data the rebuild actually moves + computes: k
-    # survivor shards in, len(victims) shards out
-    out["rebuild_gbps"] = round(10 * shard_size / p50 / 1e9, 2)
-    # chip-side reconstruction rate (window executable, pipelined)
-    out["rebuild_window_gbps"] = round(10 * shard_size / exec_s / 1e9, 2)
-    ckpt()
-
-    # extra pipelined reps on volume 0's staged window (acc-chained);
-    # optional: skipped on a tight budget so the verify still runs
-    R = 5
-    acc_r = None
-    if left() > 90:
-        t0 = time.perf_counter()
-        for _ in range(R):
-            acc_r = coder.rec_digest_window_async(
-                survivors, tuple(VICTIMS), staged_vols[0], acc_r)
-        acc_r.block_until_ready()
-        exec_rep_s = (time.perf_counter() - t0) / R
-        out["exec_steady_rep_s"] = round(exec_rep_s, 4)
-        ckpt()
-    else:
-        out["exec_steady_rep_s"] = None
-        out["skipped"] = ["exec_steady_rep (budget)"]
-
-    # --- first D2H: materialize + verify everything ---
-    for a in accs:
-        d = np.asarray(coder.materialize(a), dtype=np.uint32)
-        if d.tolist() != want.tolist():
-            raise AssertionError(f"rebuild digest {d} != files {want}")
-    if acc_r is not None:
-        d_r = np.asarray(coder.materialize(acc_r), dtype=np.uint32)
-        want_r = (want.astype(np.uint64) * R & 0xFFFFFFFF).astype(np.uint32)
-        if d_r.tolist() != want_r.tolist():
-            raise AssertionError("pipelined rebuild digest mismatch")
-    out["digest_verified"] = True
-    ckpt()
-    if left() > 30:
-        t0 = time.perf_counter()
-        acc1 = coder.rec_digest_window_async(survivors, tuple(VICTIMS),
-                                             staged_vols[0])
-        d1 = np.asarray(coder.materialize(acc1), dtype=np.uint32)
-        out["single_rep_sync_s"] = round(time.perf_counter() - t0, 4)
-        if d1.tolist() != want.tolist():
-            raise AssertionError("steady-state rebuild digest mismatch")
-    else:
-        out["single_rep_sync_s"] = None
-        out.setdefault("skipped", []).append("single_rep_sync (budget)")
-
-    # --- BASELINE config 3 batch summary + amortization curve ---
-    load_s = max(cold_exec_s - exec_s, 0.0)
-    batch = {
-        str(n_staged): {
-            "wall_s": round(stage_all_s + cold_exec_s
-                            + exec_s * (n_staged - 1), 2),
-            "per_volume_s": round(p50 + load_s / n_staged, 3),
-            "gbps_aggregate": round(
-                10 * shard_size * n_staged
-                / (stage_all_s + cold_exec_s + exec_s * (n_staged - 1))
-                / 1e9, 2),
-        },
-        "amortization_model": {
-            "one_time_load_s": round(load_s, 1),
-            "steady_per_volume_s": round(p50, 3),
-            "projected_per_volume_s": {
-                str(n): round((load_s + n * p50) / n, 2)
-                for n in (1, 10, 100, 1000)},
-        },
-    }
-    out["rebuild_batch"] = batch
-    ckpt()
-    return out
-
-
-def bench_kernel(k: int, m: int, n: int, reps: int, tile=None, rounds=1,
-                 method=None):
-    """Pinned kernel measurement (unchanged from round 3): fixed n and
-    reps, one warm+correctness pass, `rounds` timed rounds; returns
-    (median GB/s, spread). `method` selects the GF formulation
-    (rs_jax.FORMULATIONS; on a TPU "bitplane" is the Pallas kernel, the
-    others are their XLA programs) — None keeps the historical default
-    so pinned-anchor numbers stay comparable across bench rounds."""
-    import jax
-
-    from seaweedfs_tpu.ops import gf256, rs_jax, rs_pallas
-
-    data = jax.numpy.asarray(
-        np.random.default_rng(0).integers(0, 256, (k, n), dtype=np.uint8))
-    if jax.default_backend() == "tpu":
-        if method in (None, "bitplane"):
-            fn = rs_pallas.gf_apply_pallas(
-                gf256.parity_matrix(k, m), tile=tile or rs_pallas.TILE)
-        else:  # lut/xorsched have no Pallas kernel: the XLA program
-            fn = jax.jit(rs_jax.gf_apply(method,
-                                         gf256.parity_matrix(k, m)))
-    elif method is None:
-        fn = jax.jit(rs_jax.gf_apply_bitplane(gf256.parity_matrix(k, m)))
-    else:
-        fn = jax.jit(rs_jax.gf_apply(method, gf256.parity_matrix(k, m)))
-    out = fn(data)
-    out.block_until_ready()
-
-    check = np.asarray(out[:, :65536])
-    want = gf256.encode_parity(np.asarray(data[:, :65536]), m)
-    if not np.array_equal(check, want):
-        raise AssertionError(f"parity mismatch at RS({k},{m})")
-
-    # single-launch wall (dispatch + block): if launches stop
-    # pipelining, the timed loop degenerates to reps x this latency and
-    # the GB/s figure measures launch latency, not the kernel. Only the
-    # pinned multi-round call pays for it — sweep calls (rounds=1)
-    # discard it
-    single_launch_s = 0.0
-    if rounds > 1:
-        t0 = time.perf_counter()
-        out = fn(data)
-        out.block_until_ready()
-        single_launch_s = time.perf_counter() - t0
-
-    samples = []
-    for _ in range(rounds):
-        t0 = time.perf_counter()
-        for _ in range(reps):
-            out = fn(data)
-        out.block_until_ready()
-        samples.append((k * n) / ((time.perf_counter() - t0) / reps) / 1e9)
-    med = statistics.median(samples)
-    spread = (max(samples) - min(samples)) / med if med else 0.0
-    return med, spread, single_launch_s
-
-
-def phase_kernel(work: str = "", budget_s: float = 390.0) -> dict:
-    """Pinned kernel + RS(k,m) sweep (config 4) + tile sweep, ordered so
-    every config reports at least one number before optional extras.
-
-    Every sweep/tile cell is pre-filled with a "skipped: not reached"
-    reason and the record checkpoints after each cell, so a phase that
-    times out mid-sweep leaves reason strings in <work>/kernel_partial
-    .json instead of nulling cells it never got to (BENCH_r05 recorded
-    a bare null at tile 131072 exactly this way)."""
-    import jax
-
-    from seaweedfs_tpu.ops import rs_pallas
-
-    on_tpu = jax.default_backend() == "tpu"
-    n = 64 * MB if on_tpu else MB
-    reps = 10 if on_tpu else 3
-    started = time.perf_counter()
-    out: dict = {"backend": jax.default_backend()}
-
-    def ckpt() -> None:
-        if work:
-            _phase_checkpoint(work, "kernel", out)
-
-    def left() -> float:
-        return budget_s - (time.perf_counter() - started)
-
-    # 1) pinned anchor first: every config must report a number before
-    # anything optional spends budget (round 4 nulled (6,3) + the tile
-    # sweep). Full reps + 3 rounds — at reps=3 the unamortized launch
-    # latency halves the reported rate (measured round 5: 14.98 vs 33+);
-    # the timed loop itself costs <2s, compiles dominate each config.
-    t0 = time.perf_counter()
-    gbps, spread, single_s = bench_kernel(10, 4, n, reps, rounds=3)
-    per_rep_s = (10 * n) / (gbps * 1e9) if gbps else 0.0
-    launch_bound = single_s > 0.05 and per_rep_s > 0.7 * single_s
-    out["kernel"] = {
-        "gbps": round(gbps, 2),
-        "vs_target": round(gbps / BASELINE_GBPS, 3),
-        "n": n, "reps": reps, "rounds": 3,
-        "spread_pct": round(spread * 100, 1),
-        "single_launch_s": round(single_s, 3),
-        "launch_latency_bound": launch_bound,
-    }
-    if launch_bound:
-        out["kernel"]["caveat"] = (
-            "this run's timed loop degenerated to per-launch latency "
-            f"({single_s:.2f}s/launch, no pipelining): the GB/s figure "
-            "measures launch latency, not the kernel")
-    last = max(45.0, time.perf_counter() - t0)
-    ckpt()
-
-    # 2) geometry sweep — every cell before any optional extra. A cell
-    # that can't run records WHY as a string ("skipped: ..."/"error: ...")
-    # instead of a bare null, and the dicts start fully populated with
-    # "skipped: not reached" so even a phase KILLED mid-cell leaves a
-    # reason string, never a null (BENCH_r05 recorded "131072": null —
-    # the per-cell strings existed but only materialized for cells the
-    # loop actually visited before the phase timed out).
-    not_reached = "skipped: not reached (phase timed out or died earlier)"
-    sweep: dict = {f"{k},{m}": not_reached
-                   for (k, m) in ((20, 4), (12, 4), (6, 3))}
-    tiles: dict = {tl: not_reached
-                   for tl in dict.fromkeys(
-                       (rs_pallas.TILE, 65536, 131072))}
-    forms: dict = {f"{f}:{k},{m}": not_reached
-                   for f in ("lut", "bitplane", "xorsched")
-                   for (k, m) in ((10, 4), (12, 4), (20, 4))}
-    out["sweep_kernel_gbps"] = sweep
-    out["tile_sweep_gbps"] = tiles
-    out["formulation_sweep_gbps"] = forms
-    ckpt()
-
-    # 2a) static formulation metric: compiled-HLO element-ops per input
-    # byte for each formulation's RS(10,4) encode program (xorsched's is
-    # the packed bit-plane-resident per-batch program — the one the
-    # windowed path actually launches). Cheap (lower+compile, no timed
-    # loop) and meaningful without a TPU, so it lands before the sweeps.
-    from seaweedfs_tpu.ops import rs_jax as _rs_jax
-    hlo: dict = {}
-    out["hlo_ops_per_byte"] = hlo
-    for f in ("lut", "bitplane", "xorsched"):
-        try:
-            hlo[f] = round(
-                _rs_jax.encode_hlo_ops_per_byte(10, 4, method=f), 2)
-        except Exception as e:
-            hlo[f] = f"error: {type(e).__name__}: {str(e)[:160]}"
-        ckpt()
-    for (k, m) in ((20, 4), (12, 4), (6, 3)):
-        if left() < last * 1.2:
-            sweep[f"{k},{m}"] = (f"skipped: budget ({left():.0f}s left, "
-                                 f"cell needs ~{last * 1.2:.0f}s)")
-            ckpt()
-            continue
-        t0 = time.perf_counter()
-        nn = n - n % (16384 * 8)
-        try:
-            g, _, _ = bench_kernel(k, m, nn, reps)
-        except Exception as e:
-            sweep[f"{k},{m}"] = (f"error: {type(e).__name__}: "
-                                 f"{str(e)[:160]}")
-            last = max(45.0, time.perf_counter() - t0)
-            ckpt()
-            continue
-        last = max(45.0, time.perf_counter() - t0)
-        sweep[f"{k},{m}"] = round(g, 2)
-        ckpt()
-
-    # 3) tile sweep (rs_pallas.TILE reuses the step-1 compile)
-    for tl in list(tiles):
-        if left() < last * 1.2:
-            tiles[tl] = (f"skipped: budget ({left():.0f}s left, "
-                         f"cell needs ~{last * 1.2:.0f}s)")
-            ckpt()
-            continue
-        t0 = time.perf_counter()
-        try:
-            g, _, _ = bench_kernel(10, 4, n, reps, tile=tl)
-        except Exception as e:
-            tiles[tl] = f"error: {type(e).__name__}: {str(e)[:160]}"
-            last = max(45.0, time.perf_counter() - t0)
-            ckpt()
-            continue
-        last = max(45.0, time.perf_counter() - t0)
-        tiles[tl] = round(g, 2)
-        ckpt()
-
-    # 4) formulation sweep: {lut, bitplane, xorsched} x geometry. On CPU
-    # hosts this times the XLA programs (relative ordering only); on a
-    # TPU "bitplane" is the Pallas kernel. Same budget
-    # convention as the other sweeps: every unvisited cell keeps a
-    # reason string, never a null.
-    for key in list(forms):
-        f, geo = key.split(":")
-        k, m = (int(x) for x in geo.split(","))
-        if left() < last * 1.2:
-            forms[key] = (f"skipped: budget ({left():.0f}s left, "
-                          f"cell needs ~{last * 1.2:.0f}s)")
-            ckpt()
-            continue
-        t0 = time.perf_counter()
-        nn = n - n % (16384 * 8)
-        try:
-            g, _, _ = bench_kernel(k, m, nn, reps, method=f)
-        except Exception as e:
-            forms[key] = (f"error: {type(e).__name__}: "
-                          f"{str(e)[:160]}")
-            last = max(45.0, time.perf_counter() - t0)
-            ckpt()
-            continue
-        last = max(45.0, time.perf_counter() - t0)
-        forms[key] = round(g, 2)
-        ckpt()
-
-    # arithmetic context for the kernel number, only against the peaks
-    # of a device this table names: 128 x 4 int8 MACs per input byte on
-    # the MXU; 1 + m/k = 1.4 bytes of HBM traffic per input byte
-    out["device_kind"] = jax.devices()[0].device_kind
-    peaks = DEVICE_PEAKS.get(out["device_kind"])
-    if peaks is not None:
-        ops_per_s = 128 * 4 * out["kernel"]["gbps"] * 1e9
-        out["kernel"]["mxu_fraction"] = round(
-            ops_per_s / peaks["int8_ops_per_s"], 4)
-        out["kernel"]["hbm_fraction"] = round(
-            1.4 * out["kernel"]["gbps"] / peaks["hbm_gbps"], 4)
-    return out
 
 
 def phase_fused(work: str, budget_s: float = 580.0) -> dict:
@@ -3209,7 +2471,7 @@ def phase_scale(work: str = "", budget_s: float = 240.0) -> dict:
 
 # phases that pin themselves to the virtual CPU mesh or never need a
 # device; every other subprocess phase is a chip phase
-_CPU_PHASES = frozenset({"multichip", "shardgen"})
+_CPU_PHASES = frozenset({"multichip"})
 
 
 def _run_phase(name: str, work: str, timeout_s: float) -> dict:
@@ -3225,10 +2487,9 @@ def _run_phase(name: str, work: str, timeout_s: float) -> dict:
     env = dict(os.environ,
                JAX_PLATFORMS="cpu" if name in _CPU_PHASES else "tpu")
     # one persistent compilation cache shared by every phase (and every
-    # run): the rebuild phase's dynamic-matrix window program IS the
-    # program the encode phase compiled (ec/coder.py). Same rule as the
-    # product (utils/compile_cache.py): the environment's directory if
-    # it names one, else the fixed one in the checkout
+    # run). Same rule as the product (utils/compile_cache.py): the
+    # environment's directory if it names one, else the fixed one in
+    # the checkout
     env.setdefault("JAX_COMPILATION_CACHE_DIR", compile_cache.CACHE_DIR)
     try:
         p = subprocess.run(
@@ -3285,54 +2546,10 @@ def main() -> None:
         return HARD_BUDGET_S - (time.perf_counter() - started)
 
     try:
-        # host-side prep (the parent never touches the chip: jax stays
-        # un-imported here until the last chip phase has run)
-        t0 = time.perf_counter()
-        _make_volume(os.path.join(work, "1.dat"), VOL_BYTES)
-        _log(f"volume gen: {time.perf_counter() - t0:.1f}s")
-
         # per-phase incremental record: every phase lands in
-        # BENCH_DETAIL.json the moment it completes
-        detail = {"volume_bytes": VOL_BYTES, "incomplete": True}
-
-        encode = _run_phase("encode", work, min(430.0, left()))
-        _log(f"encode: {encode.get('value_gbps')} GB/s "
-             f"({encode.get('phase_wall_s')}s)")
-        detail["encode"] = encode
-        _checkpoint(detail)
-
-        # kernel before rebuild: rebuild runs LAST among the chip phases
-        # and gets all the remaining chip budget
-        kernel = _run_phase("kernel", work, min(420.0, max(left(), 60)))
-        _log(f"kernel: {kernel.get('kernel', {}).get('gbps')} GB/s "
-             f"({kernel.get('phase_wall_s')}s)")
-        detail["kernel_phase"] = kernel
-        _checkpoint(detail)
-
-        # shard files for the rebuild phase: host coder, in a CPU-pinned
-        # child (importing the pipeline here would import jax in the
-        # parent before the rebuild and fused chip phases start)
-        rebuild: dict = {"error": "skipped (budget)"}
-        if left() > 200:
-            gen = _run_phase("shardgen", work, 200.0)
-            _log(f"shard gen (host): {gen.get('phase_wall_s')}s "
-                 f"{gen.get('error', '')}")
-            # leave ~180s for fused+system+needle_map after rebuild
-            rebuild = _run_phase("rebuild", work,
-                                 min(650.0, max(left() - 180.0, 60.0)))
-        if rebuild.get("rebuild_p50_s") is None:
-            # a skipped/unreached phase has no p50: print its reason
-            # instead of the literal "p50 Nones (Nones)" (BENCH_r05
-            # tail); the JSON keeps the "skipped: ..." string as-is
-            reason = str(rebuild.get("error", "not reached"))
-            if not reason.startswith("skipped"):
-                reason = f"skipped ({reason})"
-            _log(f"rebuild: {reason}")
-        else:
-            _log(f"rebuild: p50 {rebuild.get('rebuild_p50_s')}s "
-                 f"({rebuild.get('phase_wall_s')}s)")
-        detail["rebuild"] = rebuild
-        _checkpoint(detail)
+        # BENCH_DETAIL.json the moment it completes. The parent stays
+        # off jax: each jax-touching phase owns its device in a child
+        detail: dict = {"incomplete": True}
 
         fused = ({"error": "skipped (budget)"} if left() < 120
                  else _run_phase("fused", work, min(240.0, left())))
@@ -3524,54 +2741,14 @@ def main() -> None:
             needle_map = {"error": str(e)}
         detail["disk_needle_map"] = needle_map
 
-        value = encode.get("value_gbps") or 0.0
         detail.pop("incomplete", None)
-        detail.update({
-            "note": (
-                "value = steady-state per-volume pipeline rate "
-                "(read+stage+execute, program already loaded, window "
-                "dispatches pipelined — the 1000-volume regime of "
-                "BASELINE config 2). Each chip phase runs in a fresh "
-                "process (one process owns the chip); cold_pass_s "
-                "includes compile and first dispatch. Digests verified "
-                "against an independent "
-                "host coder in every phase. The stage rate trails the "
-                "isolated H2D link rate because the disk reader and the "
-                "device_put copy contend for this host's ONE core "
-                "(probed: [10,16M] puts alone run at full link rate); "
-                "host-side feed rates are host properties — the "
-                "chip-side rates are chip_encode_gbps / "
-                "rebuild_window_gbps."),
-        })
         # final full record; stdout's LAST line stays small and
-        # single-line so the driver's parse cannot truncate it
+        # single-line so a caller's parse cannot truncate it
         _checkpoint(detail)
-        enc_rates = encode.get("component_rates_gbps") or {}
         print(json.dumps({
-            "metric": ("ec.encode pipeline GB/s/chip (disk -> H2D -> "
-                       "kernel, device parity sink, steady state)"),
-            "value": value,
-            "unit": "GB/s",
-            "vs_baseline": round(value / BASELINE_GBPS, 3),
+            "phases": [k for k, v in detail.items()
+                       if isinstance(v, dict) and "error" not in v],
             "extra": {
-                "chip_encode_gbps": encode.get("chip_encode_gbps"),
-                "encode_feed_stages_s": encode.get("feed_stages_s"),
-                "healthy_link_projection_gbps":
-                    encode.get("healthy_link_projection_gbps"),
-                "healthy_link_binding_stage":
-                    encode.get("healthy_link_binding_stage"),
-                "kernel_window_gbps": enc_rates.get("kernel_window"),
-                "pinned_kernel_gbps":
-                    (kernel.get("kernel") or {}).get("gbps"),
-                "sweep_kernel_gbps": kernel.get("sweep_kernel_gbps"),
-                "tile_sweep_gbps": kernel.get("tile_sweep_gbps"),
-                "rebuild_p50_s": rebuild.get("rebuild_p50_s"),
-                "rebuild_window_gbps":
-                    rebuild.get("rebuild_window_gbps"),
-                "rebuild_batch_steady_per_volume_s":
-                    ((rebuild.get("rebuild_batch") or {})
-                     .get("amortization_model")
-                     or {}).get("steady_per_volume_s"),
                 "system_write_req_s":
                     (system.get("write") or {}).get("req_s")
                     if isinstance(system.get("write"), dict) else None,
@@ -3635,11 +2812,7 @@ if __name__ == "__main__":
         budget = (float(sys.argv[sys.argv.index("--budget") + 1])
                   if "--budget" in sys.argv else 580.0)
         sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-        fn = {"encode": phase_encode,
-              "shardgen": phase_shardgen,
-              "rebuild": lambda w: phase_rebuild(w, budget_s=budget),
-              "kernel": lambda w: phase_kernel(w, budget_s=budget),
-              "fused": lambda w: phase_fused(w, budget_s=budget),
+        fn = {"fused": lambda w: phase_fused(w, budget_s=budget),
               "multichip": lambda w: phase_multichip(w, budget_s=budget),
               "degraded": lambda w: phase_degraded(w, budget_s=budget),
               "largefile": phase_largefile,

@@ -42,10 +42,7 @@ Staging buffers come from a bounded ``BufferPool`` so the pipeline
 double-buffers: batch N+1 assembles while batch N's device_put + kernel
 are in flight, and memory stays at pool_size * k * batch bytes no matter
 how long the volume is. The pipeline recycles a buffer once its batch is
-fully consumed (parity materialized AND every shard row written). Feeds
-with ``pooled=False`` hand out fresh buffers and recycling is a no-op —
-the device-sink bench paths use that mode because a whole window of
-batches stays referenced until its single dispatch.
+fully consumed (parity materialized AND every shard row written).
 
 Fault points: ``ec.feed.read`` fires on every stripe/survivor read
 operation (a drop fails the read — a feed must never silently feed
@@ -114,23 +111,17 @@ def _aligned_empty(shape: tuple) -> np.ndarray:
 
 class BufferPool:
     """Bounded free-list of [k, width] uint8 staging buffers.
+    ``aligned=True`` allocates page-aligned buffers (O_DIRECT
+    destinations)."""
 
-    ``pooled=False`` turns the pool into an allocator: acquire returns a
-    fresh buffer, release is a no-op (for consumers that hold many
-    batches at once, e.g. a whole staged window). ``aligned=True``
-    allocates page-aligned buffers (O_DIRECT destinations).
-    """
-
-    def __init__(self, k: int, width: int, count: int, pooled: bool = True,
+    def __init__(self, k: int, width: int, count: int,
                  aligned: bool = False):
         self.shape = (k, width)
-        self.pooled = pooled
         self.aligned = aligned
         self._closed = threading.Event()
         self._q: queue.Queue = queue.Queue()
-        if pooled:
-            for _ in range(max(count, 2)):
-                self._q.put(self._alloc())
+        for _ in range(max(count, 2)):
+            self._q.put(self._alloc())
 
     def _alloc(self) -> np.ndarray:
         if self.aligned:
@@ -138,8 +129,6 @@ class BufferPool:
         return np.empty(self.shape, dtype=np.uint8)
 
     def acquire(self) -> np.ndarray:
-        if not self.pooled:
-            return self._alloc()
         # poll with a timeout so a consumer that stops recycling (error
         # paths) can never wedge the reader thread: close() unblocks us
         stalled = False
@@ -159,8 +148,6 @@ class BufferPool:
     def try_acquire(self) -> Optional[np.ndarray]:
         """Non-blocking acquire (reader-pool lookahead must never block
         behind buffers the consumer hasn't recycled yet)."""
-        if not self.pooled:
-            return self._alloc()
         if self._closed.is_set():
             raise RuntimeError("feed closed while awaiting a buffer")
         try:
@@ -169,8 +156,7 @@ class BufferPool:
             return None
 
     def release(self, buf: np.ndarray) -> None:
-        if self.pooled:
-            self._q.put(buf)
+        self._q.put(buf)
 
     def close(self) -> None:
         self._closed.set()
@@ -303,28 +289,26 @@ class _FeedBase:
     """Common assembly bookkeeping: lent-buffer tracking + recycling +
     the ordered reader-pool window."""
 
-    def __init__(self, k: int, width: int, pool_buffers: int, pooled: bool,
+    def __init__(self, k: int, width: int, pool_buffers: int,
                  readers: Optional[int] = None, aligned: bool = False):
         self.k = k
         self.width = width
         self.readers = (reader_count_default() if readers is None
                         else max(1, int(readers)))
-        self.pool = BufferPool(k, width, pool_buffers, pooled,
-                               aligned=aligned)
+        self.pool = BufferPool(k, width, pool_buffers, aligned=aligned)
         self._rpool: Optional[_ReaderPool] = None
         self._lent: dict[int, np.ndarray] = {}
         self._lent_lock = threading.Lock()
 
     def _lend(self, buf: np.ndarray, out: np.ndarray) -> np.ndarray:
         """Register `out` (a view of pool buffer `buf`) as lent."""
-        if self.pool.pooled:
-            with self._lent_lock:
-                self._lent[id(out)] = buf
+        with self._lent_lock:
+            self._lent[id(out)] = buf
         return out
 
     def recycle(self, batch: np.ndarray) -> None:
         """Return a batch's staging buffer to the pool. No-op for
-        zero-copy views and unpooled buffers — always safe to call."""
+        zero-copy views — always safe to call."""
         with self._lent_lock:
             buf = self._lent.pop(id(batch), None)
         if buf is not None:
@@ -370,24 +354,22 @@ class _FeedBase:
 
     # --- batch aggregation ---
 
-    def batches(self, segments: Iterator[Segment],
-                pad_final: bool = False) -> Iterator[np.ndarray]:
+    def batches(self, segments: Iterator[Segment]
+                ) -> Iterator[np.ndarray]:
         """Aggregate stripe segments into [k, width] batches — the same
         column-concatenation the pipeline always used (consecutive
         segments append to the same shard files), so batch width never
-        changes the on-disk layout. pad_final yields the last batch at
-        full width, zero-padded (window executables need one shape).
+        changes the on-disk layout.
 
         readers > 1 assembles on the reader pool (ordered yield);
         readers == 1 is the serial path, byte-identical output."""
         if self.readers <= 1:
-            yield from self._batches_serial(segments, pad_final)
+            yield from self._batches_serial(segments)
         else:
-            yield from self._ordered_parallel(
-                self._stripe_plans(segments, pad_final))
+            yield from self._ordered_parallel(self._stripe_plans(segments))
 
-    def _batches_serial(self, segments: Iterator[Segment],
-                        pad_final: bool) -> Iterator[np.ndarray]:
+    def _batches_serial(self, segments: Iterator[Segment]
+                        ) -> Iterator[np.ndarray]:
         buf: Optional[np.ndarray] = None
         col = 0
         for offsets, w in segments:
@@ -406,16 +388,12 @@ class _FeedBase:
             self._fill_segment(buf, col, offsets, w)
             col += w
         if buf is not None and col:
-            if col < self.width and pad_final:
-                buf[:, col:] = 0
-                yield self._lend(buf, buf)
-            else:
-                yield self._lend(buf, buf[:, :col] if col < self.width
-                                 else buf)
+            yield self._lend(buf, buf[:, :col] if col < self.width
+                             else buf)
 
-    def _stripe_plans(self, segments: Iterator[Segment],
-                      pad_final: bool) -> Iterator[tuple]:
-        """("view", view, offsets, w) | ("fill", fills, used_cols, pad):
+    def _stripe_plans(self, segments: Iterator[Segment]
+                      ) -> Iterator[tuple]:
+        """("view", view, offsets, w) | ("fill", fills, used_cols):
         the same aggregation as the serial path, decisions only — no
         bytes move until the plan is submitted to the reader pool."""
         fills: list[tuple[int, Sequence[int], int]] = []
@@ -427,13 +405,13 @@ class _FeedBase:
                     yield ("view", zc, offsets, w)
                     continue
             if col + w > self.width:
-                yield ("fill", fills, col, False)
+                yield ("fill", fills, col)
                 fills = []
                 col = 0
             fills.append((col, offsets, w))
             col += w
         if fills:
-            yield ("fill", fills, col, pad_final)
+            yield ("fill", fills, col)
 
     def _submit_plan(self, plan: tuple,
                      block: bool) -> Optional[_Pending]:
@@ -448,14 +426,11 @@ class _FeedBase:
             for fn in jobs:
                 rpool.submit(fn, pend)
             return pend
-        _, fills, used, pad = plan
+        _, fills, used = plan
         buf = self.pool.acquire() if block else self.pool.try_acquire()
         if buf is None:
             return None
-        if used < self.width:
-            out = buf if pad else buf[:, :used]
-        else:
-            out = buf
+        out = buf[:, :used] if used < self.width else buf
         self._lend(buf, out)
         # split fills into jobs: many small fills parallelize as-is; a
         # single wide fill (large-block stripe) splits across its k rows
@@ -472,11 +447,6 @@ class _FeedBase:
                     self._fill_rows(buf, c, offsets, w, lo, hi)
 
                 jobs.append(job)
-        if pad and used < self.width:
-            def pad_job(used=used):
-                buf[:, used:] = 0
-
-            jobs.append(pad_job)
         pend = _Pending(out, buf, len(jobs))
         for fn in jobs:
             rpool.submit(fn, pend)
@@ -587,9 +557,9 @@ class MmapFeed(_FeedBase):
     """Page-cache-mapped stripe feed over one .dat file."""
 
     def __init__(self, path: str, k: int, width: int,
-                 pool_buffers: int = 4, pooled: bool = True,
+                 pool_buffers: int = 4,
                  readers: Optional[int] = None):
-        super().__init__(k, width, pool_buffers, pooled, readers=readers)
+        super().__init__(k, width, pool_buffers, readers=readers)
         self.size = os.path.getsize(path)
         self._fd = os.open(path, os.O_RDONLY)
         self._mm: Optional[mmap.mmap] = None
@@ -713,12 +683,12 @@ class PreadvFeed(_FeedBase):
     pread per row range (reader pool / O_DIRECT)."""
 
     def __init__(self, path: str, k: int, width: int,
-                 pool_buffers: int = 4, pooled: bool = True,
+                 pool_buffers: int = 4,
                  readers: Optional[int] = None,
                  odirect: Optional[bool] = None):
         if odirect is None:
             odirect = use_odirect_default()
-        super().__init__(k, width, pool_buffers, pooled, readers=readers,
+        super().__init__(k, width, pool_buffers, readers=readers,
                          aligned=odirect)
         self.size = os.path.getsize(path)
         self._rd = _DirectReader(path, odirect)
@@ -775,7 +745,7 @@ class ShardFeed(_FeedBase):
     the pool threads, so a rebuild storm drains at disk speed."""
 
     def __init__(self, paths: Sequence[str], width: int,
-                 pool_buffers: int = 4, pooled: bool = True,
+                 pool_buffers: int = 4,
                  use_mmap: Optional[bool] = None,
                  readers: Optional[int] = None,
                  odirect: Optional[bool] = None):
@@ -783,7 +753,7 @@ class ShardFeed(_FeedBase):
             odirect = use_odirect_default()
         if use_mmap is None:
             use_mmap = use_mmap_default() and not odirect
-        super().__init__(len(paths), width, pool_buffers, pooled,
+        super().__init__(len(paths), width, pool_buffers,
                          readers=readers, aligned=odirect)
         self.shard_size = os.path.getsize(paths[0])
         # all-or-nothing open: a failure on survivor 7 of 10 (EMFILE, a
@@ -833,27 +803,25 @@ class ShardFeed(_FeedBase):
                     f"shard file {self._paths[i]} short read "
                     f"{got} != {n}")
 
-    def _shard_plans(self, batch_size: int,
-                     pad_final: bool) -> Iterator[tuple]:
+    def _shard_plans(self, batch_size: int) -> Iterator[tuple]:
         """Base-shaped ("fill", ...) plans: one segment whose k rows all
         read from the same shard offset (row i = survivor file i), so
-        _FeedBase._submit_plan's acquire/lend/split/pad machinery is
+        _FeedBase._submit_plan's acquire/lend/split machinery is
         reused verbatim — only _fill_one differs."""
         offset = 0
         while offset < self.shard_size:
             n = min(batch_size, self.shard_size - offset)
-            yield ("fill", [(0, [offset] * self.k, n)], n, pad_final)
+            yield ("fill", [(0, [offset] * self.k, n)], n)
             offset += n
 
     def _fill_one(self, buf: np.ndarray, row: int, col: int, off: int,
                   w: int) -> None:
         self._fill_row(buf, row, off, w)
 
-    def batches(self, batch_size: int,
-                pad_final: bool = False) -> Iterator[np.ndarray]:
+    def batches(self, batch_size: int) -> Iterator[np.ndarray]:
         if self.readers > 1:
             yield from self._ordered_parallel(
-                self._shard_plans(batch_size, pad_final))
+                self._shard_plans(batch_size))
             return
         offset = 0
         while offset < self.shard_size:
@@ -862,14 +830,7 @@ class ShardFeed(_FeedBase):
             self._read_hook()
             for i in range(self.k):
                 self._fill_row(buf, i, offset, n)
-            if n < batch_size:
-                if pad_final:
-                    buf[:, n:] = 0
-                    yield self._lend(buf, buf)
-                else:
-                    yield self._lend(buf, buf[:, :n])
-            else:
-                yield self._lend(buf, buf)
+            yield self._lend(buf, buf[:, :n] if n < batch_size else buf)
             offset += n
 
     def close(self) -> None:
@@ -887,7 +848,6 @@ class ShardFeed(_FeedBase):
 
 
 def open_feed(path: str, k: int, width: int, pool_buffers: int = 4,
-              pooled: bool = True,
               use_mmap: Optional[bool] = None,
               readers: Optional[int] = None,
               odirect: Optional[bool] = None) -> "_FeedBase":
@@ -898,15 +858,15 @@ def open_feed(path: str, k: int, width: int, pool_buffers: int = 4,
     if odirect is None:
         odirect = use_odirect_default()
     if odirect:
-        return PreadvFeed(path, k, width, pool_buffers, pooled,
+        return PreadvFeed(path, k, width, pool_buffers,
                           readers=readers, odirect=True)
     if use_mmap is None:
         use_mmap = use_mmap_default()
     if use_mmap:
         try:
-            return MmapFeed(path, k, width, pool_buffers, pooled,
+            return MmapFeed(path, k, width, pool_buffers,
                             readers=readers)
         except (OSError, ValueError):
             pass  # e.g. filesystems that refuse MAP_SHARED; fall through
-    return PreadvFeed(path, k, width, pool_buffers, pooled,
+    return PreadvFeed(path, k, width, pool_buffers,
                       readers=readers, odirect=False)

@@ -38,11 +38,6 @@ Env knobs (all optional):
   WEED_EC_GZIP_MIN/MAX          gzip-worker bounds    (1 / min(8, cores))
   WEED_EC_MMAP=0                force the preadv feed (see ec/feed.py)
   WEED_EC_ODIRECT=1             page-cache-bypassing reads (ec/feed.py)
-  WEED_EC_FORMULATION           pin the GF kernel formulation
-                                (lut|bitplane|xorsched — ops/rs_jax.py);
-                                unset, the governor explores bitplane vs
-                                xorsched per geometry and exploits the
-                                faster measured kernel rate
 """
 
 from __future__ import annotations
@@ -73,9 +68,6 @@ class OperatingPoint(NamedTuple):
     readers: int = 1  # feed reader-pool width (ec/feed.py)
     chips: int = 1    # device-mesh width (parallel/mesh_coder.py)
     gzip_workers: int = 1  # fused warm-down compaction/gzip pool (ec/fused.py)
-    # GF kernel formulation (ops/rs_jax.FORMULATIONS); "" on runs whose
-    # coder exposes no retune hook, so finish_run never mis-attributes
-    formulation: str = "bitplane"
 
 
 # per-batch read time below this is dispatch/syscall-overhead-dominated:
@@ -87,11 +79,6 @@ _BIND_FRACTION = 0.5
 
 class FeedGovernor:
     """Process-global tuner; one instance via get()."""
-
-    # formulation candidates the governor explores per geometry; lut is
-    # reachable only by env pin (it measured slower than both everywhere
-    # the kernel bench has run, so exploration cycles aren't spent on it)
-    _FORM_CANDIDATES = ("bitplane", "xorsched")
 
     def __init__(self) -> None:
         self._lock = threading.Lock()
@@ -120,10 +107,6 @@ class FeedGovernor:
                 self.gzip_min), self.gzip_max)
         self.metrics = metrics_mod.shared("ec")
         self.stage_gbps: dict[str, float] = {}
-        # measured kernel-stage rate per (k, formulation) — the
-        # formulation axis's model, fed by finish_run
-        self.form_gbps: dict[tuple[int, str], float] = {}
-        self._form_by_k: dict[int, str] = {}
         self.runs = 0
 
     # --- planning ---
@@ -154,32 +137,9 @@ class FeedGovernor:
                     break
             op = OperatingPoint(batch, depth, self._write_depth,
                                 self._readers, max(chips, 1),
-                                self._gzip_workers,
-                                self._plan_formulation(k))
+                                self._gzip_workers)
             self._export(op)
             return op
-
-    def _plan_formulation(self, k: int) -> str:
-        """The kernel formulation for the next run at geometry k (lock
-        held): an operator pin (WEED_EC_FORMULATION) always wins; else
-        explore each candidate once, then exploit the argmax of the
-        EMA'd measured kernel rate. Formulation switches are a
-        between-runs retune like every other axis — never mid-stream."""
-        from ..ops import rs_jax
-        pin = rs_jax.formulation_env()
-        if pin is not None:
-            self._form_by_k[k] = pin
-            return pin
-        if not self.enabled:
-            return self._form_by_k.get(k, "bitplane")
-        for cand in self._FORM_CANDIDATES:
-            if (k, cand) not in self.form_gbps:
-                self._form_by_k[k] = cand
-                return cand
-        best = max(self._FORM_CANDIDATES,
-                   key=lambda f: self.form_gbps[(k, f)])
-        self._form_by_k[k] = best
-        return best
 
     # --- measurement + retune ---
 
@@ -206,13 +166,10 @@ class FeedGovernor:
         batch_bytes = k * op.batch_size
         with self._lock:
             self.runs += 1
-            kernel_gbps = None
             for stage, (count, secs) in stages.items():
                 covered = min(count * batch_bytes, nbytes)
                 if secs > 1e-6 and covered:
                     gbps = covered / secs / 1e9
-                    if stage == "kernel":
-                        kernel_gbps = gbps
                     prev = self.stage_gbps.get(stage)
                     self.stage_gbps[stage] = (
                         gbps if prev is None else 0.5 * prev + 0.5 * gbps)
@@ -222,22 +179,11 @@ class FeedGovernor:
                 if g is not None:
                     self.metrics.gauge("feed_stage_gbps", round(g, 3),
                                        labels={"stage": stage})
-            if kernel_gbps is not None and op.formulation:
-                fkey = (k, op.formulation)
-                prev = self.form_gbps.get(fkey)
-                self.form_gbps[fkey] = (
-                    kernel_gbps if prev is None
-                    else 0.5 * prev + 0.5 * kernel_gbps)
-                self.metrics.gauge(
-                    "feed_formulation_gbps",
-                    round(self.form_gbps[fkey], 3),
-                    labels={"k": str(k), "formulation": op.formulation})
             if self.enabled:
                 self._retune(stages, op)
             self._export(OperatingPoint(
                 self._batch, self._depth, self._write_depth,
-                self._readers, op.chips, self._gzip_workers,
-                self._form_by_k.get(k, op.formulation)))
+                self._readers, op.chips, self._gzip_workers))
 
     def _retune(self, stages: dict[str, tuple[int, float]],
                 op: OperatingPoint) -> None:
@@ -296,12 +242,6 @@ class FeedGovernor:
                                         self._depth + 2)
 
     def _export(self, op: OperatingPoint) -> None:
-        if op.formulation:
-            for f in self._FORM_CANDIDATES:
-                self.metrics.gauge(
-                    "feed_formulation_active",
-                    1.0 if f == op.formulation else 0.0,
-                    labels={"formulation": f})
         self.metrics.gauge("feed_batch_bytes", op.batch_size)
         self.metrics.gauge("feed_queue_depth", op.depth,
                            labels={"queue": "read"})

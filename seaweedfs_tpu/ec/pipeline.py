@@ -26,16 +26,11 @@ shard write-back parallelizes across the 14 files):
 
 Batch size and queue depths default to the adaptive governor's operating
 point (ec/governor.py), tuned from the per-stage observe spans this module
-emits — including the kernel FORMULATION axis (_steer_formulation:
-governed runs apply the governor's planned lut/bitplane/xorsched choice
-to the coder between runs, and under "xorsched" the staged-window sinks'
-stage step also transposes each batch to uint32-packed bit-plane rows on
-the stager pool, so every window kernel runs bit-plane-resident and the
-expand/repack cost amortizes per-window, not per-batch). Explicit
-arguments pin the plan. Only parity bytes (m/k of the input) cross
-device->host. Layout semantics are identical to striping.write_ec_files:
-row-major two-tier striping, final batch zero-padded and written
-full-length (tests assert byte-identical output between the two paths).
+emits. Explicit arguments pin the plan. Only parity bytes (m/k of the
+input) cross device->host. Layout semantics are identical to
+striping.write_ec_files: row-major two-tier striping, final batch
+zero-padded and written full-length (tests assert byte-identical output
+between the two paths).
 """
 
 from __future__ import annotations
@@ -89,32 +84,6 @@ def coder_chips(coder: ErasureCoder) -> int:
     every single-chip backend; parallel/mesh_coder.MeshCoder exports
     mesh_devices)."""
     return int(getattr(coder, "mesh_devices", 1) or 1)
-
-
-def _steer_formulation(coder: ErasureCoder,
-                       op: "governor.OperatingPoint"
-                       ) -> "governor.OperatingPoint":
-    """Apply the governor's planned kernel formulation to the coder
-    BEFORE the run starts (a formulation switch swaps executables, so
-    like every governor axis it lands between runs only). The coder
-    reports the formulation it actually runs — env-pinned or explicitly
-    constructed coders ignore the plan — and the returned op carries
-    that, so finish_run's formulation model never attributes one
-    kernel's spans to another. Coders without the hook (numpy, pallas,
-    cpp) report "" which opts the run out of the formulation model."""
-    retune = getattr(coder, "retune_formulation", None)
-    if retune is None:
-        return op._replace(formulation="")
-    return op._replace(formulation=retune(op.formulation))
-
-
-def stager_count_default() -> int:
-    """WEED_EC_STAGERS: concurrent device_put threads for the staged-
-    window sink (device_put releases the GIL, so stagers overlap the
-    H2D copies with the reader pool's page faults instead of
-    serializing fault -> copy -> fault). Same env rule as the reader
-    pool: positive = clamped, unset/0 = one per core up to 4."""
-    return feed_mod.env_thread_count("WEED_EC_STAGERS", 16)
 
 
 class _FanOut:
@@ -249,14 +218,12 @@ def _traced_batches(batches: Iterator[np.ndarray],
 
 
 def _run_pipeline(batches: Iterator[np.ndarray], dispatch, consume,
-                  depth: int, start_d2h: bool = True,
+                  depth: int,
                   trace_ctx: "observe.TraceCtx | None" = None,
                   recycle=None) -> None:
     """reader thread -> main dispatch -> materializer thread.
 
-    consume=None runs without the materializer stage entirely (sink mode:
-    dispatch chains its own on-device state and nothing blocks per
-    batch). recycle (optional) is called on batches drained without being
+    recycle (optional) is called on batches drained without being
     consumed on error paths, so pooled feed buffers keep circulating."""
     read_q: queue.Queue = queue.Queue(maxsize=depth)
     mat_q: queue.Queue = queue.Queue(maxsize=depth)
@@ -294,10 +261,8 @@ def _run_pipeline(batches: Iterator[np.ndarray], dispatch, consume,
                 _recycle(item[0])
 
     reader = threading.Thread(target=reader_main, daemon=True)
-    mat = None
-    if consume is not None:
-        mat = threading.Thread(target=mat_main, daemon=True)
-        mat.start()
+    mat = threading.Thread(target=mat_main, daemon=True)
+    mat.start()
     reader.start()
     drained = False
     batch_i = 0
@@ -323,18 +288,15 @@ def _run_pipeline(batches: Iterator[np.ndarray], dispatch, consume,
             # kick the device->host copy off immediately so it overlaps the
             # next batch's H2D + kernel instead of starting at materialize
             # time (matters most when the transfer link is the bottleneck)
-            start_async = (getattr(handle, "copy_to_host_async", None)
-                           if start_d2h else None)
+            start_async = getattr(handle, "copy_to_host_async", None)
             if start_async is not None:
                 try:
                     start_async()
                 except Exception:
                     pass
-            if mat is not None:
-                mat_q.put((batch, handle))
+            mat_q.put((batch, handle))
     finally:
-        if mat is not None:
-            mat_q.put(_SENTINEL)
+        mat_q.put(_SENTINEL)
         # drain read_q so a reader blocked on a full queue can finish
         # (otherwise a dispatch() exception would deadlock reader.join())
         while not drained:
@@ -343,8 +305,7 @@ def _run_pipeline(batches: Iterator[np.ndarray], dispatch, consume,
                 break
             _recycle(item)
         reader.join()
-        if mat is not None:
-            mat.join()
+        mat.join()
     if errors:
         raise errors[0]
 
@@ -421,8 +382,6 @@ def stream_encode(base_file_name: str, coder: ErasureCoder,
     else:
         op, governed = _resolve_op(batch_size, depth, dat_size,
                                    g.data_shards, coder_chips(coder))
-        if governed:
-            op = _steer_formulation(coder, op)
     src = feed_mod.open_feed(base_file_name + ".dat", g.data_shards,
                              op.batch_size, pool_buffers=op.depth + 2,
                              readers=op.readers)
@@ -474,8 +433,6 @@ def stream_encode_many(base_file_names: Sequence[str], coder: ErasureCoder,
     total = sum(os.path.getsize(b + ".dat") for b in bases)
     op, governed = _resolve_op(batch_size, depth, total, g.data_shards,
                                coder_chips(coder))
-    if governed:
-        op = _steer_formulation(coder, op)
     tctx = observe.ensure_ctx("ec")
     for base in bases:
         with observe.stage("ec.volume", tctx, tags={"base": base}):
@@ -486,290 +443,10 @@ def stream_encode_many(base_file_names: Sequence[str], coder: ErasureCoder,
     return len(bases)
 
 
-# staged window default: bounded so a >HBM volume streams in windows; one
-# window should still swallow a bench-sized volume in one kernel launch
-DEFAULT_WINDOW_BYTES = 2 * 1024 * 1024 * 1024
-
-
-def _windowed_digest_sink(batches: Iterator[np.ndarray], dispatch_window,
-                          stage, depth: int, window_bytes: int,
-                          stats: dict | None,
-                          stagers: Optional[int] = None) -> object:
-    """The staged-window sink schedule: transfers and kernel launches
-    are separated, one launch per window instead of one per batch.
-
-      reader thread -> host batches (bounded queue, disk overlaps staging)
-      stager pool   -> stage_async each batch (H2D only, healthy link);
-                       `stagers` > 1 keeps several device_puts in flight
-                       (each releases the GIL) so the H2D copies overlap
-                       the reader pool's page faults instead of
-                       serializing fault -> copy -> fault on one thread
-      window full   -> ONE multi-batch digest executable per window
-
-    Within a window no kernel runs between transfers, and launch latency
-    amortizes over the window; window N+1's staging overlaps window N's
-    (async) kernels.
-
-    Fills `stats` (when given) with a measured components ledger:
-    read-wait, stage seconds/bytes (plus the overlapped staging WALL
-    span when stagers > 1), dispatch and materialize-wait seconds,
-    batch/window counts — enough to compute each phase's rate and bound
-    the pipeline arithmetically.
-    """
-    import time
-
-    stagers = stagers if stagers is not None else stager_count_default()
-    read_q: queue.Queue = queue.Queue(maxsize=depth)
-    errors: list[BaseException] = []
-    tctx = observe.ensure_ctx("ec")
-
-    def reader_main() -> None:
-        try:
-            for item in batches:
-                read_q.put(item)
-        except BaseException as e:
-            errors.append(e)
-        finally:
-            read_q.put(_SENTINEL)
-
-    reader = threading.Thread(target=reader_main, daemon=True)
-    reader.start()
-
-    acc = None
-    staged: list = []   # handles, or futures of handles (stagers > 1)
-    staged_bytes = 0
-    t_read = t_stage = t_dispatch = 0.0
-    stage_span = [None, None]  # wall [first submit, last complete]
-    n_batches = n_windows = 0
-    total_bytes = 0
-
-    executor = None
-    if stagers > 1:
-        from concurrent.futures import ThreadPoolExecutor
-        executor = ThreadPoolExecutor(max_workers=stagers,
-                                      thread_name_prefix="ec-stager")
-
-    def do_stage(b):
-        h = stage(b)
-        block = getattr(h, "block_until_ready", None)
-        if block is not None:
-            block()
-        stage_span[1] = time.perf_counter()
-        return h
-
-    def resolve(staged_items: list) -> list:
-        return [h.result() if hasattr(h, "result") else h
-                for h in staged_items]
-
-    def flush_window() -> None:
-        nonlocal acc, staged, staged_bytes, n_windows, t_dispatch, t_stage
-        if not staged:
-            return
-        t0 = time.perf_counter()
-        handles = resolve(staged)
-        t_stage += time.perf_counter() - t0
-        t0 = time.perf_counter()
-        with observe.stage("ec.dispatch_window", tctx,
-                           tags={"batches": len(handles)}):
-            acc = dispatch_window(handles, acc)
-        t_dispatch += time.perf_counter() - t0
-        n_windows += 1
-        staged = []
-        staged_bytes = 0
-
-    drained = False
-    try:
-        while True:
-            t0 = time.perf_counter()
-            batch = read_q.get()
-            t_read += time.perf_counter() - t0
-            if batch is _SENTINEL:
-                drained = True
-                break
-            t0 = time.perf_counter()
-            if stage_span[0] is None:
-                stage_span[0] = t0
-            if executor is not None:
-                staged.append(executor.submit(do_stage, batch))
-            else:
-                staged.append(do_stage(batch))
-            t_stage += time.perf_counter() - t0
-            staged_bytes += batch.nbytes
-            total_bytes += batch.nbytes
-            n_batches += 1
-            if staged_bytes >= window_bytes:
-                flush_window()
-        flush_window()
-    finally:
-        while not drained and read_q.get() is not _SENTINEL:
-            pass  # unblock a reader stuck on a full queue after an error
-        reader.join()
-        if executor is not None:
-            executor.shutdown(wait=True)
-    if errors:
-        raise errors[0]
-    if stats is not None:
-        stage_wall = (round(stage_span[1] - stage_span[0], 3)
-                      if stage_span[0] is not None
-                      and stage_span[1] is not None else 0.0)
-        # the effective staging time: with one stager the main thread's
-        # blocked time IS the wall; with a pool the wall span covers the
-        # overlapped copies (blocked time alone would under-report)
-        stage_eff = t_stage if executor is None else (stage_wall
-                                                      or t_stage)
-        stats.update({
-            "staged_bytes": total_bytes, "n_batches": n_batches,
-            "n_windows": n_windows, "read_wait_s": round(t_read, 3),
-            "stage_s": round(stage_eff, 3),
-            "stage_blocked_s": round(t_stage, 3),
-            "stagers": stagers,
-            "stage_gbps": (round(total_bytes / stage_eff / 1e9, 3)
-                           if stage_eff > 1e-9 else None),
-            "dispatch_s": round(t_dispatch, 3),
-        })
-    return acc
-
-
-def stream_encode_device_sink(base_file_name: str, coder: ErasureCoder,
-                              geometry: Geometry = DEFAULT,
-                              batch_size: int = DEFAULT_BATCH_SIZE,
-                              depth: int = DEFAULT_DEPTH,
-                              window_bytes: int = DEFAULT_WINDOW_BYTES,
-                              stats: dict | None = None,
-                              materialize: bool = True,
-                              stagers: Optional[int] = None,
-                              readers: Optional[int] = None) -> np.ndarray:
-    """stream_encode with the parity landing in an on-device sink.
-
-    Runs the same reader schedule as stream_encode but stages batches onto
-    the device first and reduces each window's parity to a [m] uint32
-    wrapping byte-sum digest in ONE executable per window
-    (_windowed_digest_sink) — only 4*m bytes ever cross device->host and
-    no shard files are written. Returns the combined digest.
-
-    Two uses:
-      * bench.py: measures the disk->host->HBM->kernel pipeline without
-        the D2H and shard-write stages stream_encode has; `stats` returns
-        the measured-phase ledger.
-      * tests: the digest equals the per-row byte sums of the parity shard
-        files stream_encode writes (padding encodes to zeros), so the sink
-        is provably the same computation, not a shortcut XLA could elide.
-    """
-    import time
-
-    g = geometry
-    assert coder.k == g.data_shards and coder.m == g.parity_shards
-    dat_size = os.path.getsize(base_file_name + ".dat")
-    # unpooled feed: a whole window of batches stays referenced until its
-    # single dispatch, so buffers are fresh (zero-copy mmap views where
-    # the stripe allows — those reference no buffer at all; the reader
-    # pool prefaults their pages so the stagers' gathers never stall
-    # single-threaded on disk)
-    src = feed_mod.open_feed(base_file_name + ".dat", g.data_shards,
-                             batch_size, pooled=False, readers=readers)
-    t_all = time.perf_counter()
-    try:
-        acc = _windowed_digest_sink(
-            src.batches(stripe_segments(dat_size, g, batch_size),
-                        pad_final=True),
-            coder.encode_digest_window_async, coder.stage_async,
-            depth, window_bytes, stats, stagers=stagers)
-    finally:
-        src.close()
-    if acc is None:
-        out = np.zeros(g.parity_shards, dtype=np.uint32)
-    elif not materialize:
-        # deferred mode for multi-volume batches: return the on-device
-        # acc so windows pipeline across volumes (a batch pays the
-        # device->host sync once at the end, via coder.materialize on
-        # each returned acc)
-        if stats is not None:
-            stats["total_s"] = round(time.perf_counter() - t_all, 3)
-            stats["volume_bytes"] = dat_size
-        return acc
-    else:
-        t0 = time.perf_counter()
-        out = np.asarray(coder.materialize(acc), dtype=np.uint32)
-        if stats is not None:
-            stats["wait_s"] = round(time.perf_counter() - t0, 3)
-    if stats is not None:
-        stats["total_s"] = round(time.perf_counter() - t_all, 3)
-        stats["volume_bytes"] = dat_size
-    return out
-
-
-def stream_rebuild_device_sink(base_file_name: str, coder: ErasureCoder,
-                               victims: Sequence[int],
-                               geometry: Geometry = DEFAULT,
-                               batch_size: int = DEFAULT_BATCH_SIZE,
-                               depth: int = DEFAULT_DEPTH,
-                               window_bytes: int = DEFAULT_WINDOW_BYTES,
-                               stats: dict | None = None,
-                               materialize: bool = True,
-                               stagers: Optional[int] = None,
-                               readers: Optional[int] = None) -> np.ndarray:
-    """stream_rebuild with the reconstructed shards landing in an on-device
-    digest sink (BASELINE config 3's link-independent measurement).
-
-    Treats `victims` as missing, streams k survivor shard files through
-    the staged-window schedule, reconstructs the victim rows on device and
-    digests them to [len(victims)] uint32 wrapping byte sums — verifiable
-    against shard_file_digest() of the real shard files, so the measured
-    path provably performs the full reconstruction compute without pushing
-    shard bytes across a degraded D2H link.
-    Matches RebuildEcFiles' survivor->missing math (ec_encoder.go:233-287).
-    """
-    import time
-
-    g = geometry
-    victims = tuple(victims)  # digest rows follow CALLER order
-    present = [i for i in range(g.total_shards)
-               if i not in victims
-               and os.path.exists(base_file_name + to_ext(i))]
-    if len(present) < g.data_shards:
-        raise ValueError(
-            f"need {g.data_shards} survivors, have {len(present)}")
-    survivors_ids = tuple(present[:g.data_shards])
-    src = feed_mod.ShardFeed(
-        [base_file_name + to_ext(i) for i in survivors_ids],
-        batch_size, pooled=False, readers=readers)
-    shard_size = src.shard_size
-    t_all = time.perf_counter()
-
-    def dispatch_window(staged, acc):
-        return coder.rec_digest_window_async(survivors_ids, victims,
-                                             staged, acc)
-
-    try:
-        acc = _windowed_digest_sink(
-            src.batches(batch_size, pad_final=True), dispatch_window,
-            coder.stage_async, depth, window_bytes, stats,
-            stagers=stagers)
-    finally:
-        src.close()
-    if acc is None:
-        out = np.zeros(len(victims), dtype=np.uint32)
-    elif not materialize:
-        # deferred mode: see stream_encode_device_sink
-        if stats is not None:
-            stats["total_s"] = round(time.perf_counter() - t_all, 3)
-            stats["shard_bytes"] = shard_size
-        return acc
-    else:
-        t0 = time.perf_counter()
-        out = np.asarray(coder.materialize(acc), dtype=np.uint32)
-        if stats is not None:
-            stats["wait_s"] = round(time.perf_counter() - t0, 3)
-    if stats is not None:
-        stats["total_s"] = round(time.perf_counter() - t_all, 3)
-        stats["shard_bytes"] = shard_size
-    return out
-
-
 def shard_file_digest(base_file_name: str,
                       shard_ids: Sequence[int]) -> np.ndarray:
-    """[len(ids)] uint32 wrapping byte-sum of each shard file — the
-    host-side cross-check for the device digest sinks. Accumulates in
+    """[len(ids)] uint32 wrapping byte-sum of each shard file — what the
+    .ecm sidecar stamps and the EC scrubber re-computes. Accumulates in
     uint64 and masks once at the end: explicit wrapping arithmetic, no
     overflow warnings (a full uint64 holds > 2^56 bytes of sum)."""
     out = []
@@ -837,15 +514,6 @@ def stamp_shard_digests(base_file_name: str,
     return digests
 
 
-def parity_file_digest(base_file_name: str,
-                       geometry: Geometry = DEFAULT) -> np.ndarray:
-    """[m] uint32 wrapping byte-sum of each parity shard file — the
-    host-side cross-check for stream_encode_device_sink."""
-    g = geometry
-    return shard_file_digest(
-        base_file_name, range(g.data_shards, g.total_shards))
-
-
 def stream_rebuild(base_file_name: str, coder: ErasureCoder,
                    geometry: Geometry = DEFAULT,
                    batch_size: Optional[int] = None,
@@ -871,10 +539,6 @@ def stream_rebuild(base_file_name: str, coder: ErasureCoder,
     op, governed = _resolve_op(batch_size, depth,
                                g.data_shards * shard_size, g.data_shards,
                                coder_chips(coder))
-    if governed:
-        # steer BEFORE rec_apply_async binds the reconstruction program
-        # to a formulation
-        op = _steer_formulation(coder, op)
     fn = coder.rec_apply_async(survivors_ids, tuple(missing))
     src = feed_mod.ShardFeed(
         [base_file_name + to_ext(i) for i in survivors_ids],

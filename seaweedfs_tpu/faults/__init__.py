@@ -126,11 +126,6 @@ KNOWN_POINTS = frozenset({
     "disk.sync",            # DiskFile.sync fsync barrier — error =
                             # fsync failure (crash-consistency drills
                             # crash "at" a named barrier by erroring it)
-    "ec.stage.pack",        # stage-time bit-plane pack for xorsched
-                            # windows (ec/coder.py JaxCoder.stage_async)
-                            # — drop FAILS the stage: the window kernels
-                            # need the packed layout, so there is no
-                            # silent byte-domain fallback to drift to
     "ec.fused.read",        # fused warm-down compaction-chunk reads
                             # (ec/fused.py) — drop FAILS the chunk
                             # (skipping live extents would compact

@@ -3,9 +3,9 @@
 `parallel/sharded.py` proved the kernel shape (MULTICHIP_r05: the encode
 HLO is collective-free, linear weak scaling over an 8-device mesh); this
 module is the production face: an `ErasureCoder` the streaming pipeline
-(ec/pipeline.py), the store's `ec_generate`/`ec_rebuild`, and the
-device-sink bench paths drive unchanged, with every [k, B] batch's B axis
-sharded over the mesh so ONE governed host feed saturates N chips.
+(ec/pipeline.py) and the store's `ec_generate`/`ec_rebuild` drive
+unchanged, with every [k, B] batch's B axis sharded over the mesh so ONE
+governed host feed saturates N chips.
 
 Sharding shape (the pipeline's batches are [k, B] — k shard rows of a
 B-byte stripe batch):
@@ -29,11 +29,16 @@ materialize slices the pad off). Output is byte-identical to the
 single-chip JaxCoder and to striping.write_ec_files at every geometry —
 tests/test_mesh_coder.py proves it at odd widths and RS(20,4).
 
-Staging is per-chip: `stage_async` splits a host batch into per-device
-column slices and device_puts each one separately (transfers overlap;
-the pipeline's stager pool calls this from several threads), emitting an
-`ec.stage.chip` span and per-chip byte/second counters into the shared
-"ec" metrics registry next to the governor's gauges.
+Staging is per-chip: `_stage_cols` splits a host batch into per-device
+column slices and device_puts each one separately (transfers overlap),
+emitting an `ec.stage.chip` span and per-chip byte/second counters into
+the shared "ec" metrics registry next to the governor's gauges.
+
+One of two kernels runs inside the shard_map step, fixed at
+construction: `bitplane` (rs_jax's XLA matmul, what the CPU test mesh
+and `-coder jax` use) or `pallas` (the hand-tiled TPU kernel, what a TPU
+host's `auto` resolves to; `Store._maybe_mesh` picks it from the class
+of the single-chip coder).
 
 `WEED_EC_MESH_DEVICES` selects the mesh: unset/"0"/"1" means no mesh
 (production paths keep the proven single-chip JaxCoder), "all" takes
@@ -80,16 +85,10 @@ def mesh_device_count() -> int:
 
 
 def coder(data_shards: int, parity_shards: int,
-          n_devices: Optional[int] = None,
-          method: Optional[str] = None):
+          n_devices: Optional[int] = None):
     """The mesh-or-single factory: a MeshCoder over n_devices (default:
     WEED_EC_MESH_DEVICES, then all local devices) when that resolves to
-    more than one chip, else the proven single-chip backend for
-    `method` (JaxCoder, or PallasCoder for method="pallas").
-
-    method=None defers to WEED_EC_FORMULATION (rs_jax.formulation_env),
-    falling back to "bitplane" — so the operator's pin reaches the mesh
-    path exactly like the single-chip one."""
+    more than one chip, else the single-chip JaxCoder."""
     if n_devices is None:
         if os.environ.get("WEED_EC_MESH_DEVICES", "").strip():
             n_devices = mesh_device_count() or 1
@@ -97,12 +96,8 @@ def coder(data_shards: int, parity_shards: int,
             import jax
             n_devices = len(jax.devices())
     if n_devices <= 1:
-        if method == "pallas":
-            from ..ec.coder import PallasCoder
-            return PallasCoder(data_shards, parity_shards)
-        return JaxCoder(data_shards, parity_shards, method=method)
-    return MeshCoder(data_shards, parity_shards, n_devices=n_devices,
-                     method=method)
+        return JaxCoder(data_shards, parity_shards)
+    return MeshCoder(data_shards, parity_shards, n_devices=n_devices)
 
 
 class _MeshHandle:
@@ -123,24 +118,17 @@ class _MeshHandle:
 class MeshCoder(JaxCoder):
     """ErasureCoder over a jax.sharding.Mesh (axis "batch" = the stripe
     batch's column axis). See the module docstring for the sharding
-    shape; everything the JaxCoder exposes (digest windows, staged
-    sinks, reconstruct) works here, mesh-sharded where it counts."""
-
-    _VALID_METHODS = frozenset(rs_jax.FORMULATIONS) | {"pallas"}
-    _TPU_METHODS = JaxCoder._TPU_METHODS | {"pallas"}
+    shape."""
 
     def __init__(self, data_shards: int, parity_shards: int,
                  n_devices: Optional[int] = None,
-                 method: Optional[str] = None, interpret: bool = False):
-        method = method or rs_jax.formulation_env() or "bitplane"
+                 method: str = "bitplane", interpret: bool = False):
+        if method not in ("bitplane", "pallas"):
+            raise ValueError(f"unknown mesh coder method {method!r}")
+        self.method = method
         # method="pallas" in Pallas interpret mode: the CPU test mesh only
         self._interpret = interpret
-        if method not in self._VALID_METHODS:
-            raise ValueError(f"unknown mesh coder method {method!r}")
-        # always pass the resolved method down: a mesh coder's sharded
-        # executables are built for one formulation, so it stays pinned
-        # (retune_formulation is a no-op here)
-        super().__init__(data_shards, parity_shards, method=method)
+        super().__init__(data_shards, parity_shards)
         import jax
         from jax.sharding import Mesh
         devs = jax.devices()
@@ -159,7 +147,8 @@ class MeshCoder(JaxCoder):
         metrics_mod.shared("ec").gauge("feed_mesh_devices", n)
 
     def describe(self) -> dict:
-        return {**super().describe(), "mesh_devices": self.mesh_devices}
+        return {**super().describe(), "formulation": self.method,
+                "mesh_devices": self.mesh_devices}
 
     # --- staging: per-chip sub-batches ---
 
@@ -201,43 +190,17 @@ class MeshCoder(JaxCoder):
         return jax.make_array_from_single_device_arrays(
             arr.shape, self._col_sharding(), shards)
 
-    def stage_async(self, data: np.ndarray):
-        arr = self._pad_cols(np.asarray(data, dtype=np.uint8))
-        return self._stage_cols(arr)
-
     # --- encode: shard_map over the batch axis, collective-free ---
 
     def _apply_matrix_fn(self, matrix: np.ndarray):
         """The per-chip GF kernel for this coder's method — pallas keeps
         the hand-tiled TPU kernel inside the shard_map step (the demo's
-        _apply_fn shape), bitplane/lut ride the rs_jax formulations."""
+        _apply_fn shape), bitplane is rs_jax's XLA matmul."""
         if self.method == "pallas":
             from ..ops import rs_pallas
             return rs_pallas.gf_apply_pallas(matrix,
                                              interpret=self._interpret)
-        if self.method == "bitplane":
-            return rs_jax.gf_apply_bitplane(matrix)
-        if self.method == "xorsched":
-            # pure elementwise per-chip (pack -> XOR schedule -> unpack,
-            # no cross-column ops), so shard_map stays collective-free —
-            # tests assert it on the compiled HLO
-            return rs_jax.gf_apply_xorsched(matrix)
-        return rs_jax.gf_apply_lut(matrix)
-
-    # inherited digest windows route through these two hooks, so the
-    # mesh's pallas/lut choice holds there too instead of silently
-    # remapping to another formulation
-    def _encode_fn(self):
-        if self.method == "pallas":
-            return self._apply_matrix_fn(
-                gf256.parity_matrix(self.k, self.m))
-        return super()._encode_fn()
-
-    def _rec_apply(self, present, missing):
-        if self.method == "pallas":
-            return self._apply_matrix_fn(gf256.reconstruction_matrix(
-                self.k, self.m, tuple(present), tuple(missing)))
-        return super()._rec_apply(present, missing)
+        return rs_jax.gf_apply_bitplane(matrix)
 
     def _rec_apply_sync(self, present, missing, stage=""):
         # a degraded read's interval: one chip, host-side pad/slice and
@@ -326,33 +289,6 @@ class MeshCoder(JaxCoder):
             return _MeshHandle(fn(self._stage_cols(arr)), width)
 
         return run
-
-    # --- window sinks ---
-    # The inherited JaxCoder window executables work unchanged: staged
-    # batches arrive column-sharded from stage_async and GSPMD partitions
-    # the dynamic-matrix digest program along the batch axis (the final
-    # [m] digest sum is the only cross-chip reduction, 4*m bytes). AOT
-    # warming from unsharded abstract shapes would compile a single-device
-    # program the sharded call could not reuse — on a mesh the compile
-    # happens at first dispatch.
-
-    def _dyn_window_builder(self):
-        # mesh staging is per-chip BYTE column slices (the packed
-        # bit-plane transpose would couple stripe columns across the
-        # 32-bit word, fighting the column sharding), so xorsched windows
-        # ride the byte-domain dyn program here; the sharded encode
-        # kernel itself (_apply_matrix_fn) still runs the XOR schedule
-        if self.method in ("bitplane", "xorsched"):
-            return self._dyn_window_fn
-        return None
-
-    def warm_encode_digest_window(self, n_batches: int,
-                                  shape: tuple) -> None:
-        return None
-
-    def warm_rec_digest_window(self, present, missing, n_batches: int,
-                               shape: tuple) -> None:
-        return None
 
 
 def mesh_status() -> dict:

@@ -1,10 +1,10 @@
 """Hand-rolled asyncio HTTP data plane for the volume server.
 
 The reference's Go server frames requests in the runtime at negligible
-cost; CPython + aiohttp charge ~90µs/request of single-core CPU — on this
-class of host a trivial aiohttp handler tops out ~11k req/s while a
-minimal asyncio.Protocol HTTP loop does ~50k (measured, bench.py ceiling
-probe). Since the volume data plane (GET/POST/DELETE /fid —
+cost; CPython + aiohttp charge every request single-core CPU that a
+minimal asyncio.Protocol HTTP loop does not (PERF.md section 6, PR 26: an
+EC GET's p50 9.66 -> 3.58 ms once it stopped crossing to aiohttp). Since
+the volume data plane (GET/POST/DELETE /fid —
 volume_server_handlers_read.go:28, volume_server_handlers_write.go:19) is
 the server's req/s-bound surface, it is served here by a minimal HTTP/1.1
 protocol sharing the SAME store/batcher/guard objects as the aiohttp app.

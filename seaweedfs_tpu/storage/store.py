@@ -201,7 +201,7 @@ class Store:
         names (numpy/cpp/pallas — byte-exact references, kernel pins)
         stay exactly what was asked for; "mesh" resolved through the
         registry already."""
-        if self.coder_name not in ("auto", "jax", "jax_lut"):
+        if self.coder_name not in ("auto", "jax"):
             return c
         try:
             from ..parallel import mesh_coder as mesh_mod
@@ -209,12 +209,7 @@ class Store:
             if n < 2:
                 return c
             from ..ec.coder import PallasCoder
-            if isinstance(c, PallasCoder):
-                method = "pallas"
-            elif isinstance(c, ec_mod.JaxCoder):
-                method = c.method
-            else:
-                method = "bitplane"
+            method = "pallas" if isinstance(c, PallasCoder) else "bitplane"
             return mesh_mod.MeshCoder(g.data_shards, g.parity_shards,
                                       n_devices=n, method=method)
         except Exception as e:

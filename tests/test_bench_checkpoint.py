@@ -1,6 +1,6 @@
 """bench.py per-phase incremental checkpointing (VERDICT r5: a timed-out
-rebuild phase nulled the whole BENCH_DETAIL.json record two rounds
-running — now each phase lands on disk the moment it completes)."""
+phase nulled the whole BENCH_DETAIL.json record two rounds running — now
+each phase lands on disk the moment it completes)."""
 
 import json
 import os
@@ -17,19 +17,19 @@ def _bench():
 def test_checkpoint_writes_partial_record(tmp_path):
     bench = _bench()
     path = str(tmp_path / "BENCH_DETAIL.json")
-    detail = {"volume_bytes": 123, "incomplete": True,
-              "encode": {"value_gbps": 1.5}}
+    detail = {"incomplete": True,
+              "fused_compact_gzip_rs": {"gbps": 1.5}}
     bench._checkpoint(detail, path=path)
     got = json.load(open(path))
-    assert got["encode"]["value_gbps"] == 1.5
+    assert got["fused_compact_gzip_rs"]["gbps"] == 1.5
     assert got["incomplete"] is True
 
     # a later phase extends the same record; earlier numbers survive
-    detail["rebuild"] = {"rebuild_p50_s": 2.0}
+    detail["multichip"] = {"scaling": {}}
     bench._checkpoint(detail, path=path)
     got = json.load(open(path))
-    assert got["encode"]["value_gbps"] == 1.5
-    assert got["rebuild"]["rebuild_p50_s"] == 2.0
+    assert got["fused_compact_gzip_rs"]["gbps"] == 1.5
+    assert got["multichip"]["scaling"] == {}
 
 
 def test_checkpoint_is_atomic(tmp_path):
@@ -45,7 +45,7 @@ def test_checkpoint_is_atomic(tmp_path):
     assert not os.path.exists(path + ".tmp")
 
 
-def test_main_checkpoints_every_phase(monkeypatch, tmp_path):
+def test_main_checkpoints_every_phase(monkeypatch, tmp_path, capsys):
     """Drive bench.main() with every phase stubbed: each phase completes
     -> the on-disk record already contains it (and a phase that 'hangs'
     forever would still leave all earlier phases on disk)."""
@@ -57,10 +57,9 @@ def test_main_checkpoints_every_phase(monkeypatch, tmp_path):
     def fake_phase(name, work, timeout_s):
         if os.path.exists(path):
             snapshots.append(set(json.load(open(path))))
-        return {"value_gbps": 1.0, "kernel": {}, "phase_wall_s": 0.1}
+        return {"gbps": 1.0, "phase_wall_s": 0.1}
 
     monkeypatch.setattr(bench, "_run_phase", fake_phase)
-    monkeypatch.setattr(bench, "_make_volume", lambda *a: None)
     monkeypatch.setattr(bench, "bench_system",
                         lambda w: {"write": {"req_s": 1},
                                    "read": {"req_s": 1}})
@@ -70,12 +69,19 @@ def test_main_checkpoints_every_phase(monkeypatch, tmp_path):
     monkeypatch.setattr(bench, "HARD_BUDGET_S", 10_000.0)
     bench.main()
 
-    # the kernel phase saw encode's checkpoint; rebuild saw kernel's
-    assert {"encode"} <= snapshots[1]
-    assert {"encode", "kernel_phase"} <= snapshots[2]
+    # the subprocess phases are fused, then multichip: multichip saw
+    # the checkpoint of fused and of every inline phase between them
+    assert len(snapshots) == 1
+    assert {"fused_compact_gzip_rs", "system_req_s", "saturation",
+            "georepl"} <= snapshots[0]
     final = json.load(open(path))
     assert "incomplete" not in final
-    for key in ("encode", "kernel_phase", "rebuild",
-                "fused_compact_gzip_rs", "system_req_s", "saturation",
-                "disk_needle_map"):
+    for key in ("fused_compact_gzip_rs", "system_req_s", "saturation",
+                "multichip", "disk_needle_map"):
         assert key in final, key
+    for key in ("encode", "kernel_phase", "rebuild", "volume_bytes"):
+        assert key not in final, key
+    # the last printed line names what ran, and carries no GB/s headline
+    last = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert "fused_compact_gzip_rs" in last["phases"]
+    assert "value" not in last and "metric" not in last

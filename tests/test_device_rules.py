@@ -37,7 +37,6 @@ def fake_tpu(monkeypatch):
     before = (jax.config.jax_compilation_cache_dir,
               jax.config.jax_persistent_cache_min_compile_time_secs)
     monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
-    monkeypatch.delenv("WEED_EC_FORMULATION", raising=False)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     yield
     jax.config.update("jax_compilation_cache_dir", before[0])
@@ -95,17 +94,19 @@ def test_pallas_coder_needs_a_tpu_unless_interpreting():
 
 
 def test_formulation_pins_are_never_dropped(fake_tpu, monkeypatch):
-    """On a TPU only programs that compiled there are selectable, and a
-    pin the Pallas coder cannot honour is an error, not ignored."""
-    with pytest.raises(ValueError, match="does not compile on a TPU"):
-        coder_mod.JaxCoder(10, 4, method="xorsched")
-    with pytest.raises(ValueError, match="does not compile on a TPU"):
-        get_coder("jax_lut", 10, 4)
-    c = coder_mod.JaxCoder(10, 4)
-    assert c.retune_formulation("xorsched") == "bitplane"
+    """A coder has one kernel, so there is no pin to drop: the names that
+    selected an XLA formulation are unknown (the error lists what
+    exists), and the old environment pin changes no coder."""
+    for gone in ("jax_lut", "jax_xorsched"):
+        with pytest.raises(KeyError) as e:
+            get_coder(gone, 10, 4)
+        for name in ("cpp", "jax", "mesh", "numpy", "pallas"):
+            assert name in str(e.value), (gone, name)
     monkeypatch.setenv("WEED_EC_FORMULATION", "xorsched")
-    with pytest.raises(ValueError, match="one kernel"):
-        get_coder("auto", 10, 4)
+    assert get_coder("jax", 10, 4).describe()["formulation"] == "bitplane"
+    c = get_coder("auto", 10, 4)
+    assert isinstance(c, coder_mod.PallasCoder)
+    assert "formulation" not in c.describe()
 
 
 def test_sibling_shards_are_pinned_to_the_host_before_jax_loads():

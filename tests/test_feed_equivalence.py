@@ -102,8 +102,7 @@ def test_buffer_pool_bounded_and_recycled(tmp_path):
     recycling returns the SAME buffers."""
     size = 64 * 1024
     base = _write_dat(tmp_path, "1", size, seed=11)
-    f = feed_mod.PreadvFeed(base + ".dat", 10, 1024, pool_buffers=2,
-                            pooled=True)
+    f = feed_mod.PreadvFeed(base + ".dat", 10, 1024, pool_buffers=2)
     seen_ids = set()
     it = f.batches(stripe_segments(size, GEO, 1024))
     held = [next(it), next(it)]
@@ -142,8 +141,7 @@ def test_feed_close_unblocks_starved_reader(tmp_path):
     import threading
     size = 64 * 1024
     base = _write_dat(tmp_path, "1", size, seed=13)
-    f = feed_mod.PreadvFeed(base + ".dat", 10, 1024, pool_buffers=2,
-                            pooled=True)
+    f = feed_mod.PreadvFeed(base + ".dat", 10, 1024, pool_buffers=2)
     it = f.batches(stripe_segments(size, GEO, 1024))
     _ = [next(it), next(it)]  # drain the pool, never recycle
     raised = threading.Event()
@@ -258,7 +256,7 @@ def test_shard_feed_reader_pool_agrees_with_serial(tmp_path):
         f = feed_mod.ShardFeed(paths, 512, pool_buffers=3,
                                readers=readers)
         out = []
-        for b in f.batches(512, pad_final=True):
+        for b in f.batches(512):
             out.append(b.copy())
             f.recycle(b)
         f.close()

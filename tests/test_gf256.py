@@ -117,59 +117,6 @@ def test_reconstruct_data_only():
     assert out[13] is None  # parity left unfilled in data-only mode
 
 
-def test_xor_schedule_matches_dense_reference_random():
-    """Schedule-CSE correctness: for random binary matrices (including
-    one with an all-zero row), executing the greedy-CSE XOR schedule on
-    dense 0/1 inputs equals the mod-2 matmul, and the scheduled XOR
-    count never exceeds the dense popcount bound."""
-    from seaweedfs_tpu.ops import xor_schedule
-
-    rng = np.random.default_rng(6)
-    cases = [rng.integers(0, 2, size=(r, c)).astype(np.uint8)
-             for r, c in [(8, 8), (32, 80), (17, 33)]]
-    zero_row = rng.integers(0, 2, size=(10, 12)).astype(np.uint8)
-    zero_row[4, :] = 0
-    cases.append(zero_row)
-    for w in cases:
-        sched = xor_schedule.build_schedule(w)
-        assert sched.sched_xors <= sched.dense_xors, (sched.sched_xors,
-                                                      sched.dense_xors)
-        bits = rng.integers(0, 2, size=(w.shape[1], 257)).astype(np.uint8)
-        got = xor_schedule.apply_schedule_numpy(sched, bits)
-        want = (w.astype(np.int64) @ bits.astype(np.int64)) % 2
-        assert np.array_equal(got, want.astype(np.uint8)), w.shape
-
-
-def test_xor_schedule_cse_beats_dense_on_cauchy():
-    """On the real expanded RS matrices the shared-pair CSE must deliver
-    a real reduction, not just parity with the dense bound (the perf
-    claim the xorsched formulation rests on). Logged for the record."""
-    from seaweedfs_tpu.ops import xor_schedule
-
-    for k, m in [(10, 4), (20, 4)]:
-        sched = xor_schedule.schedule_for_matrix(gf256.parity_matrix(k, m))
-        saved = 1 - sched.sched_xors / sched.dense_xors
-        print(f"RS({k},{m}): dense {sched.dense_xors} XORs -> scheduled "
-              f"{sched.sched_xors} ({saved:.1%} saved)")
-        assert sched.sched_xors < 0.8 * sched.dense_xors, (
-            k, m, sched.sched_xors, sched.dense_xors)
-
-
-def test_xor_schedule_pack_unpack_roundtrip():
-    from seaweedfs_tpu.ops import xor_schedule
-
-    rng = np.random.default_rng(7)
-    for n in [1, 31, 32, 33, 1000]:
-        x = rng.integers(0, 256, size=(10, n)).astype(np.uint8)
-        planes = np.asarray(xor_schedule.pack_planes(x))
-        assert planes.shape == (80, xor_schedule.packed_width(n))
-        assert planes.dtype == np.uint32
-        # packed footprint never exceeds the 32-rounded input bytes
-        assert planes.nbytes == 10 * 8 * 4 * ((n + 31) // 32)
-        back = np.asarray(xor_schedule.unpack_planes(planes, n))
-        assert np.array_equal(back, x), n
-
-
 def test_too_few_shards_raises():
     k, m = 4, 2
     data = np.zeros((k, 8), dtype=np.uint8)

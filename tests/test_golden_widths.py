@@ -137,40 +137,6 @@ def _fold(tmp_path, width: int) -> tuple[str, list[int]]:
     return base, doomed
 
 
-def _stream_vs_reference(tmp_path, geometry, k, m, batch_size):
-    """stream_encode with the xorsched JaxCoder vs write_ec_files with
-    the numpy coder over the reference .dat — every shard file must be
-    byte-identical (the interop bar every formulation must clear)."""
-    from seaweedfs_tpu.ec import pipeline
-    from seaweedfs_tpu.ec.coder import JaxCoder
-
-    ref = str(tmp_path / "ref")
-    shutil.copy(os.path.join(FIXTURES, "1.dat"), ref + ".dat")
-    striping.write_ec_files(ref, get_coder("numpy", k, m), geometry,
-                            buffer_size=50)
-    got = str(tmp_path / "got")
-    shutil.copy(os.path.join(FIXTURES, "1.dat"), got + ".dat")
-    pipeline.stream_encode(got, JaxCoder(k, m, method="xorsched"),
-                           geometry, batch_size=batch_size)
-    for i in range(k + m):
-        assert _sha(got + to_ext(i)) == _sha(ref + to_ext(i)), to_ext(i)
-
-
-def test_xorsched_stream_identity_rs10_4(tmp_path):
-    _stream_vs_reference(tmp_path, SHRUNK, 10, 4, batch_size=4096)
-
-
-def test_xorsched_stream_identity_rs10_4_odd_batch(tmp_path):
-    # a batch width that is neither a multiple of 32 (the packed-word
-    # lane) nor of the stripe blocks: the pack/unpack tail-word path
-    _stream_vs_reference(tmp_path, SHRUNK, 10, 4, batch_size=999)
-
-
-def test_xorsched_stream_identity_rs20_4(tmp_path):
-    wide = Geometry(20, 4, large_block_size=10000, small_block_size=100)
-    _stream_vs_reference(tmp_path, wide, 20, 4, batch_size=4096)
-
-
 def test_ecj_fold_width4_pinned(tmp_path):
     base, doomed = _fold(tmp_path, 4)
     assert _sha(base + ".ecx") == GOLDEN["ecx_w4_folded"]

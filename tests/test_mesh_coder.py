@@ -91,51 +91,6 @@ def test_mesh_pallas_method_matches_single_chip():
     assert np.array_equal(mc.encode(data), gf256.encode_parity(data, 4))
 
 
-@pytest.mark.parametrize("width", [8 * 512, 999, 7])
-def test_mesh_xorsched_matches_single_chip(width):
-    """The xorsched formulation through the shard_map step: per-chip
-    pack -> XOR schedule -> unpack is pure elementwise, so the mesh
-    stays byte-identical at every width including the padded path."""
-    mc = MeshCoder(10, 4, n_devices=8, method="xorsched")
-    rng = np.random.default_rng(width + 1)
-    data = rng.integers(0, 256, (10, width), dtype=np.uint8)
-    got = mc.encode(data)
-    assert got.shape == (4, width)
-    assert np.array_equal(got, gf256.encode_parity(data, 4))
-
-
-def test_mesh_xorsched_wide_geometry_and_rebuild():
-    mc = MeshCoder(20, 4, n_devices=8, method="xorsched")
-    rng = np.random.default_rng(99)
-    data = rng.integers(0, 256, (20, 1013), dtype=np.uint8)
-    parity = gf256.encode_parity(data, 4)
-    assert np.array_equal(mc.encode(data), parity)
-    rows = list(data) + list(parity)
-    missing = (2, 21)
-    present = tuple(i for i in range(24) if i not in missing)[:20]
-    survivors = np.stack([rows[i] for i in present])
-    out = mc.materialize(mc.rec_apply_async(present, missing)(survivors))
-    for got, want_id in zip(out, missing):
-        assert np.array_equal(got, rows[want_id]), want_id
-
-
-def test_mesh_xorsched_collective_free():
-    """The headline composition claim: swapping the per-chip kernel for
-    the packed XOR schedule inserts no cross-chip collective into the
-    compiled encode HLO."""
-    mc = MeshCoder(10, 4, n_devices=8, method="xorsched")
-    assert mc.encode_is_collective_free()
-
-
-def test_mesh_formulation_env_pin(monkeypatch):
-    monkeypatch.setenv("WEED_EC_FORMULATION", "xorsched")
-    mc = MeshCoder(10, 4, n_devices=8)
-    assert mc.method == "xorsched"
-    # mesh coders stay pinned: the governor cannot retune a formulation
-    # whose sharded executables are already built
-    assert mc.retune_formulation("bitplane") == "xorsched"
-
-
 def test_encode_hlo_is_collective_free(mesh8):
     """The property MULTICHIP_r05 proved for the demo kernel, asserted
     for the production coder from the compiled HLO: encode inserts no
@@ -184,18 +139,6 @@ def test_stream_rebuild_mesh_byte_identical(tmp_path, mesh8):
     assert sorted(rebuilt) == victims
     for i in range(14):
         assert _sha(base + ec.to_ext(i)) == golden[i], i
-
-
-def test_device_sink_digest_matches_shards_on_mesh(tmp_path, mesh8):
-    """The windowed digest sink with mesh-sharded staging computes the
-    same parity the fan-out path writes (the sink provably performs the
-    full encode, sharded or not)."""
-    base = _write_dat(tmp_path, "1", 30_001, seed=9)
-    pipeline.stream_encode(base, mesh8, GEO, batch_size=1000)
-    dig = pipeline.stream_encode_device_sink(base, mesh8, GEO,
-                                             batch_size=1000)
-    assert np.array_equal(np.asarray(dig),
-                          pipeline.parity_file_digest(base, GEO))
 
 
 def test_governed_mesh_run_exports_chips(tmp_path, mesh8):
@@ -363,7 +306,7 @@ def test_repair_pool_checks_out_numbered_workers(monkeypatch):
 # -------------------------------------------------------- status faces
 
 def test_mesh_status_reports_chips_after_staging(mesh8):
-    mesh8.stage_async(np.zeros((10, 800), dtype=np.uint8))
+    mesh8.materialize(mesh8.encode_async(np.zeros((10, 800), dtype=np.uint8)))
     st = mesh_status()
     assert st["mesh_devices"] == 8
     assert len(st["chips"]) == 8

@@ -111,81 +111,101 @@ def test_mixed_tier_layout_consistency(tmp_path, dat_blocks):
     assert open(base + ".dat", "rb").read() == dat
 
 
-def _coder(name: str):
+CODERS = ["numpy", "jax", "pallas"]
+GEOMETRIES = {"rs10+4": GEO,
+              "rs20+4": ec.Geometry(20, 4, large_block_size=10000,
+                                    small_block_size=100),
+              "rs6+3": ec.Geometry(6, 3, large_block_size=10000,
+                                   small_block_size=100)}
+
+
+def _coder(name: str, g=GEO):
     if name == "pallas":  # interpret mode is the test's explicit request
         from seaweedfs_tpu.ec.coder import PallasCoder
-        return PallasCoder(10, 4, interpret=True)
-    return ec.get_coder(name, 10, 4)
+        return PallasCoder(g.data_shards, g.parity_shards, interpret=True)
+    return ec.get_coder(name, g.data_shards, g.parity_shards)
 
 
-@pytest.mark.parametrize("coder_name", ["numpy", "jax", "pallas"])
-def test_device_sink_digest_matches_shard_files(tmp_path, coder_name):
-    # the on-device parity sink (bench mode) must be the same computation
-    # as the file-writing path: its [m] uint32 wrapping byte-sum digest has
-    # to equal the sums over the parity shard files stream_encode writes
-    build_volume(tmp_path)
-    coder = _coder(coder_name)
+def _write_dat(tmp_path, size: int, seed: int) -> str:
+    import numpy as np
+    os.makedirs(str(tmp_path), exist_ok=True)
     base = os.path.join(str(tmp_path), "1")
-    pipeline.stream_encode(base, coder, GEO, batch_size=4096)
-    want = pipeline.parity_file_digest(base, GEO)
-    got = pipeline.stream_encode_device_sink(base, coder, GEO,
-                                             batch_size=4096)
-    assert got.tolist() == want.tolist()
-    # batch width must not change the combined digest
-    got2 = pipeline.stream_encode_device_sink(base, coder, GEO,
-                                              batch_size=512)
-    assert got2.tolist() == want.tolist()
+    with open(base + ".dat", "wb") as f:
+        f.write(np.random.default_rng(seed).integers(
+            0, 256, size, dtype=np.uint8).tobytes())
+    return base
 
 
-@pytest.mark.parametrize("coder_name", ["numpy", "jax", "pallas"])
-def test_device_sink_windowed_schedule(tmp_path, coder_name):
-    # a window smaller than the volume forces multiple window dispatches;
-    # the chained digest must still equal the shard-file ground truth
-    build_volume(tmp_path)
-    coder = _coder(coder_name)
-    base = os.path.join(str(tmp_path), "1")
-    pipeline.stream_encode(base, coder, GEO, batch_size=4096)
-    want = pipeline.parity_file_digest(base, GEO)
-    stats = {}
-    got = pipeline.stream_encode_device_sink(
-        base, coder, GEO, batch_size=1024,
-        window_bytes=10 * 1024, stats=stats)
-    assert got.tolist() == want.tolist()
-    assert stats["n_windows"] >= 2
-    assert stats["n_batches"] >= stats["n_windows"]
-    assert stats["staged_bytes"] >= stats["volume_bytes"]
+def _host_recomputes() -> float:
+    from seaweedfs_tpu.utils import metrics as metrics_mod
+    return metrics_mod.shared("ec").value("ec_digest_host_recompute")
 
 
-@pytest.mark.parametrize("coder_name", ["numpy", "jax", "pallas"])
-def test_rebuild_device_sink_digest(tmp_path, coder_name):
-    # the reconstruction digest sink must reproduce the byte sums of the
-    # real shard files for the victim ids WITHOUT writing anything
-    build_volume(tmp_path)
-    coder = _coder(coder_name)
-    base = os.path.join(str(tmp_path), "1")
-    pipeline.stream_encode(base, coder, GEO, batch_size=4096)
-    victims = [0, 3, 7, 12]
-    want = pipeline.shard_file_digest(base, victims)
-    stats = {}
-    got = pipeline.stream_rebuild_device_sink(
-        base, coder, victims, GEO, batch_size=4096, stats=stats)
-    assert got.tolist() == want.tolist()
-    assert stats["n_batches"] >= 1
-    # no shard file was touched
-    assert sorted(os.listdir(tmp_path))  # files all still present
-    for i in victims:
-        assert os.path.exists(base + ec.to_ext(i))
+@pytest.mark.parametrize("geometry", list(GEOMETRIES))
+@pytest.mark.parametrize("coder_name", CODERS)
+def test_stream_encode_stamps_digests_of_its_files(tmp_path, coder_name,
+                                                   geometry):
+    # the scrubber's reference comes out of the encode pass itself: the
+    # .ecm digests equal the byte sums of all k+m files as written, and
+    # a following stamp finds nothing left to read back
+    g = GEOMETRIES[geometry]
+    base = _write_dat(tmp_path, 123_457, seed=g.total_shards)
+    pipeline.stream_encode(base, _coder(coder_name, g), g, batch_size=4096)
+    stamped = pipeline.read_stamped_digests(base)
+    true = pipeline.shard_file_digest(base, range(g.total_shards))
+    assert stamped == {i: int(true[i]) for i in range(g.total_shards)}
+    before = _host_recomputes()
+    assert pipeline.stamp_shard_digests(base, g) == stamped
+    assert _host_recomputes() == before
 
 
-def test_rebuild_device_sink_too_few_survivors(tmp_path):
-    build_volume(tmp_path)
-    coder = ec.get_coder("numpy", 10, 4)
-    base = os.path.join(str(tmp_path), "1")
-    pipeline.stream_encode(base, coder, GEO, batch_size=4096)
-    for i in range(5):
+@pytest.mark.parametrize("lost", [[4], [12], [0, 3, 7, 12], [1, 2, 5, 8]],
+                         ids=["one_data", "one_parity", "mixed4", "four_data"])
+@pytest.mark.parametrize("coder_name", CODERS)
+def test_stream_rebuild_restores_bytes_and_digests(tmp_path, coder_name,
+                                                   lost):
+    # encode under the host reference, rebuild under the coder in test:
+    # the files come back byte for byte and still answer to the digests
+    # the encode stamped
+    base = _write_dat(tmp_path, 98_765, seed=len(lost))
+    pipeline.stream_encode(base, ec.get_coder("numpy", 10, 4), GEO,
+                           batch_size=4096)
+    golden = {i: _sha(base + ec.to_ext(i)) for i in range(14)}
+    stamped = pipeline.read_stamped_digests(base)
+    for i in lost:
         os.remove(base + ec.to_ext(i))
-    with pytest.raises(ValueError):
-        pipeline.stream_rebuild_device_sink(base, coder, [5, 6], GEO)
+    rebuilt = pipeline.stream_rebuild(base, _coder(coder_name), GEO,
+                                      batch_size=1000)
+    assert sorted(rebuilt) == lost
+    for i in range(14):
+        assert _sha(base + ec.to_ext(i)) == golden[i], i
+    true = pipeline.shard_file_digest(base, lost)
+    assert [stamped[i] for i in lost] == [int(d) for d in true]
+
+
+@pytest.mark.parametrize("coder_name", CODERS)
+def test_governed_equals_pinned(tmp_path, coder_name):
+    # the governor's operating point changes the schedule, never a byte
+    from seaweedfs_tpu.ec import governor
+    governor.reset()
+    try:
+        coder = _coder(coder_name)
+        a = _write_dat(tmp_path / "a", 210_011, seed=5)
+        b = _write_dat(tmp_path / "b", 210_011, seed=5)
+        pipeline.stream_encode(a, coder, GEO)  # governed
+        pipeline.stream_encode(b, coder, GEO, batch_size=777, depth=2)
+        assert governor.get().runs == 1
+        for i in range(14):
+            assert _sha(a + ec.to_ext(i)) == _sha(b + ec.to_ext(i)), i
+        os.remove(a + ec.to_ext(2))
+        os.remove(b + ec.to_ext(2))
+        assert pipeline.stream_rebuild(a, coder, GEO) == [2]  # governed
+        assert pipeline.stream_rebuild(b, coder, GEO, batch_size=333,
+                                       depth=2) == [2]
+        assert governor.get().runs == 2
+        assert _sha(a + ec.to_ext(2)) == _sha(b + ec.to_ext(2))
+    finally:
+        governor.reset()
 
 
 def test_ec_layout_marker(tmp_path, caplog):

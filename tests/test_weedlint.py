@@ -162,24 +162,21 @@ def test_sharded_scope_pinned():
 
 
 def test_ops_scope_pinned():
-    """The kernel formulations (ops/rs_jax.py, ops/rs_pallas.py,
-    ops/xor_schedule.py) export the governor's formulation gauges and
-    the xorsched path holds packed device buffers across a window —
-    exactly what the metric-label / resource-leak guards exist for. A
-    scope edit that narrows either away from the ops tree silently
-    un-lints the hottest kernels in the repo."""
+    """The kernels (ops/rs_jax.py, ops/rs_pallas.py) run under jit on
+    device buffers and sit on every EC path — what the metric-label /
+    resource-leak guards exist for. A scope edit that narrows either
+    away from the ops tree silently un-lints the hottest code in the
+    repo."""
     for name in ("metric-label-registry", "resource-leak"):
         rule = RULES[name]
         for path in ("seaweedfs_tpu/ops/rs_jax.py",
-                     "seaweedfs_tpu/ops/rs_pallas.py",
-                     "seaweedfs_tpu/ops/xor_schedule.py"):
+                     "seaweedfs_tpu/ops/rs_pallas.py"):
             assert rule.applies_to(path), \
                 f"rule {name} no longer covers {path}"
-    # the stage-time pack fault point must stay registered: firing an
-    # unknown point is exactly what fault-point-registry catches
+    # the point went with the packed-plane staging it guarded: a
+    # declared point nothing fires is what fault-point-registry catches
     from seaweedfs_tpu import faults
-    assert "ec.stage.pack" in faults.KNOWN_POINTS, \
-        "fault point ec.stage.pack dropped from faults.KNOWN_POINTS"
+    assert "ec.stage.pack" not in faults.KNOWN_POINTS
 
 
 # ------------------------------------------------------- tree enforcement
