@@ -46,6 +46,8 @@ from .geometry import DEFAULT, Geometry, to_ext
 from .locate import Interval, locate_data
 
 ShardReader = Callable[[int, int, int], Optional[bytes]]
+# what `EcVolume.locate` gives: (stored offset, size, intervals)
+Located = tuple[int, int, list[Interval]]
 
 # .ecx lookups by the way an entry was fetched, on the volume server's
 # /metrics (seaweedfs_tpu_volume_ecx_lookups_total{via=}); the label
@@ -67,6 +69,10 @@ def _map_shared(f, size: int) -> Optional[mmap.mmap]:
         return mmap.mmap(f.fileno(), size, mmap.MAP_SHARED, mmap.PROT_READ)
     except (OSError, ValueError):
         return None
+
+
+class _Unmapped(Exception):
+    """An interval `read_needle_nowait` cannot slice: it declines."""
 
 
 def _unmap(mm: Optional[mmap.mmap]) -> None:
@@ -92,9 +98,23 @@ class EcShard:
         self.size = os.path.getsize(self.path)
         self._mm = _map_shared(self._f, self.size)
 
+    def slice_at(self, offset: int, size: int) -> Optional[bytes]:
+        """The bytes out of the mapping, never a system call; None where
+        there is no mapping, the range is not wholly inside the file, or
+        the mapping is closed under the slice (the shard is being
+        unmounted)."""
+        mm = self._mm
+        if mm is None or offset < 0 or offset + size > self.size:
+            return None
+        try:
+            return mm[offset:offset + size]
+        except ValueError:
+            return None
+
     def read_at(self, offset: int, size: int) -> bytes:
-        if self._mm is not None and 0 <= offset and offset + size <= self.size:
-            return self._mm[offset:offset + size]
+        data = self.slice_at(offset, size)
+        if data is not None:
+            return data
         # positioned read: no shared seek state, safe under concurrency;
         # short reads past EOF keep the reference semantics
         return os.pread(self._f.fileno(), size, offset)
@@ -233,7 +253,7 @@ class EcVolume:
                 hi = mid
         raise KeyError(f"needle {needle_id:x} not in ec volume {self.vid}")
 
-    def locate(self, needle_id: int) -> tuple[int, int, list[Interval]]:
+    def locate(self, needle_id: int) -> Located:
         """(offset, size, intervals) for a needle
         (LocateEcShardNeedle, ec_volume.go:190-204)."""
         offset, size = self.find_needle(needle_id)
@@ -256,20 +276,70 @@ class EcVolume:
 
     # --- read path ---
     def read_needle(self, needle_id: int, cookie: Optional[int] = None,
-                    shard_reader: Optional[ShardReader] = None) -> Needle:
+                    shard_reader: Optional[ShardReader] = None,
+                    located: Optional[Located] = None) -> Needle:
         """One EC needle read. Its parts are observe stages (`ec.get.*`:
         PERF.md has the table), each exclusive of the others, under
-        whatever request context is ambient."""
+        whatever request context is ambient. `located` is what a
+        `read_needle_nowait` that declined had found: the index is then
+        not searched again."""
+        return self._read(
+            needle_id, cookie, located or self._locate_live(needle_id),
+            lambda iv: self._read_interval(iv, shard_reader))
+
+    def read_needle_nowait(self, needle_id: int,
+                           cookie: Optional[int] = None,
+                           max_size: int = 64 * 1024
+                           ) -> tuple[Optional[Needle], Optional[Located]]:
+        """`read_needle` for a caller on the event loop's thread, the twin
+        of `Volume.read_needle_nowait`: (needle, None) when everything
+        the read needs is in this process's address space (the index
+        mapped, a stored needle of at most `max_size`, every interval
+        inside the mapped file of a shard mounted here), and then no
+        system call, no lock waited for, nothing that gives the GIL away.
+        Otherwise it declines, (None, located): `read_needle`, on a
+        thread that may block, takes `located` (None where the index was
+        not searched). Raises what `read_needle` raises."""
+        if self._ecx_mm is None or not self._layout_checked:
+            return None, None  # a search of preads; the marker's first read
+        located = self._locate_live(needle_id)
+        if located[1] > max_size:
+            return None, located
+        try:
+            return self._read(needle_id, cookie, located,
+                              self._slice_interval), None
+        except _Unmapped:
+            return None, located
+
+    def _locate_live(self, needle_id: int) -> Located:
         with observe.stage("ec.get.ecx"):
-            offset, size, intervals = self.locate(needle_id)
-        if t.size_is_deleted(size):
+            located = self.locate(needle_id)
+        if t.size_is_deleted(located[1]):
             raise KeyError(f"needle {needle_id:x} deleted")
-        parts = [self._read_interval(iv, shard_reader) for iv in intervals]
+        return located
+
+    def _read(self, needle_id: int, cookie: Optional[int],
+              located: Located,
+              read_interval: Callable[[Interval], bytes]) -> Needle:
+        parts = [read_interval(iv) for iv in located[2]]
         with observe.stage("ec.get.parse"):
             n = Needle.from_bytes(b"".join(parts), self.version)
             if cookie is not None and n.cookie != cookie:
                 raise KeyError(f"needle {needle_id:x} cookie mismatch")
         return n
+
+    def _slice_interval(self, iv: Interval) -> bytes:
+        """A present interval out of its shard's mapping; `_Unmapped`
+        where the shard is not mounted here or `slice_at` has nothing."""
+        shard_id, offset = iv.to_shard_id_and_offset(self.g)
+        shard = self.shards.get(shard_id)
+        if shard is None:
+            raise _Unmapped
+        with observe.stage("ec.get.shard_read"):
+            data = shard.slice_at(offset, iv.size)
+        if data is None:
+            raise _Unmapped
+        return data
 
     def _read_interval(self, iv: Interval,
                        shard_reader: Optional[ShardReader]) -> bytes:

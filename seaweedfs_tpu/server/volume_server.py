@@ -59,6 +59,11 @@ from ..utils import metrics as metrics_mod
 
 log = logging.getLogger("volume")
 
+# labels of `ec_read_nowait` (`VolumeServer.read_ec_needle`): made once,
+# a GET only counts
+_SERVED = {"result": "served"}
+_DECLINED = {"result": "declined"}
+
 
 def _resize_image(data: bytes, mime: str, width: int, height: int,
                   mode: str) -> bytes:
@@ -306,6 +311,10 @@ class VolumeServer:
         # counters on /metrics from the start, a 0 and not an absence
         self.metrics.count("ec_read_inline", 0)
         self.metrics.count("ec_read_proxied", 0)
+        # and how often the loop's thread read the needle itself
+        # (`read_ec_needle`): born at 0 likewise
+        for result in (_SERVED, _DECLINED):
+            self.metrics.count("ec_read_nowait", 0, labels=result)
         # what an EC read took from peers (`_make_shard_reader`), and how
         # often it asked the master where a shard is: born at 0 likewise
         for via in ("grpc", "http"):
@@ -735,28 +744,46 @@ class VolumeServer:
         raises (NeedleExpired, NeedleNotFound / KeyError, NeedleDeleted,
         CrcError).
 
-        The read runs in the executor under a copy of the request's
-        context: its stages are children of the open `ec.get.handler`
-        and reach the request's wide event. Both hand-offs are stages:
-        `ec.get.queue` from the submit to the worker's first line,
-        `ec.get.resume` from the worker's last line, over the wait for
-        the loop, to the response in hand."""
+        The loop's thread tries the read first
+        (`EcVolume.read_needle_nowait`: every interval in a mapped shard
+        file here, so microseconds and no system call, where the hand-off
+        to an executor thread and back costs two GIL convoys) and
+        `ec_read_nowait{result=}` says how it went. A read that declined
+        (a lost or remote shard, a big needle, nothing mapped) runs in
+        the executor with what the loop located, under a copy of the
+        request's context: its stages are children of the open
+        `ec.get.handler` and reach the request's wide event. Those
+        hand-offs are stages: `ec.get.queue` from the submit to the
+        worker's first line (a declined read alone), `ec.get.resume`
+        from the read's last line, over the wait for the loop if there
+        was one, to the response in hand."""
         self.metrics.count("read")
         if await faults.fire_async("volume.read"):
             return None
-        submit_us = int(time.time() * 1e6)
-        t_submit = time.perf_counter()
-
-        def work():
-            observe.record_span(
-                "ec.get.queue", None, submit_us,
-                int((time.perf_counter() - t_submit) * 1e6))
-            n = self.store.read_needle(fid.volume_id, fid.key, fid.cookie)
-            return n, time.time(), time.perf_counter()
-
         with self.metrics.timed("read"):
-            n, done_s, t_done = await asyncio.get_event_loop(
-                ).run_in_executor(None, contextvars.copy_context().run, work)
+            served = True  # an error of the search is the loop's answer too
+            try:
+                n, located = self.store.read_ec_needle_nowait(
+                    fid.volume_id, fid.key, fid.cookie)
+                served = n is not None
+            finally:
+                self.metrics.count("ec_read_nowait",
+                                   labels=_SERVED if served else _DECLINED)
+            done_s, t_done = time.time(), time.perf_counter()
+            if not served:
+                submit_us, t_submit = int(done_s * 1e6), t_done
+
+                def work():
+                    observe.record_span(
+                        "ec.get.queue", None, submit_us,
+                        int((time.perf_counter() - t_submit) * 1e6))
+                    read = self.store.read_needle(
+                        fid.volume_id, fid.key, fid.cookie, located=located)
+                    return read, time.time(), time.perf_counter()
+
+                n, done_s, t_done = await asyncio.get_event_loop(
+                    ).run_in_executor(None, contextvars.copy_context().run,
+                                      work)
         # lifecycle heat: EC reads are the warm tier's un-EC signal
         self.heat.record_read(fid.volume_id)
         resp = respond(n)

@@ -467,15 +467,27 @@ class Store:
         return v.write_needle(n)
 
     def read_needle(self, vid: int, needle_id: int,
-                    cookie: Optional[int] = None) -> Needle:
+                    cookie: Optional[int] = None, located=None) -> Needle:
+        """`located`: what `read_ec_needle_nowait` found before it
+        declined, so that the EC volume's index is searched once."""
         v = self.find_volume(vid)
         if v is not None:
             return v.read_needle(needle_id, cookie=cookie)
         ev = self.find_ec_volume(vid)
         if ev is not None:
             return ev.read_needle(needle_id, cookie=cookie,
-                                  shard_reader=self._remote_shard_reader(ev))
+                                  shard_reader=self._remote_shard_reader(ev),
+                                  located=located)
         raise KeyError(f"volume {vid} not found")
+
+    def read_ec_needle_nowait(self, vid: int, needle_id: int,
+                              cookie: Optional[int] = None):
+        """`EcVolume.read_needle_nowait` for an event loop's thread:
+        (needle, None), or (None, located) for "use `read_needle`"."""
+        ev = self.find_ec_volume(vid)
+        if ev is None:
+            return None, None
+        return ev.read_needle_nowait(needle_id, cookie)
 
     def delete_needle(self, vid: int, n: Needle) -> int:
         v = self.find_volume(vid)

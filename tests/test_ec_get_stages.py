@@ -36,9 +36,13 @@ GEOMETRY = Geometry(10, 4, large_block_size=64 * 1024,
 COOKIE = 0x1234
 N_NEEDLES = 40
 
-# stage -> how often one degraded GET of one interval records it
-PER_GET = {"ec.get": 1, "ec.get.handler": 1, "ec.get.queue": 1,
-           "ec.get.ecx": 1, "ec.get.parse": 1, "ec.get.resume": 1}
+# stage -> how often one GET records it: one that the loop's thread
+# serves itself (every interval in a mapped shard file here) queues for
+# no executor thread; one that declines (a lost shard) does, and still
+# searches the index once
+PER_SERVED_GET = {"ec.get": 1, "ec.get.handler": 1, "ec.get.ecx": 1,
+                  "ec.get.parse": 1, "ec.get.resume": 1}
+PER_DECLINED_GET = {**PER_SERVED_GET, "ec.get.queue": 1}
 # stack, then pad to the bucket: two copies, two entries
 PER_LOST_INTERVAL = {"ec.get.peer_fetch": 1, "ec.get.survivors": 1,
                      "ec.get.stack_pad": 2, "ec.get.dispatch": 1,
@@ -130,6 +134,19 @@ class Served:
                       r"(\S+)$", text, re.M)
             for which in ("inline", "proxied")))
 
+    def read_counts(self) -> dict[str, int]:
+        """How the loop's own read went, the `read` histogram's count
+        and the index lookups, off /metrics."""
+        text = self.get("metrics").decode()
+        return {name: int(float(m.group(1))) if m else 0 for name, m in (
+            (name, re.search(rf"^seaweedfs_tpu_volume_{re.escape(key)} "
+                             r"(\S+)$", text, re.M))
+            for name, key in (
+                ("served", 'ec_read_nowait_total{result="served"}'),
+                ("declined", 'ec_read_nowait_total{result="declined"}'),
+                ("timed", "read_seconds_count"),
+                ("lookups", 'ecx_lookups_total{via="mmap"}')))}
+
     def stop(self) -> None:
         asyncio.run_coroutine_threadsafe(self.runner.cleanup(),
                                          self.loop).result(10)
@@ -166,7 +183,7 @@ def test_degraded_get_is_one_tree_with_every_stage(served):
         == payload(served.lost - 1)
     spans = trace_of("tree1")
     got = names(spans)
-    for stage, count in {**PER_GET, **PER_LOST_INTERVAL}.items():
+    for stage, count in {**PER_DECLINED_GET, **PER_LOST_INTERVAL}.items():
         assert got[stage] == count, (stage, got)
     # the interval was lost: nothing read it, nobody's flight was ridden
     assert got["ec.get.shard_read"] == 0
@@ -249,7 +266,7 @@ def test_range_get_takes_the_hop_and_records_both_sides(served):
         == payload(served.lost - 1)[:100]
     spans = trace_of("range1")
     got = names(spans)
-    for stage, count in {**PER_GET, **PER_LOST_INTERVAL}.items():
+    for stage, count in {**PER_DECLINED_GET, **PER_LOST_INTERVAL}.items():
         assert got[stage] == count, (stage, got)
     by_id = {s["id"]: s for s in spans}
     roots = [s for s in spans if not s["parent"]]
@@ -277,11 +294,76 @@ def test_debug_trace_serves_the_same_tree(served):
 def test_present_interval_is_read_not_reconstructed(served):
     assert served.get(served.fid(served.present), trace="present1") \
         == payload(served.present - 1)
-    got = names(trace_of("present1"))
-    for stage, count in PER_GET.items():
+    spans = trace_of("present1")
+    got = names(spans)
+    for stage, count in PER_SERVED_GET.items():
         assert got[stage] == count, (stage, got)
     assert got["ec.get.shard_read"] == 1
+    assert got["ec.get.queue"] == 0
     assert not any(got[s] for s in PER_LOST_INTERVAL)
+    # all of it on the loop's thread, under the handler's stage
+    by_id = {s["id"]: s for s in spans}
+    for s in spans:
+        if s["name"] in ("ec.get.ecx", "ec.get.shard_read", "ec.get.parse",
+                         "ec.get.resume"):
+            assert by_id[s["parent"]]["name"] == "ec.get.handler", s
+
+
+@pytest.mark.parametrize("needle,headers,plane", [
+    ("present", {}, "fast"), ("lost", {}, "fast"),
+    # a Range is the aiohttp plane's, which makes the same read
+    ("present", {"Range": "bytes=10-19"}, "aiohttp"),
+    ("lost", {"Range": "bytes=10-19"}, "aiohttp"),
+])
+def test_loop_reads_what_is_mapped_here_and_hands_on_the_rest(
+        served, monkeypatch, needle, headers, plane):
+    """A GET whose interval is in a mapped shard file is answered on the
+    loop's thread: no executor submit, no `ec.get.queue`. One that meets
+    the lost shard declines and is handed on with what the loop located:
+    one search of the index, not two. Either way the `read` histogram
+    and the heat tracker take it once."""
+    from seaweedfs_tpu.lifecycle.heat import HeatTracker
+    submits, heat = [], []
+    real_submit = served.loop.run_in_executor
+    real_heat = HeatTracker.record_read
+    monkeypatch.setattr(
+        served.loop, "run_in_executor",
+        lambda *a: submits.append(a) or real_submit(*a))
+    monkeypatch.setattr(
+        HeatTracker, "record_read",
+        lambda self, vid: heat.append(vid) or real_heat(self, vid))
+    needle_id = getattr(served, needle)
+    trace = f"nowait-{needle}-{plane}"
+    before = served.read_counts()
+    del submits[:]  # (the /metrics read is none of this GET's)
+    body = served.get(served.fid(needle_id), trace=trace, headers=headers)
+    n_submits = len(submits)
+    assert body == (payload(needle_id - 1)[10:20] if headers
+                    else payload(needle_id - 1))
+    got = names(trace_of(trace))
+    after = served.read_counts()
+    rose = {k: after[k] - before[k] for k in after}
+    on_loop = needle == "present"
+    assert rose == {"served": int(on_loop), "declined": int(not on_loop),
+                    "timed": 1, "lookups": 1}
+    assert n_submits == got["ec.get.queue"] == int(not on_loop)
+    assert got["ec.get.ecx"] == got["ec.get.resume"] == 1
+    assert heat == [1]
+    assert bool(got["GET /" + served.fid(needle_id)]) == (plane == "aiohttp")
+
+
+def test_an_error_of_the_search_is_the_loops_answer(served):
+    """Unknown and deleted needles end on the loop's thread too."""
+    before = served.read_counts()
+    with pytest.raises(urllib.error.HTTPError) as miss:
+        served.get(served.fid(N_NEEDLES + 7), trace="nowait-miss")
+    assert miss.value.code == 404
+    miss.value.close()
+    got = names(trace_of("nowait-miss"))
+    after = served.read_counts()
+    assert after["served"] == before["served"] + 1
+    assert after["declined"] == before["declined"]
+    assert got["ec.get.ecx"] == 1 and got["ec.get.queue"] == 0
 
 
 def test_counters_rise_by_the_spans_counts(served):
@@ -297,8 +379,9 @@ def test_counters_rise_by_the_spans_counts(served):
         time.sleep(0.01)
     rose = {k: after[k] - before.get(k, 0) for k in after
             if after[k] != before.get(k, 0)}
-    want = {k: 2 * v for k, v in PER_GET.items()}
+    want = {k: 2 * v for k, v in PER_SERVED_GET.items()}
     want.update(PER_LOST_INTERVAL)
+    want["ec.get.queue"] = 1  # the degraded GET alone was queued
     want["ec.get.shard_read"] = 1
     assert rose == want
 
@@ -318,7 +401,7 @@ def test_wide_event_carries_the_exclusive_stages(served):
     events = wideevents.events(trace="wide1")
     assert len(events) == 1
     stages = events[0]["stages"]
-    for stage in {**PER_GET, **PER_LOST_INTERVAL}:
+    for stage in {**PER_DECLINED_GET, **PER_LOST_INTERVAL}:
         assert (stage in stages) == (stage not in ENCLOSING), stage
     assert not ENCLOSING & set(stages)
     # so the tail is put down to what the GET waited for, not to the
@@ -339,7 +422,7 @@ def test_wrapped_ring_leaves_the_counters_whole(served, monkeypatch):
             break
         time.sleep(0.01)
     assert len(observe.spans()) == 8
-    for stage, count in {**PER_GET, **PER_LOST_INTERVAL}.items():
+    for stage, count in {**PER_DECLINED_GET, **PER_LOST_INTERVAL}.items():
         assert after[stage] - before[stage] == 5 * count, stage
 
 

@@ -660,6 +660,16 @@ def _plane_counts(port: int) -> dict:
         for name in ("ec_read_inline", "ec_read_proxied", "read"))}
 
 
+def _nowait_counts(port: int) -> tuple[int, int]:
+    """EC reads the loop's thread made itself, and those it handed on."""
+    import re
+    text = _req(port, "GET", "/metrics")[2].decode()
+    return tuple(int(float(re.search(
+        rf'^seaweedfs_tpu_volume_ec_read_nowait_total{{result="{result}"}} '
+        r"(\S+)$", text, re.M).group(1)))
+        for result in ("served", "declined"))
+
+
 _EC_HEADERS = ("etag", "x-last-modified", "content-type", "content-length",
                "content-disposition", "content-encoding")
 
@@ -687,11 +697,17 @@ def test_ec_get_answers_alike_on_both_planes(ec_planes, case, method,
     if "If-None-Match" in headers:
         headers = {"If-None-Match": _req(fast.port, "GET", path)[1]["etag"]}
     before = _plane_counts(fast.port)
+    nowait = _nowait_counts(fast.port)
     got = _req(fast.port, method, path, headers=headers)
     after = _plane_counts(fast.port)
+    served, declined = _nowait_counts(fast.port)
     want = _req(slow.port, method, path, headers=headers)
     assert got[0] == want[0] == status
     assert got[2] == want[2]
+    # the loop's thread read it, unless it lies on the lost shard
+    on_loop = needle != EC_LOST
+    assert (served - nowait[0], declined - nowait[1]) \
+        == (int(on_loop), int(not on_loop))
     # the fast path answered, and no EC GET went to the aiohttp listener
     assert after["ec_read_inline"] == before["ec_read_inline"] + 1
     assert after["ec_read_proxied"] == before["ec_read_proxied"]
@@ -737,6 +753,7 @@ def test_volume_read_fault_acts_once_on_inline_ec_get(ec_planes, action,
     from seaweedfs_tpu import faults
     fast, _, ids = ec_planes
     before = _plane_counts(fast.port)
+    nowait = _nowait_counts(fast.port)
     faults.clear()
     faults.set_fault("volume.read", action, ms=200.0)
     try:
@@ -758,8 +775,14 @@ def test_volume_read_fault_acts_once_on_inline_ec_get(ec_planes, action,
     assert after["ec_read_inline"] == before["ec_read_inline"] + 1
     assert after["ec_read_proxied"] == before["ec_read_proxied"]
     assert after["read"] == before["read"] + 1
+    # the point stands before the read, the loop's own included: a delay
+    # is followed by it, a drop or an error is all there is
+    served, declined = _nowait_counts(fast.port)
+    assert (served - nowait[0], declined - nowait[1]) \
+        == (int(action == "delay"), 0)
 
 
+@pytest.mark.parametrize("where", ["loop", "executor"])
 @pytest.mark.parametrize("raised,status,body,inline,proxied", [
     ("NeedleDeleted", 404, {"error": "deleted"}, 1, 0),
     ("NeedleExpired", 404, {"error": "not found"}, 1, 0),
@@ -767,16 +790,23 @@ def test_volume_read_fault_acts_once_on_inline_ec_get(ec_planes, action,
     ("CrcError", 500, {"error": "data corruption"}, 0, 1),
 ])
 def test_what_the_ec_read_raises_maps_as_on_the_aiohttp_plane(
-        ec_planes, monkeypatch, raised, status, body, inline, proxied):
+        ec_planes, monkeypatch, raised, status, body, inline, proxied,
+        where):
+    """Whichever thread the read ends on: the loop's own (a needle in a
+    mapped shard file) or an executor's (one on the lost shard)."""
     from seaweedfs_tpu.storage import needle, volume
     exc = getattr(volume, raised, None) or getattr(needle, raised)
     fast, slow, ids = ec_planes
 
-    def read_needle(self, vid, needle_id, cookie=None):
+    def read_needle(self, vid, needle_id, cookie=None, located=None):
         raise exc("as the store would")
 
-    monkeypatch.setattr(Store, "read_needle", read_needle)
-    path = _ec_fid(ids[EC_PRESENT])
+    if where == "loop":
+        monkeypatch.setattr(Store, "read_ec_needle_nowait", read_needle)
+        path = _ec_fid(ids[EC_PRESENT])
+    else:
+        monkeypatch.setattr(Store, "read_needle", read_needle)
+        path = _ec_fid(EC_LOST)
     before = _plane_counts(fast.port)
     got = _req(fast.port, "GET", path)
     want = _req(slow.port, "GET", path)
