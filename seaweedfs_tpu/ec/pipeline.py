@@ -46,6 +46,7 @@ import numpy as np
 
 from .. import observe
 from ..utils import durable
+from ..utils import metrics as metrics_mod
 from . import feed as feed_mod
 from . import governor
 from .coder import ErasureCoder
@@ -328,6 +329,15 @@ def _stream_encode_core(batches: Iterator[np.ndarray], coder: ErasureCoder,
     scrubber's reference digest comes out of the encode pass itself and
     the host never re-reads the fresh shards to compute it."""
     fan = _FanOut(list(shard_paths), op.write_depth)
+    counters = metrics_mod.shared("ec")
+
+    def dispatch(batch: np.ndarray):
+        # inside `ec.dispatch`, whose clock this rides: what went to the
+        # coder, the last row's zero padding included
+        handle = coder.encode_async(batch)
+        counters.count("encode_input_bytes", batch.nbytes)
+        counters.count("encode_batches")
+        return handle
 
     def consume(data: np.ndarray, handle) -> None:
         with observe.stage("ec.kernel", tctx):
@@ -349,7 +359,7 @@ def _stream_encode_core(batches: Iterator[np.ndarray], coder: ErasureCoder,
     try:
         _run_pipeline(
             _traced_batches(batches, tctx),
-            coder.encode_async, consume, op.depth, trace_ctx=tctx,
+            dispatch, consume, op.depth, trace_ctx=tctx,
             recycle=recycle)
     finally:
         # what is still queued, then each shard file's fsync and close
@@ -494,7 +504,6 @@ def stamp_shard_digests(base_file_name: str,
         return {}
     digests = {int(k): int(v)
                for k, v in (meta.get("shard_digests") or {}).items()}
-    from ..utils import metrics as metrics_mod
     recomputed = 0
     for sid in range(geometry.total_shards):
         if sid in digests or not os.path.exists(

@@ -330,6 +330,10 @@ class VolumeServer:
         for via in ("mmap", "pread"):
             metrics_mod.shared("volume").count("ecx_lookups", 0,
                                                labels={"via": via})
+        # and what an encode handed to the coder (ec/pipeline.py counts
+        # it, in the shared `ec` registry): born at 0 too
+        for name in ("encode_input_bytes", "encode_batches"):
+            metrics_mod.shared("ec").count(name, 0)
         self.app = self._build_app()
         # the EC read path fetches missing shards from peers through this
         store._remote_shard_reader = self._make_shard_reader

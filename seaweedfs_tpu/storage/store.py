@@ -523,13 +523,18 @@ class Store:
         return list(range(g.total_shards))
 
     def ec_generate(self, vid: int) -> list[int]:
-        v, base, g = self._ec_seal(vid)
-        # streaming pipeline: overlapped disk read / H2D / kernel / shard
-        # write-back (ec/pipeline.py) — byte-identical to the synchronous
-        # write_ec_files layout; geometry follows the collection policy
-        # and is stamped into the .ecm for rebuild/mount/decode
-        ec_pipeline.stream_encode(base, self.coder(g), g)
-        return self._ec_finish_generate(v, base, g)
+        # `ec.generate` encloses a warm-down from seal to stamp: its
+        # seconds are the time one was in flight, its count the passes
+        with observe.stage("ec.generate", observe.capture(),
+                           enclosing=True):
+            v, base, g = self._ec_seal(vid)
+            # streaming pipeline: overlapped disk read / H2D / kernel /
+            # shard write-back (ec/pipeline.py) — byte-identical to the
+            # synchronous write_ec_files layout; geometry follows the
+            # collection policy and is stamped into the .ecm for
+            # rebuild/mount/decode
+            ec_pipeline.stream_encode(base, self.coder(g), g)
+            return self._ec_finish_generate(v, base, g)
 
     def ec_generate_many(self, vids: list[int]) -> dict[int, list[int]]:
         """Encode a WINDOW of volumes back-to-back: all volumes of one
@@ -544,30 +549,36 @@ class Store:
         absent = [vid for vid in vids if self.find_volume(vid) is None]
         if absent:
             raise KeyError(f"volume(s) {absent} not found")
-        by_geometry: dict[ec_mod.Geometry, list] = {}
-        sealed: list = []
-        for vid in vids:
-            was_read_only = self.find_volume(vid).read_only
-            v, base, g = self._ec_seal(vid)
-            by_geometry.setdefault(g, []).append((vid, v, base))
-            sealed.append((v, base, was_read_only))
-        out: dict[int, list[int]] = {}
-        try:
-            for g, items in by_geometry.items():
-                ec_pipeline.stream_encode_many(
-                    [base for _, _, base in items], self.coder(g), g)
-                for vid, v, base in items:
-                    out[vid] = self._ec_finish_generate(v, base, g)
-        except BaseException:
-            # a mid-window failure must not leave the REST of the batch
-            # sealed with nothing to show for it: lift seals we applied
-            # on volumes whose encode never completed (stream_encode
-            # writes the .ecm marker only at the end of each volume)
-            for v, base, was_read_only in sealed:
-                if not was_read_only and not os.path.exists(base + ".ecm"):
-                    v.read_only = False
-            raise
-        return out
+        # one `ec.generate` a window: it seals all, encodes all and then
+        # finishes all, so no one volume's seal-to-stamp stands apart
+        with observe.stage("ec.generate", observe.capture(),
+                           tags={"volumes": len(vids)}, enclosing=True):
+            by_geometry: dict[ec_mod.Geometry, list] = {}
+            sealed: list = []
+            for vid in vids:
+                was_read_only = self.find_volume(vid).read_only
+                v, base, g = self._ec_seal(vid)
+                by_geometry.setdefault(g, []).append((vid, v, base))
+                sealed.append((v, base, was_read_only))
+            out: dict[int, list[int]] = {}
+            try:
+                for g, items in by_geometry.items():
+                    ec_pipeline.stream_encode_many(
+                        [base for _, _, base in items], self.coder(g), g)
+                    for vid, v, base in items:
+                        out[vid] = self._ec_finish_generate(v, base, g)
+            except BaseException:
+                # a mid-window failure must not leave the REST of the
+                # batch sealed with nothing to show for it: lift seals we
+                # applied on volumes whose encode never completed
+                # (stream_encode writes the .ecm marker only at the end
+                # of each volume)
+                for v, base, was_read_only in sealed:
+                    if not was_read_only \
+                            and not os.path.exists(base + ".ecm"):
+                        v.read_only = False
+                raise
+            return out
 
     # --- fused warm-down: compact + gzip + RS + digest in one pass ---
 
