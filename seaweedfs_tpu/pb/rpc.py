@@ -417,22 +417,31 @@ def _retried_unary(call_fn):
 
 
 class _SpecStub:
-    """Client multicallables (what a generated stub would contain)."""
+    """Client multicallables (what a generated stub would contain), each
+    built when it is first asked for and kept: a caller that makes one
+    kind of call pays for one multicallable, not for the whole spec."""
 
     def __init__(self, channel, service: str, spec: dict):
-        factories = {"uu": channel.unary_unary,
-                     "us": channel.unary_stream,
-                     "ss": channel.stream_stream}
-        for name, (kind, req, resp) in spec.items():
-            call = _traced_call(factories[kind](
-                f"/{service}/{name}",
-                request_serializer=req.SerializeToString,
-                response_deserializer=resp.FromString))
-            if kind == "uu" and name in _RETRYABLE_RPCS:
-                # retries re-enter _traced_call, so every attempt
-                # re-injects fresh trace metadata
-                call = _retried_unary(call)
-            setattr(self, name, call)
+        self._channel, self._service, self._spec = channel, service, spec
+
+    def __getattr__(self, name: str):
+        # reached only for what is not an attribute yet
+        if name.startswith("_") or name not in self._spec:
+            raise AttributeError(name)
+        kind, req, resp = self._spec[name]
+        factory = {"uu": self._channel.unary_unary,
+                   "us": self._channel.unary_stream,
+                   "ss": self._channel.stream_stream}[kind]
+        call = _traced_call(factory(
+            f"/{self._service}/{name}",
+            request_serializer=req.SerializeToString,
+            response_deserializer=resp.FromString))
+        if kind == "uu" and name in _RETRYABLE_RPCS:
+            # retries re-enter _traced_call, so every attempt
+            # re-injects fresh trace metadata
+            call = _retried_unary(call)
+        setattr(self, name, call)
+        return call
 
 
 class MasterStub(_SpecStub):

@@ -28,6 +28,13 @@ from ..utils import durable
 log = logging.getLogger("volume.grpc")
 
 _CHUNK = 1 << 20
+# the largest shard range the loop's thread copies out of a mapping
+# itself (as `EcVolume.read_needle_nowait`'s needle): a degraded read asks
+# for an interval of a needle, a rebuild or a copy for megabytes
+_INLINE_MAX = 64 << 10
+# labels of `ec_shard_read_served`: made once, a read only counts
+_INLINE = {"how": "inline"}
+_EXECUTOR = {"how": "executor"}
 
 
 def _run(fn):
@@ -421,7 +428,21 @@ class VolumeGrpcServicer:
                                 context):
         """Stream a shard byte range (VolumeEcShardRead,
         volume_grpc_erasure_coding.go:270-328) — the degraded-read path's
-        peer fetch rides this stream."""
+        peer fetch rides this stream. A range of at most 64 KiB that lies
+        in the mapped file of a shard mounted here is sliced on the
+        loop's thread and is the whole answer, one message; anything
+        else is read on an executor thread, 1 MiB a message, with a
+        closing `is_last`."""
+        if 0 < request.size <= _INLINE_MAX:
+            data = self.store.ec_shard_slice(
+                request.volume_id, request.shard_id, request.offset,
+                request.size)
+            if data is not None:
+                self.vs.metrics.count("ec_shard_read_served",
+                                      labels=_INLINE)
+                yield pb.DataChunk(data=data, is_last=True)
+                return
+        self.vs.metrics.count("ec_shard_read_served", labels=_EXECUTOR)
         try:
             offset, remaining = request.offset, request.size
             while remaining > 0:

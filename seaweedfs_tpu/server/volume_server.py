@@ -39,10 +39,13 @@ import uuid
 from typing import Optional
 
 import aiohttp
+import grpc
 from aiohttp import web
 
 from .. import faults, observe, overload
 from ..lifecycle.heat import HeatTracker
+from ..pb import volume_server_pb2 as vpb
+from ..pb.rpc import VolumeServerStub, dial, grpc_address
 from ..storage.file_id import FileId
 from ..utils import compression, fast_multipart
 from ..utils import retry as _retry
@@ -63,6 +66,9 @@ log = logging.getLogger("volume")
 # a GET only counts
 _SERVED = {"result": "served"}
 _DECLINED = {"result": "declined"}
+# and of `ec_peer_call` (`_make_shard_reader`): a remote read only counts
+_CALL_REUSED = {"result": "reused"}
+_CALL_BUILT = {"result": "built"}
 
 
 def _resize_image(data: bytes, mime: str, width: int, height: int,
@@ -260,7 +266,9 @@ class VolumeServer:
         self._grpc_server = None
         self._replica_cache: dict[int, tuple[list[str], float]] = {}
         self._shard_loc_cache: dict[int, tuple[dict, float]] = {}
-        self._peer_grpc_channels: dict[str, object] = {}
+        # peer url -> (channel, its prepared VolumeEcShardRead call): the
+        # call lives and dies with the channel it was made from
+        self._peer_grpc_channels: dict[str, tuple] = {}
         self._peer_grpc_dead: dict[str, float] = {}
         self._repair_neg: dict[str, float] = {}
         self._repair_inflight = 0
@@ -321,6 +329,14 @@ class VolumeServer:
             self.metrics.count("ec_remote_shard_reads", 0,
                                labels={"via": via})
         self.metrics.count("ec_remote_shard_read_bytes", 0)
+        # whether a remote read found its peer's call prepared
+        for result in (_CALL_REUSED, _CALL_BUILT):
+            self.metrics.count("ec_peer_call", 0, labels=result)
+        # and, as the peer, where it read a shard range that was asked of
+        # it (server/volume_grpc.py counts them)
+        for how in ("inline", "executor"):
+            self.metrics.count("ec_shard_read_served", 0,
+                               labels={"how": how})
         for result in ("holder", "none"):
             self.metrics.count("ec_shard_location_lookups", 0,
                                labels={"result": result})
@@ -479,7 +495,7 @@ class VolumeServer:
             self._fast_srv.close()
             await self._fast_srv.wait_closed()
             self._fast_srv = None
-        for ch in self._peer_grpc_channels.values():
+        for ch, _ in self._peer_grpc_channels.values():
             try:
                 ch.close()
             except Exception:
@@ -1743,29 +1759,29 @@ class VolumeServer:
 
         def fetch_grpc(url: str, shard_id: int, offset: int,
                        size: int) -> Optional[bytes]:
-
-            import grpc as grpc_mod
-
-            from ..pb import volume_server_pb2 as vpb
-            from ..pb.rpc import VolumeServerStub, grpc_address
             # peers whose +10000 gRPC port is closed/filtered go HTTP-first
             # for a while instead of paying the deadline on every shard
             if time.time() < self._peer_grpc_dead.get(url, 0):
                 return None
+            # channels are thread-safe and reconnect internally: one per
+            # peer with the one call a read makes on it, prepared once
+            # and not once per fetch (setdefault so racing executor
+            # threads don't leak a loser channel)
+            entry = self._peer_grpc_channels.get(url)
+            result = _CALL_REUSED
+            if entry is None:
+                ch = dial(grpc_address(url))
+                new = (ch, VolumeServerStub(ch).VolumeEcShardRead)
+                entry = self._peer_grpc_channels.setdefault(url, new)
+                if entry is new:
+                    result = _CALL_BUILT
+                else:
+                    ch.close()
+            self.metrics.count("ec_peer_call", labels=result)
+            _, shard_read = entry
             try:
-                # channels are thread-safe and reconnect internally; one
-                # per peer, not one per fetch (setdefault so racing
-                # executor threads don't leak a loser channel)
-                ch = self._peer_grpc_channels.get(url)
-                if ch is None:
-                    from ..pb.rpc import dial
-                    new_ch = dial(grpc_address(url))
-                    ch = self._peer_grpc_channels.setdefault(url, new_ch)
-                    if ch is not new_ch:
-                        new_ch.close()
-                stub = VolumeServerStub(ch)
                 buf = bytearray()
-                for chunk in stub.VolumeEcShardRead(
+                for chunk in shard_read(
                         vpb.EcShardReadRequest(
                             volume_id=ev.vid, shard_id=shard_id,
                             offset=offset, size=size),
@@ -1776,9 +1792,9 @@ class VolumeServer:
                     if chunk.is_last:
                         break
                 return bytes(buf) if len(buf) == size else None
-            except grpc_mod.RpcError as e:
-                if e.code() in (grpc_mod.StatusCode.UNAVAILABLE,
-                                grpc_mod.StatusCode.DEADLINE_EXCEEDED):
+            except grpc.RpcError as e:
+                if e.code() in (grpc.StatusCode.UNAVAILABLE,
+                                grpc.StatusCode.DEADLINE_EXCEEDED):
                     self._peer_grpc_dead[url] = time.time() + 60.0
                 return None
 

@@ -714,6 +714,16 @@ class Store:
             raise KeyError(f"shard {vid}.{shard_id} not here")
         return shard.read_at(offset, size)
 
+    def ec_shard_slice(self, vid: int, shard_id: int, offset: int,
+                       size: int) -> Optional[bytes]:
+        """`ec_shard_read` for an event loop's thread (`EcShard.slice_at`:
+        never a system call), or None for "use `ec_shard_read`": no such
+        volume or shard here, no mapping, a range not wholly in the
+        file."""
+        ev = self.find_ec_volume(vid)
+        shard = ev.shards.get(shard_id) if ev is not None else None
+        return shard.slice_at(offset, size) if shard is not None else None
+
     def ec_rebuild(self, vid: int, collection: str = "") -> list[int]:
         loc = self._location_with_ec_files(vid, collection)
         prefix = f"{collection}_" if collection else ""
