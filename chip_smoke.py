@@ -202,13 +202,40 @@ class Cluster:
     def ec_status(self) -> dict:
         return http_json(f"http://{self.volume}/admin/ec/mesh_status")
 
-    def metric(self, name: str) -> float:
+    def metrics_text(self) -> str:
         with urllib.request.urlopen(f"http://{self.volume}/metrics",
                                     timeout=30) as r:
-            text = r.read().decode()
+            return r.read().decode()
+
+    def metric(self, name: str) -> float:
+        text = self.metrics_text()
         m = re.search(rf"^{re.escape(name)}(?:{{[^}}]*}})? ([0-9.e+-]+)$",
                       text, re.M)
         return float(m.group(1)) if m else 0.0
+
+    def cold_dispatches(self) -> int:
+        """Degraded-read dispatches that met a width not yet compiled
+        (`..._ec_reconstruct_dispatch_total{warm="no",width=..}`), over
+        every width."""
+        return int(sum(float(v) for v in re.findall(
+            r'^seaweedfs_tpu_ec_reconstruct_dispatch_total'
+            r'\{warm="no",[^}]*\} ([0-9.e+-]+)$', self.metrics_text(),
+            re.M)))
+
+    def wait_warm(self, limit_s: float = 120.0) -> list[dict]:
+        """What each coder says of the warm-up that the store's first
+        generate or mount began, once none is under way (a host coder
+        says nothing)."""
+        deadline = time.time() + limit_s
+        while True:
+            warm = [d["warm"] for d in self.ec_status()["coder"]["resolved"]
+                    if "warm" in d]
+            if all(w["state"] not in ("idle", "running") for w in warm):
+                return warm
+            if time.time() > deadline:
+                raise SystemExit(f"the warm-up of the degraded read's "
+                                 f"widths has not ended: {warm}")
+            time.sleep(0.1)
 
     def stop(self) -> None:
         for p in self.procs:
@@ -465,6 +492,14 @@ def main() -> None:
         gone = [s for s in lost if not os.path.exists(f"{vbase}.ec{s:02d}")]
         if gone != lost:
             raise SystemExit(f"shards {lost} not all removed: {gone}")
+        # ec.encode generated and mounted the shards, and with the first
+        # of those the store began compiling every width a degraded read
+        # can meet: once that has ended no read may meet a cold width
+        warm = cluster.wait_warm()
+        if require_tpu and [w["state"] for w in warm] != ["done"]:
+            raise SystemExit(f"the server's warm-up did not end well: "
+                             f"{warm}")
+        cold_before = cluster.cold_dispatches()
         counter = "seaweedfs_tpu_ec_reconstruct_intervals_total"
         seen = [cluster.metric(counter)]
         inline = "seaweedfs_tpu_volume_ec_read_inline_total"
@@ -515,6 +550,12 @@ def main() -> None:
         info["xprof"] = device_view(cluster, client, cold_sample,
                                     require_tpu)
         log(f"/debug/xprof while degraded GETs ran: {info['xprof']}")
+        cold = cluster.cold_dispatches() - cold_before
+        if cold:
+            raise SystemExit(f"{cold} degraded-read dispatches met a width "
+                             f"that was not compiled, after the warm-up "
+                             f"had ended: {warm}")
+        info["degraded"]["warm"] = warm
 
         deadline = time.time() + 60
         while True:  # ec.rebuild plans from the master's view of the loss

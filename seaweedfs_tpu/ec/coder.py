@@ -31,6 +31,11 @@ from ..ops import gf256, rs_jax
 # fn(survivors [k, n] uint8) -> rebuilt rows [len(missing), n] uint8
 ApplyFn = Callable[[np.ndarray], np.ndarray]
 
+# the widths at which the Pallas coder dispatches a degraded read's
+# interval (`ops/rs_pallas.host_widths()` at the served tile), for who
+# labels by them and must not import the kernel (a server's /metrics)
+DISPATCH_WIDTHS = tuple(16384 << i for i in range(7))
+
 
 class ErasureCoder:
     """Encode/reconstruct fixed-width stripes of k data + m parity shards."""
@@ -71,6 +76,14 @@ class ErasureCoder:
     def materialize(self, handle) -> np.ndarray:
         """Block until a handle from encode_async/rec_apply_async is real."""
         return np.asarray(handle)
+
+    def warm_widths(self) -> None:
+        """Called when a store generates or mounts EC shards of this
+        geometry (never at boot for a server that holds none): a backend
+        that compiles a program a width of a degraded read's interval
+        starts compiling them now, on a thread of its own, and says how
+        far it is in `describe()["warm"]`. Returns at once; a host
+        backend has nothing to compile."""
 
     def reconstruct(self, shards: Sequence[Optional[np.ndarray]],
                     data_only: bool = False,
@@ -216,10 +229,19 @@ class PallasCoder(ErasureCoder):
         return build(matrix, tile=self.tile, interpret=self.interpret,
                      vmem_limit_bytes=self.vmem_limit_bytes)
 
+    def _host_state(self):
+        """The degraded read's program, one row out (rs_pallas)."""
+        return self._mod.host_state(1, self.k, self.tile, self.interpret,
+                                    self.vmem_limit_bytes)
+
+    def warm_widths(self) -> None:
+        self._host_state().warm()
+
     def describe(self) -> dict:
         return {**super().describe(), "tile": self.tile,
                 "vmem_limit_bytes": self.vmem_limit_bytes,
-                "interpret": self.interpret, "device": _device_info()}
+                "interpret": self.interpret, "device": _device_info(),
+                "warm": self._host_state().status()}
 
     def encode(self, data: np.ndarray) -> np.ndarray:
         return self._encode_host(np.asarray(data, dtype=np.uint8))

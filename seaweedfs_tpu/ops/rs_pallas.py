@@ -23,6 +23,7 @@ from __future__ import annotations
 
 import contextlib
 import functools
+import threading
 
 import jax
 import jax.numpy as jnp
@@ -31,6 +32,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .. import observe
+from ..utils import glog
+from ..utils import metrics as metrics_mod
 from . import gf256
 from .rs_jax import bitplane_matrix
 
@@ -150,6 +153,102 @@ def gf_apply_pallas(matrix: np.ndarray, tile: int = TILE,
 _BUCKET_TILES = 64
 
 
+def host_widths(tile: int = TILE) -> tuple[int, ...]:
+    """The bucketed widths of a host call, smallest first: 1, 2, 4 ...
+    `_BUCKET_TILES` tiles (seven: 16 KiB ... 1 MiB at the served tile)."""
+    return tuple(tile << i for i in range(_BUCKET_TILES.bit_length()))
+
+
+def host_width(n: int, tile: int = TILE) -> int:
+    """The width at which a host call of `n` columns is dispatched."""
+    tiles = -(-n // tile)
+    if tiles < _BUCKET_TILES:
+        tiles = 1 << (tiles - 1).bit_length()
+    return tiles * tile
+
+
+class _HostWidths:
+    """What one host-call program (`_build_apply`'s arguments name it:
+    the matrix is an operand) has run at in this process, and the thread
+    that compiles its bucketed widths before a read needs them."""
+
+    def __init__(self, rows: int, cols: int, tile: int, interpret: bool,
+                 vmem_limit_bytes: int):
+        self.rows, self.cols, self.tile = rows, cols, tile
+        self.raw = _build_apply(rows, cols, tile, interpret,
+                                vmem_limit_bytes)
+        self.done: set[int] = set()  # widths a call has returned from
+        self.state = "idle"  # -> "running" -> "done" | "failed: ..."
+        self._lock = threading.Lock()
+        # a dispatch's labels, made once a width
+        self._labels: dict[tuple[int, bool], dict] = {}
+
+    def warm(self) -> None:
+        """Start the warm-up, once; returns at once."""
+        with self._lock:
+            if self.state != "idle":
+                return
+            self.state = "running"
+        threading.Thread(target=self._run, name="ec-warm-widths",
+                         daemon=True).start()
+
+    def _run(self) -> None:
+        # under no request: a context of its own, and `enclosing` keeps
+        # it out of any wide event (as `ec.generate` is)
+        try:
+            with observe.stage("ec.warm_widths", observe.capture(),
+                               enclosing=True):
+                # any matrix will do, it is an operand: put as `_bind`
+                # puts a real one, so a read finds the same program
+                w = jnp.asarray(np.zeros((8 * self.rows, 8 * self.cols),
+                                         dtype=np.int8))
+                for width in host_widths(self.tile):
+                    if width not in self.done:
+                        np.asarray(self.raw(w, np.zeros(
+                            (self.cols, width), dtype=np.uint8)))
+                        self.done.add(width)
+            self.state = "done"
+        except Exception as e:  # reads go on compiling what they meet
+            self.state = f"failed: {type(e).__name__}: {e}"
+            glog.error("warm-up of the degraded read's widths failed "
+                       "(%s)", self.state)
+
+    def status(self) -> dict:
+        return {"state": self.state, "widths": sorted(self.done)}
+
+    def count(self, n: int, width: int) -> None:
+        """One dispatch of an interval of `n` columns at `width`, on the
+        shared `ec` registry; `warm="no"`: no call at this width had
+        returned yet, so this one compiles or waits for the compile."""
+        warm = width in self.done
+        labels = self._labels.get((width, warm))
+        if labels is None:
+            # an interval of a large block can be wider than any bucket
+            # and of any width: one label for them all
+            bucket = width <= _BUCKET_TILES * self.tile
+            labels = self._labels[(width, warm)] = {
+                "width": str(width) if bucket else "wider",
+                "warm": "yes" if warm else "no"}
+        reg = metrics_mod.shared("ec")
+        reg.count("reconstruct_interval_bytes", value=n)
+        reg.count("reconstruct_padded_bytes", value=width)
+        reg.count("reconstruct_dispatch", labels=labels)
+
+
+_host_states: dict[tuple, _HostWidths] = {}
+
+
+def host_state(rows: int, cols: int, tile: int = TILE,
+               interpret: bool = False,
+               vmem_limit_bytes: int = VMEM_LIMIT_BYTES) -> _HostWidths:
+    """The one `_HostWidths` of a program in this process."""
+    key = (rows, cols, tile, interpret, vmem_limit_bytes)
+    state = _host_states.get(key)
+    if state is None:
+        state = _host_states.setdefault(key, _HostWidths(*key))
+    return state
+
+
 def gf_apply_pallas_host(matrix: np.ndarray, tile: int = TILE,
                          interpret: bool = False,
                          vmem_limit_bytes: int = VMEM_LIMIT_BYTES):
@@ -160,31 +259,35 @@ def gf_apply_pallas_host(matrix: np.ndarray, tile: int = TILE,
     and widths under 64 tiles round up to a power of two of tiles: reads
     of any size up to 1 MiB share seven executables instead of compiling
     one per size inside the GET (chip run of PR 21: ~1 s per degraded GET
-    before, each new interval length a fresh compile)."""
+    before, each new interval length a fresh compile). `host_state` of
+    the same arguments compiles the seven ahead of the reads."""
     kernel, cols = _bind(matrix, tile, interpret, vmem_limit_bytes)
+    state = host_state(len(matrix), cols, tile, interpret, vmem_limit_bytes)
 
     def apply_fn(data: np.ndarray, stage: str = "") -> np.ndarray:
         """`stage`: the prefix under which a caller that has one (a
         degraded read: "ec.get") wants the three steps of this call
         timed as observe stages (`<stage>.stack_pad`, `.dispatch`,
-        `.d2h_wait`); without one nothing is timed."""
+        `.d2h_wait`) and its dispatch counted; without one nothing is
+        timed or counted."""
         def timed(step: str):
             return (observe.stage(stage + step) if stage
                     else contextlib.nullcontext())
 
         n = data.shape[1]
-        tiles = -(-n // tile)
-        if tiles < _BUCKET_TILES:
-            tiles = 1 << (tiles - 1).bit_length()
-        if tiles * tile != n:
+        width = host_width(n, tile)
+        if width != n:
             with timed(".stack_pad"):
-                padded = np.zeros((cols, tiles * tile), dtype=np.uint8)
+                padded = np.zeros((cols, width), dtype=np.uint8)
                 padded[:, :n] = data
                 data = padded
         with timed(".dispatch"):
+            if stage:
+                state.count(n, width)
             out = kernel(data)  # H2D + launch; returns before the device
         with timed(".d2h_wait"):
             out = np.asarray(out)  # the kernel, D2H, delinearize
+        state.done.add(width)
         return out[:, :n]
 
     return apply_fn

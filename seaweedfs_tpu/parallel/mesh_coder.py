@@ -147,8 +147,20 @@ class MeshCoder(JaxCoder):
         metrics_mod.shared("ec").gauge("feed_mesh_devices", n)
 
     def describe(self) -> dict:
-        return {**super().describe(), "formulation": self.method,
-                "mesh_devices": self.mesh_devices}
+        out = {**super().describe(), "formulation": self.method,
+               "mesh_devices": self.mesh_devices}
+        if self.method == "pallas":
+            out["warm"] = self._host_state().status()
+        return out
+
+    def _host_state(self):
+        """The degraded read's program (`_rec_apply_sync`), one row out."""
+        from ..ops import rs_pallas
+        return rs_pallas.host_state(1, self.k, interpret=self._interpret)
+
+    def warm_widths(self) -> None:
+        if self.method == "pallas":
+            self._host_state().warm()
 
     # --- staging: per-chip sub-batches ---
 

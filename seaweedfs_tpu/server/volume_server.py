@@ -43,6 +43,7 @@ import grpc
 from aiohttp import web
 
 from .. import faults, observe, overload
+from ..ec.coder import DISPATCH_WIDTHS
 from ..lifecycle.heat import HeatTracker
 from ..pb import volume_server_pb2 as vpb
 from ..pb.rpc import VolumeServerStub, dial, grpc_address
@@ -350,6 +351,18 @@ class VolumeServer:
         # it, in the shared `ec` registry): born at 0 too
         for name in ("encode_input_bytes", "encode_batches"):
             metrics_mod.shared("ec").count(name, 0)
+        # and what a degraded read handed to the device: the intervals as
+        # asked, as padded, and each dispatch by its width and whether
+        # that width was compiled when the read met it
+        # (ops/rs_pallas.py counts them, same registry): born at 0 too
+        for name in ("reconstruct_interval_bytes",
+                     "reconstruct_padded_bytes"):
+            metrics_mod.shared("ec").count(name, 0)
+        for width in DISPATCH_WIDTHS:
+            for warm in ("yes", "no"):
+                metrics_mod.shared("ec").count(
+                    "reconstruct_dispatch", 0,
+                    labels={"width": str(width), "warm": warm})
         self.app = self._build_app()
         # the EC read path fetches missing shards from peers through this
         store._remote_shard_reader = self._make_shard_reader

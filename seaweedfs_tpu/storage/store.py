@@ -141,6 +141,8 @@ class Store:
         self._lock = threading.RLock()
         for loc in self.locations:
             loc.load_existing(self.coder, self._resolve_geometry)
+            for ev in loc.ec_volumes.values():
+                ev.coder.warm_widths()
 
     def check_free_space(self) -> bool:
         """Min-free-space watchdog (disk_location.go:304 + statfs,
@@ -509,7 +511,12 @@ class Store:
         with observe.stage("ec.seal"):
             v.read_only = True
             v.sync()
-        return v, v.base_file_name(), self.geometry_for(v.collection)
+        g = self.geometry_for(v.collection)
+        # a server that generates shards is about to mount them: the
+        # widths a degraded read can meet compile beside the encode, on
+        # the coder's own thread (`ec_mount` has the rest)
+        self.coder(g).warm_widths()
+        return v, v.base_file_name(), g
 
     def _ec_finish_generate(self, v, base: str,
                             g: ec_mod.Geometry) -> list[int]:
@@ -682,7 +689,12 @@ class Store:
                               coder=self.coder(g))
                 loc.ec_volumes[vid] = ev
             mounted = [sid for sid in shard_ids if ev.add_shard(sid)]
-            return mounted
+        # the widths a degraded read of this geometry can meet compile
+        # from the store's first generate or mount of it on, on the
+        # coder's own thread and not under the lock; every later call
+        # finds that begun
+        ev.coder.warm_widths()
+        return mounted
 
     def _location_with_ec_files(self, vid: int, collection: str):
         prefix = f"{collection}_" if collection else ""

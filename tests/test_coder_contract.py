@@ -67,7 +67,8 @@ def test_interface_is_what_the_product_calls():
     public = {n for n, v in vars(ErasureCoder).items()
               if callable(v) and not n.startswith("_")}
     assert public == {"encode", "encode_async", "rec_apply_async",
-                      "materialize", "reconstruct", "verify", "describe"}
+                      "materialize", "reconstruct", "verify", "describe",
+                      "warm_widths"}
     # no backend widens it either (MeshCoder adds its HLO inspection)
     from seaweedfs_tpu.parallel import MeshCoder
     for cls in (coder_mod.NumpyCoder, coder_mod.CppCoder,
