@@ -71,6 +71,33 @@ def _map_shared(f, size: int) -> Optional[mmap.mmap]:
         return None
 
 
+# The largest stored needle `read_needle_nowait` serves on the event
+# loop's thread. It guards one thing: how long one GET holds that thread.
+# Measured on the chip's host (`scripts/ec_loop_hold.py`, PR 35: one
+# connection, an idle server, 2,020 GETs a size; the median of three
+# runs, 1 KiB from one), ms a GET:
+#
+#   needle    held    of it the read    the same GET through the executor
+#   1 KiB     0.138       0.016                   0.342
+#   64 KiB    0.199       0.039                   0.404
+#   128 KiB   0.243       0.062                   0.454
+#   256 KiB   0.333       0.124  (0.123-0.124)    0.591
+#   512 KiB   0.522       0.249  (0.229-0.263)    0.795
+#   1 MiB     0.983       0.554                   1.245
+#
+# "held" is `ec.get.ecx` + `.shard_read` + `.parse` + `.resume` of a
+# served GET. The search and `respond` (etag, head, body into the
+# transport) run on the loop's thread whichever thread reads, and hold
+# it for 0.12 ms at 1 KiB, so the limit decides only where "the read"
+# runs (`.shard_read` + `.parse`: the slices, the join, the CRC).
+# It is the largest power of two at which the read stays under 0.15 ms,
+# a quarter of the 0.66 ms of `ec.get.queue` + `ec.get.resume` that the
+# hand-off cost a 64 KB GET under load (PERF.md §5-§6, PR 35). Haystack's
+# 65,536-byte photo is stored as 65,541; a filer's 1 MiB chunk keeps the
+# executor.
+NOWAIT_MAX_SIZE = 256 * 1024
+
+
 class _Unmapped(Exception):
     """An interval `read_needle_nowait` cannot slice: it declines."""
 
@@ -289,12 +316,13 @@ class EcVolume:
 
     def read_needle_nowait(self, needle_id: int,
                            cookie: Optional[int] = None,
-                           max_size: int = 64 * 1024
+                           max_size: int = NOWAIT_MAX_SIZE
                            ) -> tuple[Optional[Needle], Optional[Located]]:
         """`read_needle` for a caller on the event loop's thread, the twin
         of `Volume.read_needle_nowait`: (needle, None) when everything
         the read needs is in this process's address space (the index
-        mapped, a stored needle of at most `max_size`, every interval
+        mapped, a stored needle of at most `max_size`, which bounds how
+        long the GET holds the loop: `NOWAIT_MAX_SIZE`, every interval
         inside the mapped file of a shard mounted here), and then no
         system call, no lock waited for, nothing that gives the GIL away.
         Otherwise it declines, (None, located): `read_needle`, on a

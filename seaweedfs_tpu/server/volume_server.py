@@ -779,10 +779,12 @@ class VolumeServer:
 
         The loop's thread tries the read first
         (`EcVolume.read_needle_nowait`: every interval in a mapped shard
-        file here, so microseconds and no system call, where the hand-off
-        to an executor thread and back costs two GIL convoys) and
+        file here, so no system call and a hold of the loop that
+        `ec_volume.NOWAIT_MAX_SIZE` bounds, where the hand-off to an
+        executor thread and back costs two GIL convoys) and
         `ec_read_nowait{result=}` says how it went. A read that declined
-        (a lost or remote shard, a big needle, nothing mapped) runs in
+        (a lost or remote shard, a needle over that limit, nothing
+        mapped) runs in
         the executor with what the loop located, under a copy of the
         request's context: its stages are children of the open
         `ec.get.handler` and reach the request's wide event. Those

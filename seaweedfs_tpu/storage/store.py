@@ -484,8 +484,9 @@ class Store:
 
     def read_ec_needle_nowait(self, vid: int, needle_id: int,
                               cookie: Optional[int] = None):
-        """`EcVolume.read_needle_nowait` for an event loop's thread:
-        (needle, None), or (None, located) for "use `read_needle`"."""
+        """`EcVolume.read_needle_nowait` for an event loop's thread, at
+        its own limit (`ec_volume.NOWAIT_MAX_SIZE`): (needle, None), or
+        (None, located) for "use `read_needle`"."""
         ev = self.find_ec_volume(vid)
         if ev is None:
             return None, None

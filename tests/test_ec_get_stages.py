@@ -21,6 +21,7 @@ import numpy as np
 import pytest
 
 from seaweedfs_tpu import observe
+from seaweedfs_tpu.ec import ec_volume as ec_volume_mod
 from seaweedfs_tpu.ec.coder import PallasCoder
 from seaweedfs_tpu.ec.geometry import Geometry
 from seaweedfs_tpu.observe import profiler, wideevents
@@ -54,6 +55,25 @@ def payload(i: int) -> bytes:
     return bytes([i % 251]) * 1000
 
 
+# volume 3: no shard lost, and needles that meet the loop's limit. A
+# 65,536-byte photo first, then needles over `NOWAIT_MAX_SIZE`, enough
+# of them for one row of large blocks, so that the photo lies on two
+# shards and not on all ten
+BIG_VID, PHOTO, OVER_LIMIT = 3, 1, 2
+BIG_SIZES = {PHOTO: 65536,
+             **{OVER_LIMIT + j: ec_volume_mod.NOWAIT_MAX_SIZE
+                for j in range(10 * GEOMETRY.large_block_size
+                               // ec_volume_mod.NOWAIT_MAX_SIZE + 1)}}
+
+
+BIG_NEEDLES = {"photo": PHOTO, "over-limit": OVER_LIMIT}
+
+
+def big_payload(i: int) -> bytes:
+    return (bytes((i + j) % 256 for j in range(253))
+            * (BIG_SIZES[i] // 253 + 1))[:BIG_SIZES[i]]
+
+
 def free_port() -> int:
     with socket.socket() as s:
         s.bind(("127.0.0.1", 0))
@@ -63,6 +83,15 @@ def free_port() -> int:
 class Served:
     def __init__(self, tmpdir: str):
         self.store = Store([tmpdir], coder_name="numpy", geometry=GEOMETRY)
+        self.store.add_volume(BIG_VID)
+        for i in BIG_SIZES:
+            self.store.write_needle(BIG_VID, Needle(
+                id=i, cookie=COOKIE, data=big_payload(i)))
+        self.store.ec_generate(BIG_VID)  # (0.85 MB: by the host coder)
+        self.store.ec_mount(BIG_VID, "", list(range(14)))
+        self.store.delete_volume(BIG_VID)
+        # the layout marker's one read, which is not the loop's
+        assert len(self.store.find_ec_volume(BIG_VID).locate(PHOTO)[2]) == 2
         self.store._coders[(10, 4)] = PallasCoder(10, 4, interpret=True)
         self.store.add_volume(1)
         self.store.add_volume(2)
@@ -314,14 +343,21 @@ def test_present_interval_is_read_not_reconstructed(served):
     # a Range is the aiohttp plane's, which makes the same read
     ("present", {"Range": "bytes=10-19"}, "aiohttp"),
     ("lost", {"Range": "bytes=10-19"}, "aiohttp"),
+    # a 64 KB needle, over two present shards, is the loop's too; one
+    # over `NOWAIT_MAX_SIZE`, as present, is not
+    ("photo", {}, "fast"), ("over-limit", {}, "fast"),
+    ("photo", {"Range": "bytes=10-19"}, "aiohttp"),
+    ("over-limit", {"Range": "bytes=10-19"}, "aiohttp"),
 ])
 def test_loop_reads_what_is_mapped_here_and_hands_on_the_rest(
         served, monkeypatch, needle, headers, plane):
-    """A GET whose interval is in a mapped shard file is answered on the
-    loop's thread: no executor submit, no `ec.get.queue`. One that meets
-    the lost shard declines and is handed on with what the loop located:
-    one search of the index, not two. Either way the `read` histogram
-    and the heat tracker take it once."""
+    """A GET whose intervals are in mapped shard files, of a needle that
+    holds the loop no longer than `NOWAIT_MAX_SIZE` allows, is answered
+    on the loop's thread: no executor submit, no `ec.get.queue`. One
+    that meets the lost shard, or is over the limit, declines and is
+    handed on with what the loop located: one search of the index, not
+    two. Either way the `read` histogram and the heat tracker take it
+    once."""
     from seaweedfs_tpu.lifecycle.heat import HeatTracker
     submits, heat = [], []
     real_submit = served.loop.run_in_executor
@@ -332,24 +368,30 @@ def test_loop_reads_what_is_mapped_here_and_hands_on_the_rest(
     monkeypatch.setattr(
         HeatTracker, "record_read",
         lambda self, vid: heat.append(vid) or real_heat(self, vid))
-    needle_id = getattr(served, needle)
+    if needle in BIG_NEEDLES:
+        vid, needle_id = BIG_VID, BIG_NEEDLES[needle]
+        want = big_payload(needle_id)
+    else:
+        vid, needle_id = 1, getattr(served, needle)
+        want = payload(needle_id - 1)
     trace = f"nowait-{needle}-{plane}"
     before = served.read_counts()
     del submits[:]  # (the /metrics read is none of this GET's)
-    body = served.get(served.fid(needle_id), trace=trace, headers=headers)
+    body = served.get(served.fid(needle_id, vid), trace=trace,
+                      headers=headers)
     n_submits = len(submits)
-    assert body == (payload(needle_id - 1)[10:20] if headers
-                    else payload(needle_id - 1))
+    assert body == (want[10:20] if headers else want)
     got = names(trace_of(trace))
     after = served.read_counts()
     rose = {k: after[k] - before[k] for k in after}
-    on_loop = needle == "present"
+    on_loop = needle in ("present", "photo")
     assert rose == {"served": int(on_loop), "declined": int(not on_loop),
                     "timed": 1, "lookups": 1}
     assert n_submits == got["ec.get.queue"] == int(not on_loop)
     assert got["ec.get.ecx"] == got["ec.get.resume"] == 1
-    assert heat == [1]
-    assert bool(got["GET /" + served.fid(needle_id)]) == (plane == "aiohttp")
+    assert heat == [vid]
+    assert bool(got["GET /" + served.fid(needle_id, vid)]) \
+        == (plane == "aiohttp")
 
 
 def test_an_error_of_the_search_is_the_loops_answer(served):

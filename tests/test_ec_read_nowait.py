@@ -4,9 +4,10 @@ make itself, the twin of `Volume.read_needle_nowait`.
 It serves a needle only when everything the read needs is in this
 process's address space (the index mapped, every interval inside the
 mapped file of a shard mounted here, a stored needle of at most
-`max_size`), byte for byte as `read_needle` does and raising what it
-raises; otherwise it declines, having called nothing that can block, and
-hands on what it located so that `read_needle` searches the index once.
+`max_size`, by default `NOWAIT_MAX_SIZE`), byte for byte as `read_needle`
+does and raising what it raises; otherwise it declines, having called
+nothing that can block, and hands on what it located so that
+`read_needle` searches the index once.
 """
 
 import os
@@ -49,18 +50,19 @@ def built(tmp_path_factory) -> str:
     return directory
 
 
-def _open(directory: str, warm: bool = True) -> ec.EcVolume:
-    ev = ec.EcVolume(directory, "", 1, GEO,
+def _open(directory: str, warm: bool = True, geo: ec.Geometry = GEO,
+          first: int = IDS[0]) -> ec.EcVolume:
+    ev = ec.EcVolume(directory, "", 1, geo,
                      coder=ec.get_coder("numpy", 10, 4))
-    for sid in range(GEO.total_shards):
+    for sid in range(geo.total_shards):
         ev.add_shard(sid)
     if warm:
-        ev.locate(IDS[0])  # the layout marker's one read: not the loop's
+        ev.locate(first)  # the layout marker's one read: not the loop's
     return ev
 
 
 def _shards_of(ev: ec.EcVolume, needle_id: int) -> list[int]:
-    return [iv.to_shard_id_and_offset(GEO)[0]
+    return [iv.to_shard_id_and_offset(ev.g)[0]
             for iv in ev.locate(needle_id)[2]]
 
 
@@ -188,9 +190,88 @@ def test_declines_a_needle_over_max_size(built, monkeypatch):
         got, located = ev.read_needle_nowait(BIG, cookie=COOKIE + BIG,
                                              max_size=2048)
         assert got is None and located[1] > 2048
-        # the plain volume's own limit, 64 KB, admits it
+        # the default, `NOWAIT_MAX_SIZE`, admits it
         got, located = ev.read_needle_nowait(BIG, cookie=COOKIE + BIG)
         assert got.data == b"b" * 3000 and located is None
+    finally:
+        ev.close()
+
+
+# --- the default limit, at sizes that meet it ---
+LIMIT = ec_volume_mod.NOWAIT_MAX_SIZE
+# blocks a photo crosses now and then and a needle at the limit always
+PHOTO_GEO = ec.Geometry(data_shards=10, parity_shards=4,
+                        large_block_size=16 << 20,
+                        small_block_size=128 << 10)
+PHOTO = 65536  # Haystack's needle (PERF.md: `haystack-photo-rs10-4`)
+STORED_EXTRA = 5  # data size (4) and flags (1) beside the data
+AT_LIMIT, OVER_LIMIT, FIRST_PHOTO, N_PHOTOS = 1, 2, 10, 12
+STORED = {AT_LIMIT: LIMIT, OVER_LIMIT: LIMIT + 1,
+          **{FIRST_PHOTO + j: PHOTO + STORED_EXTRA for j in range(N_PHOTOS)}}
+
+
+def _photo_payload(i: int) -> bytes:
+    size = STORED[i] - STORED_EXTRA
+    return (bytes((i * 7 + j) % 256 for j in range(251))
+            * (size // 251 + 1))[:size]
+
+
+@pytest.fixture(scope="module")
+def photos(tmp_path_factory) -> str:
+    directory = str(tmp_path_factory.mktemp("nowait-photos"))
+    v = Volume(directory, "", 1, create=True)
+    for i in STORED:
+        v.write_needle(Needle(cookie=COOKIE + i, id=i,
+                              data=_photo_payload(i)))
+    base = v.base_file_name()
+    v.close()
+    ec.write_ec_files(base, ec.get_coder("numpy", 10, 4), PHOTO_GEO)
+    ec.write_sorted_ecx_from_idx(base)
+    return directory
+
+
+def _photo_in(ev: ec.EcVolume, n_parts: int) -> int:
+    return next(i for i in range(FIRST_PHOTO, FIRST_PHOTO + N_PHOTOS)
+                if len(set(_shards_of(ev, i))) == n_parts)
+
+
+@pytest.mark.parametrize("case,served", [
+    ("at-the-limit", True), ("one-byte-over", False),
+    ("photo", True), ("photo-across-a-block", True),
+    ("photo-across-a-block-one-part-lost", False)])
+def test_default_limit_by_stored_size(photos, monkeypatch, case, served):
+    """What `NOWAIT_MAX_SIZE` admits is decided by the stored size the
+    index gives and by where the intervals lie, nothing else: a needle
+    stored at exactly the limit, Haystack's 65,536-byte photo (over the
+    64 KiB the limit once was) in one block and across two; one byte
+    over, or with a part on a lost shard, declines with what it located
+    handed on."""
+    assert 64 * 1024 < PHOTO + STORED_EXTRA <= LIMIT
+    ev = _open(photos, geo=PHOTO_GEO, first=AT_LIMIT)
+    try:
+        i = {"at-the-limit": AT_LIMIT, "one-byte-over": OVER_LIMIT}.get(
+            case) or _photo_in(ev, 1 if case == "photo" else 2)
+        assert ev.locate(i)[1] == STORED[i]
+        want = ev.read_needle(i, cookie=COOKIE + i)
+        assert want.data == _photo_payload(i)
+        if case.endswith("one-part-lost"):
+            ev.delete_shard(_shards_of(ev, i)[1])
+        before = _lookups()
+        _no_pread(monkeypatch)
+        got, located = ev.read_needle_nowait(i, cookie=COOKIE + i)
+        assert _lookups() == before + 1
+        monkeypatch.undo()
+        if served:
+            assert located is None
+            assert got.to_bytes(ev.version) == want.to_bytes(ev.version)
+            return
+        assert got is None and located == ev.locate(i)
+        # `read_needle` takes it from there without a second search
+        before = _lookups()
+        n = ev.read_needle(i, cookie=COOKIE + i, located=located,
+                           shard_reader=lambda *_: None)
+        assert n.to_bytes(ev.version) == want.to_bytes(ev.version)
+        assert _lookups() == before
     finally:
         ev.close()
 
