@@ -555,6 +555,15 @@ def stream_rebuild(base_file_name: str, coder: ErasureCoder,
     fan = _FanOut([base_file_name + to_ext(i) for i in missing],
                   op.write_depth)
     tctx = observe.ensure_ctx("ec")
+    counters = metrics_mod.shared("ec")
+
+    def dispatch(survivors: np.ndarray):
+        # inside `ec.dispatch`, as the encode's count: what went to the
+        # coder, k rows of the batch's columns
+        handle = fn(survivors)
+        counters.count("rebuild_input_bytes", survivors.nbytes)
+        counters.count("rebuild_batches")
+        return handle
 
     def consume(survivors: np.ndarray, handle) -> None:
         with observe.stage("ec.kernel", tctx):
@@ -568,7 +577,7 @@ def stream_rebuild(base_file_name: str, coder: ErasureCoder,
 
     try:
         _run_pipeline(
-            _traced_batches(src.batches(op.batch_size), tctx), fn,
+            _traced_batches(src.batches(op.batch_size), tctx), dispatch,
             consume, op.depth, trace_ctx=tctx, recycle=src.recycle)
     finally:
         with observe.stage("ec.fsync", tctx):

@@ -48,7 +48,7 @@ from ..lifecycle.heat import HeatTracker
 from ..pb import volume_server_pb2 as vpb
 from ..pb.rpc import VolumeServerStub, dial, grpc_address
 from ..storage.file_id import FileId
-from ..utils import compression, fast_multipart
+from ..utils import compression, durable, fast_multipart
 from ..utils import retry as _retry
 from ..storage.needle import (FLAG_IS_COMPRESSED,
                               FLAG_HAS_LAST_MODIFIED, FLAG_HAS_MIME,
@@ -70,6 +70,39 @@ _DECLINED = {"result": "declined"}
 # and of `ec_peer_call` (`_make_shard_reader`): a remote read only counts
 _CALL_REUSED = {"result": "reused"}
 _CALL_BUILT = {"result": "built"}
+
+# what `_pull_file` gathers on the loop before one write off it
+_PULL_WRITE_BYTES = 1 << 20
+
+
+async def _pull_file(content: aiohttp.StreamReader, path: str) -> int:
+    """A pulled file's body into `path`; returns its bytes. It is written
+    beside the path on executor threads (the loop only gathers what the
+    socket gave), fsynced, and swapped in whole: a mapping of the file it
+    replaces keeps reading that file's bytes, where a truncation in place
+    is a SIGBUS at the next access of a mounted shard or index."""
+    loop = asyncio.get_event_loop()
+    tmp = path + ".copying"
+    f = await loop.run_in_executor(None, open, tmp, "wb")
+    pulled = 0
+    try:
+        buf = bytearray()
+        async for chunk in content.iter_chunked(_PULL_WRITE_BYTES):
+            buf += chunk
+            if len(buf) >= _PULL_WRITE_BYTES:
+                await loop.run_in_executor(None, f.write, buf)
+                pulled += len(buf)
+                buf = bytearray()
+        if buf:
+            await loop.run_in_executor(None, f.write, buf)
+            pulled += len(buf)
+        await loop.run_in_executor(None, f.close)
+        await loop.run_in_executor(None, durable.replace_atomic, tmp, path)
+    finally:
+        f.close()
+        if os.path.exists(tmp):
+            os.remove(tmp)
+    return pulled
 
 
 def _resize_image(data: bytes, mime: str, width: int, height: int,
@@ -354,6 +387,14 @@ class VolumeServer:
         # it, in the shared `ec` registry): born at 0 too
         for name in ("encode_input_bytes", "encode_batches"):
             metrics_mod.shared("ec").count(name, 0)
+        # what a rebuild handed to the coder (ec/pipeline.py) and what
+        # `ec/copy` pulled from peers, with the stages that time both:
+        # born at 0 too
+        for name in ("rebuild_input_bytes", "rebuild_batches",
+                     "copy_bytes"):
+            metrics_mod.shared("ec").count(name, 0)
+        metrics_mod.shared("ec").zero_seconds("stage", "stage",
+                                              ("ec.rebuild", "ec.copy"))
         # and what a degraded read handed to the device: the intervals as
         # asked, as padded, and each dispatch by its width and whether
         # that width was compiled when the read met it
@@ -1667,6 +1708,10 @@ class VolumeServer:
             if copy_ecx and self.store.find_ec_volume(vid) is None:
                 exts += [".ecx", ".ecj", ".ecm"]
             for ext in exts:
+                # `ec.copy`: one a file pulled, request to swap-in; the
+                # record form, since the loop serves other requests at
+                # every await in between
+                start_us, t0 = int(time.time() * 1e6), time.perf_counter()
                 async with self._session.get(
                         f"http://{source}/admin/file_copy",
                         params={"volume_id": str(vid),
@@ -1678,9 +1723,12 @@ class VolumeServer:
                         return web.json_response(
                             {"error": f"copy {ext} from {source}: "
                              f"{r.status}"}, status=502)
-                    with open(base + ext, "wb") as f:
-                        async for chunk in r.content.iter_chunked(1 << 20):
-                            f.write(chunk)
+                    pulled = await _pull_file(r.content, base + ext)
+                metrics_mod.shared("ec").count("copy_bytes", pulled)
+                observe.record_span(
+                    "ec.copy", None, start_us,
+                    int((time.perf_counter() - t0) * 1e6),
+                    tags={"ext": ext, "bytes": pulled})
         except aiohttp.ClientError as e:
             return web.json_response({"error": str(e)}, status=502)
         return web.json_response({"ok": True})

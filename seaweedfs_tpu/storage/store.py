@@ -745,14 +745,20 @@ class Store:
         # a RS(20,4) archive volume under a since-changed default would
         # reconstruct garbage
         g = self._resolve_geometry(base, collection)
-        rebuilt = ec_pipeline.stream_rebuild(base, self.coder(g), g)
-        ev = self.find_ec_volume(vid)
-        ec_mod.rebuild_ecx_file(
-            base, offset_size=(ev.offset_size if ev is not None
-                               else t.OFFSET_SIZE))
-        # merge-only stamp: freshly reconstructed shards get their digest
-        # recorded; already-stamped ids keep the encode-time value
-        ec_pipeline.stamp_shard_digests(base, g)
+        # `ec.rebuild` encloses a rebuild from the survivors found to the
+        # digests stamped, as `ec.generate` does a warm-down: its seconds
+        # are the time one was in flight, its count the rebuilds
+        with observe.stage("ec.rebuild", observe.capture(),
+                           enclosing=True):
+            rebuilt = ec_pipeline.stream_rebuild(base, self.coder(g), g)
+            ev = self.find_ec_volume(vid)
+            ec_mod.rebuild_ecx_file(
+                base, offset_size=(ev.offset_size if ev is not None
+                                   else t.OFFSET_SIZE))
+            # merge-only stamp: freshly reconstructed shards get their
+            # digest recorded; already-stamped ids keep the encode-time
+            # value
+            ec_pipeline.stamp_shard_digests(base, g)
         return rebuilt
 
     def ec_blob_delete(self, vid: int, needle_id: int) -> None:

@@ -140,6 +140,14 @@ class Registry:
                     rec[0] += 1
                     rec[1] += entry[1]
 
+    def zero_seconds(self, name: str, label: str, values) -> None:
+        """Series of an `add_seconds` family on the page at a count and a
+        sum of 0 before their first entry: a 0 and not an absence."""
+        with self._lock:
+            totals = self._seconds.setdefault(name, (label, {}))[1]
+            for value in values:
+                totals.setdefault(value, [0, 0.0])
+
     def count_from(self, name: str, read) -> None:
         """A counter that counts by itself, a clock such as CPU time:
         `read()` is its value, taken when the registry renders, so that
