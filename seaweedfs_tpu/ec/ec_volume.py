@@ -349,22 +349,34 @@ class EcVolume:
     def _read(self, needle_id: int, cookie: Optional[int],
               located: Located,
               read_interval: Callable[[Interval], bytes]) -> Needle:
-        parts = [read_interval(iv) for iv in located[2]]
+        return self.needle_from_parts(
+            needle_id, cookie, [read_interval(iv) for iv in located[2]])
+
+    def needle_from_parts(self, needle_id: int, cookie: Optional[int],
+                          parts: list[bytes]) -> Needle:
+        """The needle out of the bytes of its intervals, in order: the
+        join, the CRC and the cookie (`ec.get.parse`). Raises what
+        `read_needle` raises for them."""
         with observe.stage("ec.get.parse"):
             n = Needle.from_bytes(b"".join(parts), self.version)
             if cookie is not None and n.cookie != cookie:
                 raise KeyError(f"needle {needle_id:x} cookie mismatch")
         return n
 
-    def _slice_interval(self, iv: Interval) -> bytes:
-        """A present interval out of its shard's mapping; `_Unmapped`
-        where the shard is not mounted here or `slice_at` has nothing."""
+    def slice_interval(self, iv: Interval) -> Optional[bytes]:
+        """A present interval out of its shard's mapping, never a system
+        call; None where the shard is not mounted here or `slice_at` has
+        nothing."""
         shard_id, offset = iv.to_shard_id_and_offset(self.g)
         shard = self.shards.get(shard_id)
         if shard is None:
-            raise _Unmapped
+            return None
         with observe.stage("ec.get.shard_read"):
-            data = shard.slice_at(offset, iv.size)
+            return shard.slice_at(offset, iv.size)
+
+    def _slice_interval(self, iv: Interval) -> bytes:
+        """`slice_interval`, or `_Unmapped`."""
+        data = self.slice_interval(iv)
         if data is None:
             raise _Unmapped
         return data

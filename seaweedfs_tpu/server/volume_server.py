@@ -44,9 +44,10 @@ from aiohttp import web
 
 from .. import faults, observe, overload
 from ..ec.coder import DISPATCH_WIDTHS
+from ..ec.ec_volume import NOWAIT_MAX_SIZE
 from ..lifecycle.heat import HeatTracker
 from ..pb import volume_server_pb2 as vpb
-from ..pb.rpc import VolumeServerStub, dial, grpc_address
+from ..pb.rpc import VolumeServerStub, aio_dial, dial, grpc_address
 from ..storage.file_id import FileId
 from ..utils import compression, durable, fast_multipart
 from ..utils import retry as _retry
@@ -67,9 +68,13 @@ log = logging.getLogger("volume")
 # a GET only counts
 _SERVED = {"result": "served"}
 _DECLINED = {"result": "declined"}
+# and of `ec_read_declined`: where a declined read went on
+_AWAITED = {"then": "awaited"}
+_HANDED_ON = {"then": "executor"}
 # and of `ec_peer_call` (`_make_shard_reader`): a remote read only counts
 _CALL_REUSED = {"result": "reused"}
 _CALL_BUILT = {"result": "built"}
+_VIA_GRPC_AIO = {"via": "grpc_aio"}
 
 # what `_pull_file` gathers on the loop before one write off it
 _PULL_WRITE_BYTES = 1 << 20
@@ -303,6 +308,9 @@ class VolumeServer:
         # peer url -> (channel, its prepared VolumeEcShardRead call): the
         # call lives and dies with the channel it was made from
         self._peer_grpc_channels: dict[str, tuple] = {}
+        # and the loop's own (grpc.aio channel, call) a peer, for the
+        # reads it awaits (`_read_ec_awaited`): made and closed on the loop
+        self._peer_aio_channels: dict[str, tuple] = {}
         self._peer_grpc_dead: dict[str, float] = {}
         self._repair_neg: dict[str, float] = {}
         self._repair_inflight = 0
@@ -360,9 +368,14 @@ class VolumeServer:
         # (`read_ec_needle`): born at 0 likewise
         for result in (_SERVED, _DECLINED):
             self.metrics.count("ec_read_nowait", 0, labels=result)
-        # what an EC read took from peers (`_make_shard_reader`), and how
-        # often it asked the master where a shard is: born at 0 likewise
-        for via in ("grpc", "http"):
+        # and whether the loop then awaited a declined read's peer
+        # intervals itself or handed the read on
+        for then in (_AWAITED, _HANDED_ON):
+            self.metrics.count("ec_read_declined", 0, labels=then)
+        # what an EC read took from peers (`_make_shard_reader`, and the
+        # loop's awaited reads), and how often it asked the master where
+        # a shard is: born at 0 likewise
+        for via in ("grpc", "grpc_aio", "http"):
             self.metrics.count("ec_remote_shard_reads", 0,
                                labels={"via": via})
         self.metrics.count("ec_remote_shard_read_bytes", 0)
@@ -560,6 +573,12 @@ class VolumeServer:
             except Exception:
                 pass
         self._peer_grpc_channels.clear()
+        for ch, _ in self._peer_aio_channels.values():
+            try:
+                await ch.close()
+            except Exception:
+                pass
+        self._peer_aio_channels.clear()
         if self._grpc_server is not None:
             await self._grpc_server.stop(grace=0.5)
         if self._hb_task:
@@ -829,13 +848,17 @@ class VolumeServer:
         `ec_volume.NOWAIT_MAX_SIZE` bounds, where the hand-off to an
         executor thread and back costs two GIL convoys) and
         `ec_read_nowait{result=}` says how it went. A read that declined
-        (a lost or remote shard, a needle over that limit, nothing
-        mapped) runs in
+        only for an interval a live peer holds is still the loop's: it
+        awaits the peer's bytes (`_read_ec_awaited`), with no thread.
+        `ec_read_declined{then=}` says which way a declined read went.
+        The rest (a lost shard, a needle over that limit, nothing
+        mapped, a location the cache does not know, a peer that failed)
+        runs in
         the executor with what the loop located, under a copy of the
         request's context: its stages are children of the open
         `ec.get.handler` and reach the request's wide event. Those
         hand-offs are stages: `ec.get.queue` from the submit to the
-        worker's first line (a declined read alone), `ec.get.resume`
+        worker's first line (a read handed on alone), `ec.get.resume`
         from the read's last line, over the wait for the loop if there
         was one, to the response in hand."""
         self.metrics.count("read")
@@ -850,8 +873,10 @@ class VolumeServer:
             finally:
                 self.metrics.count("ec_read_nowait",
                                    labels=_SERVED if served else _DECLINED)
-            done_s, t_done = time.time(), time.perf_counter()
             if not served:
+                n = await self._read_ec_awaited(fid, located)
+            done_s, t_done = time.time(), time.perf_counter()
+            if n is None:
                 submit_us, t_submit = int(done_s * 1e6), t_done
 
                 def work():
@@ -871,6 +896,116 @@ class VolumeServer:
         observe.record_span("ec.get.resume", None, int(done_s * 1e6),
                             int((time.perf_counter() - t_done) * 1e6))
         return resp
+
+    async def _read_ec_awaited(self, fid: FileId, located):
+        """A declined read answered by the loop's thread all the same, or
+        None for "hand it on": a stored needle of at most
+        `NOWAIT_MAX_SIZE` whose every interval is a slice of a shard
+        mapped here or lies on a shard that the location cache alone (no
+        master call) says a peer not marked dead holds. Each such
+        interval is awaited from that peer on the loop's grpc.aio call
+        (`ec.get.peer_fetch` and `ec.get.remote_read`, record form: no
+        profiler annotation stays open across an await). A stale or
+        empty cache, a shard nobody holds, a failed or short answer:
+        None, and the executor's read does all it did before."""
+        ev = self.store.find_ec_volume(fid.volume_id)
+        parts = None
+        if ev is not None and located is not None \
+                and located[1] <= NOWAIT_MAX_SIZE:
+            parts = await self._awaited_parts(ev, located[2])
+        self.metrics.count("ec_read_declined",
+                           labels=_HANDED_ON if parts is None else _AWAITED)
+        if parts is None:
+            return None
+        return ev.needle_from_parts(fid.key, fid.cookie, parts)
+
+    async def _awaited_parts(self, ev, intervals) -> Optional[list[bytes]]:
+        """The bytes of `intervals`, in order, for `_read_ec_awaited`;
+        None where one of them is not the loop's to read. Every interval
+        is placed, and every local one sliced, before a peer is asked:
+        only a peer's failure leaves a read handed on after a call."""
+        parts: list = []
+        for iv in intervals:
+            shard_id, offset = iv.to_shard_id_and_offset(ev.g)
+            if shard_id in ev.shards:
+                parts.append(iv)
+                continue
+            url = self._live_holder(ev.vid, shard_id)
+            if url is None:
+                return None
+            parts.append((url, shard_id, offset, iv.size))
+        parts = [p if isinstance(p, tuple) else ev.slice_interval(p)
+                 for p in parts]
+        if None in parts:
+            return None
+        for i, part in enumerate(parts):
+            if isinstance(part, tuple):
+                start_s, t0 = time.time(), time.perf_counter()
+                parts[i] = await self._fetch_awaited(ev.vid, *part)
+                observe.record_span(
+                    "ec.get.peer_fetch", None, int(start_s * 1e6),
+                    int((time.perf_counter() - t0) * 1e6))
+                if parts[i] is None:
+                    return None
+        return parts
+
+    def _live_holder(self, vid: int, shard_id: int) -> Optional[str]:
+        """The first peer that the location cache alone says holds the
+        shard and that is not marked dead; None where the cache is not
+        fresh, names nobody or only dead peers."""
+        now = time.time()
+        for url in self._cached_holders(vid, shard_id) or ():
+            if now >= self._peer_grpc_dead.get(url, 0):
+                return url
+        return None
+
+    async def _fetch_awaited(self, vid: int, url: str, shard_id: int,
+                             offset: int, size: int) -> Optional[bytes]:
+        """One interval from one peer on the loop's grpc.aio call to it,
+        the twin of `_make_shard_reader`'s sync `fetch_grpc`: the same
+        request, timeout and trace metadata; exactly `size` bytes or
+        None, and the peer marked dead for 60 s on UNAVAILABLE or
+        DEADLINE_EXCEEDED. The loop is one thread, so the first read of
+        a peer builds its one channel and call without a race."""
+        entry = self._peer_aio_channels.get(url)
+        result = _CALL_REUSED
+        if entry is None:
+            ch = aio_dial(grpc_address(url))
+            entry = (ch, VolumeServerStub(ch).VolumeEcShardRead)
+            self._peer_aio_channels[url] = entry
+            result = _CALL_BUILT
+        self.metrics.count("ec_peer_call", labels=result)
+        start_s, t0 = time.time(), time.perf_counter()
+        data = None
+        try:
+            call = entry[1](vpb.EcShardReadRequest(
+                volume_id=vid, shard_id=shard_id, offset=offset, size=size),
+                timeout=5)
+            buf = bytearray()
+            while True:
+                chunk = await call.read()
+                if chunk is grpc.aio.EOF:
+                    break
+                if chunk.error:
+                    buf = None
+                    break
+                buf += chunk.data
+                if chunk.is_last:
+                    break
+            if buf is not None and len(buf) == size:
+                data = bytes(buf)
+        except grpc.RpcError as e:
+            if e.code() in (grpc.StatusCode.UNAVAILABLE,
+                            grpc.StatusCode.DEADLINE_EXCEEDED):
+                self._peer_grpc_dead[url] = time.time() + 60.0
+        except grpc.aio.BaseError as e:  # a channel closed under the read
+            log.debug("awaited shard read from %s failed: %s", url, e)
+        observe.record_span("ec.get.remote_read", None, int(start_s * 1e6),
+                            int((time.perf_counter() - t0) * 1e6))
+        if data is not None:
+            self.metrics.count("ec_remote_shard_reads", labels=_VIA_GRPC_AIO)
+            self.metrics.count("ec_remote_shard_read_bytes", value=size)
+        return data
 
     async def _read_ec(self, request: web.Request,
                        fid: FileId) -> web.Response:
@@ -1784,21 +1919,33 @@ class VolumeServer:
     _SHARD_LOC_MISSING_TTL = 11.0
     _SHARD_LOC_KNOWN_TTL = 7 * 60.0
 
+    def _cached_holders(self, vid: int,
+                        shard_id: int) -> Optional[list[str]]:
+        """The peers the location cache says hold the shard, while the
+        cache is fresh under the tiers above; None where it is not."""
+        cached = self._shard_loc_cache.get(vid)
+        if cached is None:
+            return None
+        shards, fetched = cached
+        age = time.monotonic() - fetched
+        have = str(shard_id) in shards
+        if age < self._SHARD_LOC_MISSING_TTL or \
+                (have and age < self._SHARD_LOC_KNOWN_TTL):
+            return [u for u in shards.get(str(shard_id), [])
+                    if u != self.url]
+        return None
+
     def _shard_locations(self, vid: int, shard_id: int,
                          force: bool = False) -> list[str]:
         """Tiered-TTL cache of vid -> shard -> holder urls."""
         import json as _json
         import urllib.request
+        if not force:
+            urls = self._cached_holders(vid, shard_id)
+            if urls is not None:
+                return urls
         now = time.monotonic()
         cached = self._shard_loc_cache.get(vid)
-        if cached is not None and not force:
-            shards, fetched = cached
-            age = now - fetched
-            have = str(shard_id) in shards
-            if age < self._SHARD_LOC_MISSING_TTL or \
-                    (have and age < self._SHARD_LOC_KNOWN_TTL):
-                return [u for u in shards.get(str(shard_id), [])
-                        if u != self.url]
         try:
             req = urllib.request.Request(
                 f"http://{self.master_url}/col/lookup/ec?volumeId={vid}",

@@ -386,7 +386,10 @@ def test_read_at_still_preads_what_the_mapping_cannot_give(built):
 
 def test_unmounts_under_the_read_are_declines_never_errors(built):
     """A shard unmounted and mounted again by other threads while a
-    reader slices it: every read is the needle or a decline."""
+    reader slices it: every read is the needle or a decline. The reads
+    go on until both have been seen (at most 10 s); each churner holds
+    its shard mounted a moment between a mount and the next unmount, so
+    that every shard of the needle is mounted at once now and then."""
     import sys
     import threading
     import time
@@ -401,6 +404,7 @@ def test_unmounts_under_the_read_are_declines_never_errors(built):
             while not stop.is_set():
                 ev.delete_shard(sid)
                 ev.add_shard(sid)
+                stop.wait(0.001)
         except Exception as e:  # reported below
             errors.append(e)
 
@@ -411,8 +415,8 @@ def test_unmounts_under_the_read_are_declines_never_errors(built):
     try:
         for th in churners:
             th.start()
-        deadline = time.time() + 1.0
-        while time.time() < deadline:
+        deadline = time.time() + 10.0
+        while not (served and declined) and time.time() < deadline:
             got, located = ev.read_needle_nowait(i, cookie=COOKIE + i)
             if got is None:
                 assert located is not None
